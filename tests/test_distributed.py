@@ -41,15 +41,12 @@ def test_two_process_bsp_matches_single_process(tmp_path):
     base = [
         "--rule", "BSP", "--config", CFG,
     ]
-    env_cache = {
-        "JAX_COMPILATION_CACHE_DIR": str(tmp_path.parent / "jax_cache_dist"),
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0.5",
-    }
+    # no cache variables: the spawned ranks place their compile cache
+    # by the repo's one rule (theanompi_tpu/cachedir.py)
     spawn_local(
         2,
         base + ["--checkpoint-dir", str(d2)],
         local_device_count=2,
-        env_extra=env_cache,
         timeout=600,
         stream_output=False,
     )
@@ -59,7 +56,6 @@ def test_two_process_bsp_matches_single_process(tmp_path):
         1,
         base + ["--checkpoint-dir", str(d1)],
         local_device_count=4,
-        env_extra=env_cache,
         timeout=600,
         stream_output=False,
     )
@@ -91,15 +87,10 @@ def test_two_process_dcn_hybrid_matches_flat(tmp_path):
     dh = tmp_path / "dcn_two_proc"
     df = tmp_path / "flat_one_proc"
     dcn_cfg = _json.dumps(dict(_json.loads(CFG), dcn_shape=2))
-    env_cache = {
-        "JAX_COMPILATION_CACHE_DIR": str(tmp_path.parent / "jax_cache_dcn"),
-        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0.5",
-    }
     spawn_local(
         2,
         ["--rule", "BSP", "--config", dcn_cfg, "--checkpoint-dir", str(dh)],
         local_device_count=4,
-        env_extra=env_cache,
         timeout=600,
         stream_output=False,
     )
@@ -107,7 +98,6 @@ def test_two_process_dcn_hybrid_matches_flat(tmp_path):
         1,
         ["--rule", "BSP", "--config", CFG, "--checkpoint-dir", str(df)],
         local_device_count=8,
-        env_extra=env_cache,
         timeout=600,
         stream_output=False,
     )

@@ -21,15 +21,8 @@ CFG = (
     '"comm_probe": false, "seed": 5}'
 )
 
-ENV_CACHE = {
-    "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0.5",
-}
-
-
-def _cache_env(tmp_path):
-    return dict(ENV_CACHE, JAX_COMPILATION_CACHE_DIR=str(
-        tmp_path.parent / "jax_cache_dist"
-    ))
+# no cache variables: the spawned ranks place their compile cache by the
+# repo's one rule (theanompi_tpu/cachedir.py)
 
 
 @pytest.mark.distributed
@@ -50,7 +43,6 @@ def test_easgd_across_processes(tmp_path):
             "--duties-coalesce", "0",
         ],
         local_device_count=1,
-        env_extra=_cache_env(tmp_path),
         timeout=600,
         stream_output=False,
     )
@@ -98,7 +90,6 @@ def test_gosgd_across_processes(tmp_path):
             "--async-port-base", str(port),
         ],
         local_device_count=1,
-        env_extra=_cache_env(tmp_path),
         timeout=600,
         stream_output=False,
     )
@@ -126,7 +117,6 @@ def test_easgd_fp16_wire_across_processes(tmp_path):
             "--wire-dtype", "float16",
         ],
         local_device_count=1,
-        env_extra=_cache_env(tmp_path),
         timeout=600,
         stream_output=False,
     )

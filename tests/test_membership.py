@@ -799,13 +799,6 @@ def test_worker_evicted_alert_exactly_once_per_kill():
 # the real drill: kill → evict → respawn → re-admit, cross-process
 # ---------------------------------------------------------------------------
 
-# NOTE: unlike test_distributed_async, the drill runs WITHOUT a
-# persistent compile cache: a respawned child would RELOAD executables
-# its predecessor cached, and on this container's legacy jaxlib a
-# cached-executable reload segfaults (see cachedir.legacy_jaxlib) —
-# cold compiles are the price of a deterministic drill.
-
-
 @pytest.mark.distributed
 def test_easgd_chaos_drill_kill_evict_respawn_readmit(tmp_path):
     """The acceptance drill (ISSUE 10): SIGKILL an EASGD worker

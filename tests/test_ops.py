@@ -350,9 +350,10 @@ def test_maxpool_pallas_tie_split_and_batch_padding():
 
     np.testing.assert_allclose(np.asarray(jax.grad(loss_m)(x)), np.asarray(g))
 
-    # force multiple grid blocks + padding: row budget makes nb < n
-    old = pallas_pool._ROW_BUDGET
-    pallas_pool._ROW_BUDGET = 81  # 9x9 plane -> nb=1
+    # force multiple grid blocks + padding: a budget of two images'
+    # planes makes nb=2 < n=3
+    old = pallas_pool._VMEM_BUDGET
+    pallas_pool._VMEM_BUDGET = 2 * pallas_pool._image_bytes(9, 9)
     try:
         xr = jax.random.normal(jax.random.PRNGKey(3), (3, 9, 9, 2))
 
@@ -364,7 +365,7 @@ def test_maxpool_pallas_tie_split_and_batch_padding():
         g_n = jax.grad(lambda x: loss_r(x, "native"))(xr)
         np.testing.assert_allclose(np.asarray(g_p), np.asarray(g_n), atol=1e-6)
     finally:
-        pallas_pool._ROW_BUDGET = old
+        pallas_pool._VMEM_BUDGET = old
 
 
 def test_adam_matches_numpy():
@@ -387,6 +388,25 @@ def test_adam_matches_numpy():
         w2 = w2 - scale * m2 / (np.sqrt(v2) + 1e-8)
     np.testing.assert_allclose(np.asarray(p["w"]), w2, rtol=1e-6)
     assert int(state["step"]) == 3
+
+
+def test_set_lr_keeps_the_old_scalars_placement():
+    """A fresh uncommitted lr among committed step outputs is a second
+    argument signature: the train step then compiled twice, once for the
+    first iteration after every ``set_lr`` (PR 21 bring-up)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from theanompi_tpu.runtime.mesh import make_mesh
+
+    sh = NamedSharding(make_mesh(), P())
+    state = {"lr": jax.device_put(jnp.float32(0.1), sh), "step": 0}
+    new = optim.set_lr(state, 0.01)
+    assert new["lr"].committed and new["lr"].sharding == sh
+    np.testing.assert_allclose(float(new["lr"]), 0.01)
+    assert state["lr"] is not new["lr"] and float(state["lr"]) == \
+        pytest.approx(0.1)
+    # a host-side state (no placement to keep) still works
+    assert float(optim.set_lr({"lr": 0.1}, 0.5)["lr"]) == 0.5
 
 
 def test_adamw_decoupled_decay():

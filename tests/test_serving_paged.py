@@ -149,6 +149,71 @@ def test_paged_scheduler_interleaved_matches_serial(paged_chunked):
     assert [len(inter[r]) for r, _, n in reqs] == [n for _, _, n in reqs]
 
 
+def _aligned_copy(a, align=64):
+    """``a`` copied into memory aligned to ``align`` bytes.  The CPU
+    client shares a host buffer with jax only when it is aligned like
+    its own; malloc hands NumPy such memory now and then, which is why
+    the bug flickered instead of failing every time."""
+    raw = np.empty(a.nbytes + align, np.uint8)
+    off = (-raw.ctypes.data) % align
+    out = raw[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+def test_dispatch_inputs_cannot_alias_scheduler_state(paged_chunked,
+                                                      monkeypatch):
+    """PR 21's flicker, pinned: a jitted program must read what the
+    scheduler's host arrays held AT DISPATCH, whatever the scheduler
+    writes into them afterwards.  Dispatch returns before the program
+    has read its inputs, and on the CPU client an aligned NumPy buffer
+    handed to jax is shared, not copied (``serving.engine.host_input``).
+    Here the hazard is made as certain as it can be: the scheduler's
+    ``_tokens``/``_tables``/``_lengths`` sit in aligned memory, and
+    right after every decode dispatch they are overwritten, the program
+    is left to finish, and the arrays restored — with private input
+    copies nothing changes; with shared buffers the program decodes
+    token 0 at position 0 through the trash block."""
+    eng = paged_chunked
+    reqs = [
+        ("a", [1, 2, 3], 7),
+        ("b", list(np.random.RandomState(7).randint(0, 32, size=30)), 5),
+        ("c", [4], 9),
+        ("e", [5, 5, 5, 5, 5, 5], 4),
+    ]
+
+    def serve(interleaved):
+        out, scheds = {}, []
+        for rid, prompt, n in reqs:
+            if not scheds or not interleaved:
+                sched = ContinuousBatchingScheduler(eng)
+                for name in ("_tokens", "_tables", "_lengths"):
+                    setattr(sched, name, _aligned_copy(getattr(sched, name)))
+                scheds.append(sched)
+            scheds[-1].submit(
+                Request(id=rid, prompt=list(prompt), max_new_tokens=n))
+        for s in scheds:
+            out.update(s.run())
+        return out
+
+    serial = serve(interleaved=False)
+    real = PagedServingEngine.decode_step_paged
+
+    def hostile(self, params, state, tokens, tables, lengths, active):
+        out = real(self, params, state, tokens, tables, lengths, active)
+        held = [np.array(a) for a in (tokens, tables, lengths)]
+        for a in (tokens, tables, lengths):
+            a[...] = 0
+        jax.block_until_ready(out)
+        for a, was in zip((tokens, tables, lengths), held):
+            a[...] = was
+        return out
+
+    monkeypatch.setattr(PagedServingEngine, "decode_step_paged", hostile)
+    for _ in range(3):
+        assert serve(interleaved=True) == serial
+
+
 def test_paged_metric_names_identical(contiguous, paged):
     """The serving metrics surface is engine-agnostic: a consumer of
     BENCH_serve/ serve_summary sees the same TTFT/TPOT keys."""

@@ -442,16 +442,19 @@ def test_pallas_engine_decode_allclose_to_xla(model, kv_dtype):
     )
 
 
-def test_pallas_falls_back_on_multidevice_mesh():
-    """A dp-sharded pool cannot run the single-shard kernel: the engine
-    records the fallback and serves through XLA — never a crash."""
+def test_pallas_refused_on_multidevice_mesh_auto_selects_xla():
+    """A dp-sharded pool cannot run the single-shard kernel: demanding
+    it is an error at engine build (never a quiet swap), and 'auto'
+    selects the XLA gather by the same rule."""
     mesh = make_mesh()  # 8 fake devices
     if mesh.devices.size == 1:
         pytest.skip("single-device environment")
     model = TransformerLM(config=dict(CFG), mesh=mesh)
+    with pytest.raises(ValueError, match="single-device pool"):
+        PagedServingEngine(model, n_slots=2, max_len=64, block_size=8,
+                           paged_attn="pallas")
     eng = PagedServingEngine(model, n_slots=2, max_len=64, block_size=8,
-                             paged_attn="pallas")
+                             paged_attn="auto")
     assert eng.paged_attn_effective == "xla"
-    assert eng.paged_attn_fallback
     out = eng.greedy([5, 3, 2], 4)
     assert len(out) == 4

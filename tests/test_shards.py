@@ -23,6 +23,35 @@ def test_native_lib_builds():
     assert shards.native_available()
 
 
+def test_native_lib_never_loads_a_stale_binary(monkeypatch):
+    """``native/libtnploader.so`` is git-ignored, so the file on disk may
+    be anything.  The library comes from ``shard_loader.cpp`` or the
+    failure is loud: a build that fails raises even with a ``.so`` lying
+    there, and a machine without a toolchain warns and reads with NumPy
+    — in neither case is the stray file handed to ``dlopen``."""
+    import ctypes
+    import subprocess
+
+    assert shards.native_available()  # a built .so now exists on disk
+    loaded = []
+    monkeypatch.setattr(ctypes, "CDLL", lambda p: loaded.append(p))
+    monkeypatch.setattr(shards, "_lib", None)
+
+    monkeypatch.setattr(shards, "_lib_tried", False)
+    monkeypatch.setattr(
+        shards.subprocess, "run",
+        lambda *a, **k: subprocess.CompletedProcess(a, 2, "", "g++: boom"),
+    )
+    with pytest.raises(RuntimeError, match="failed to build"):
+        shards._load_lib()
+
+    monkeypatch.setattr(shards, "_lib_tried", False)
+    monkeypatch.setattr(shards.shutil, "which", lambda name: None)
+    with pytest.warns(RuntimeWarning, match="no C\\+\\+ toolchain"):
+        assert shards._load_lib() is None
+    assert loaded == []
+
+
 def test_roundtrip_native(tmp_path):
     batches = _make_batches()
     paths = shards.write_shard_dir(str(tmp_path), batches)

@@ -1,0 +1,209 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+*described*, not attached (``topologies.get_topology_desc``).  Nothing
+runs, so nothing here says a word about results or speed; what it does
+say is what interpret mode cannot: whether Mosaic lowers each kernel at
+the real widths (PR 21 found the fp16s wire kernels refused and the pool
+backward never finishing), whether the AlexNet step fits a 16 GB chip,
+and whether the BSP step over four chips holds its all-reduce.
+
+The kernel cases are the ones ``chip_smoke.py`` runs on the chip
+(``theanompi_tpu/ops/kernel_cases.py``) — one list, no second copy.
+
+Rules this file keeps (``on-chip-measurement`` guide, section 2):
+
+- the topology is described inside a module-scoped, non-``autouse``
+  fixture OF THIS FILE, after a test has started — never at import, in a
+  ``skipif``, in a ``parametrize`` argument or in ``conftest.py`` (only
+  one process may load the TPU library; the xdist worker that is handed
+  this file is the one that does);
+- code that asks "am I on a TPU" sees the CPU here, so the test steers
+  the one gate (``ops.platform.on_tpu``) — the program has no option for
+  it;
+- the persistent compile cache is off around these compiles: an entry
+  written for a described chip cannot be read back without one.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from theanompi_tpu.ops import kernel_cases, platform
+
+HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
+
+# building the list imports modules and closes over shapes; no device,
+# no backend, no topology is touched until a test asks a fixture
+CASES = {c.name: c for c in kernel_cases.cases("real")}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Every kernel gate and dtype policy takes its TPU branch."""
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+
+
+def _described(tree, sharding):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+# ---------------------------------------------------------------------------
+# every Pallas kernel, at the shapes the chip runs
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_under_mosaic_for_v5e(name, one_chip, as_tpu):
+    case = CASES[name]
+    args = _described(
+        jax.eval_shape(case.make_args, jax.random.PRNGKey(0)), one_chip
+    )
+    with kernel_cases.matmul_precision(case.kernel_precision):
+        text = jax.jit(case.kernel).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, f"{name}: kernel did not reach Mosaic"
+
+
+def test_pool_backward_compiles_in_bf16_too(one_chip, as_tpu):
+    """AlexNet's activations are bf16 under ``compute_dtype='bfloat16'``;
+    the kernel widens into its fp32 scratch, so the narrow input must
+    lower as well."""
+    from theanompi_tpu.ops.pallas_pool import maxpool_bwd
+
+    x = jax.ShapeDtypeStruct((512, 32, 32, 96), jnp.bfloat16,
+                             sharding=one_chip)
+    y = jax.ShapeDtypeStruct((512, 15, 15, 96), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(
+        lambda x, y, dy: maxpool_bwd(x, y, dy, (3, 3), (2, 2))
+    ).lower(x, y, y).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_pool_backward_refuses_a_plane_over_its_vmem_budget():
+    """ADVICE r5: above the plane its fast memory can hold the kernel
+    raises a clear error of its own instead of meeting Mosaic's."""
+    from theanompi_tpu.ops.pallas_pool import maxpool_bwd, plane_fits_vmem
+
+    assert plane_fits_vmem(32, 32) and not plane_fits_vmem(112, 112)
+    x = jnp.zeros((1, 112, 112, 8))
+    y = jnp.zeros((1, 55, 55, 8))
+    with pytest.raises(ValueError, match="VMEM"):
+        maxpool_bwd(x, y, y, (3, 3), (2, 2))
+
+
+# ---------------------------------------------------------------------------
+# whole programs: the AlexNet step and the paged engine
+# ---------------------------------------------------------------------------
+
+def _alexnet_step(topo, n_devices, batch_size=512):
+    """AlexNet's jitted train step over ``n_devices`` described chips,
+    and its arguments as shapes.  The model is built on CPU devices (it
+    places its own parameters, and nothing can be placed on a described
+    device), then handed the described mesh before the step is built."""
+    from theanompi_tpu.models.alex_net import AlexNet
+    from theanompi_tpu.runtime.mesh import make_mesh
+
+    model = AlexNet(
+        config=dict(batch_size=batch_size, image_size=128, n_classes=1000,
+                    compute_dtype="bfloat16", n_synth_batches=1, lr=1e-3),
+        mesh=make_mesh(devices=jax.devices()[:n_devices]),
+    )
+    mesh = make_mesh(devices=topo.devices[:n_devices])
+    model.mesh = mesh
+    step = model.compile_train()
+    rep, batch = NamedSharding(mesh, P()), NamedSharding(mesh, model.batch_spec)
+    gb = batch_size * n_devices
+    args = (
+        _described(model.params, rep), _described(model.net_state, rep),
+        _described(model.opt_state, rep),
+        jax.ShapeDtypeStruct((gb, 128, 128, 3), jnp.float32, sharding=batch),
+        jax.ShapeDtypeStruct((gb,), jnp.int32, sharding=batch),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
+    )
+    return step, args
+
+
+def test_alexnet_step_fits_one_v5e_chip(topo, as_tpu):
+    """Batch 512, bf16, every layer at its width: the step the *train*
+    phase of chip_smoke.py runs."""
+    step, args = _alexnet_step(topo, 1)
+    mem = step.lower(*args).compile().memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < need < HBM_BYTES, f"step needs {need / 2**30:.2f} GiB"
+
+
+def test_bsp_step_over_four_chips_holds_an_all_reduce(topo, as_tpu):
+    step, args = _alexnet_step(topo, 4)
+    text = step.lower(*args).compile().as_text()
+    assert re.search(r"all-reduce(-start)?\(", text), (
+        "dp=4 BSP step compiled without an all-reduce"
+    )
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_paged_engine_programs_compile_at_serving_widths(
+    kv_dtype, one_chip, as_tpu
+):
+    """The prefill-chunk and decode programs of ``PagedServingEngine``
+    at ``bench_serve.py``'s real knobs, Pallas decode kernel inside."""
+    from theanompi_tpu.models.transformer import TransformerLM
+    from theanompi_tpu.serving import PagedServingEngine
+
+    cfg = dict(seq_len=1024, vocab_size=4096, d_model=512, n_heads=8,
+               n_layers=8, batch_size=1, n_synth_train=2, n_synth_val=1,
+               comm_probe=False, print_freq=10_000)
+    model = TransformerLM(
+        config=cfg,
+        mesh=TransformerLM.build_mesh(devices=jax.devices()[:1], config=cfg),
+    )
+    eng = PagedServingEngine(
+        model, n_slots=32, max_len=1024, block_size=32, n_blocks=257,
+        prefill_chunk=256, kv_dtype=kv_dtype, paged_attn="pallas",
+    )
+    params = _described(model.params, one_chip)
+    state = _described(jax.eval_shape(eng.init_state), one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s, nb, c = eng.n_slots, eng.blocks_per_seq, eng.chunk_buckets[-1]
+    eng._paged_prefill_jit.lower(
+        params, state, arg(jnp.int32, s, c), arg(jnp.int32, s, nb),
+        arg(jnp.int32, s), arg(jnp.int32, s), arg(jnp.bool_, s),
+    ).compile()
+    text = eng._paged_decode_jit.lower(
+        params, state, arg(jnp.int32, s), arg(jnp.int32, s, nb),
+        arg(jnp.int32, s), arg(jnp.bool_, s),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text

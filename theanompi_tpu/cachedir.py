@@ -1,209 +1,53 @@
-"""Per-host, per-user XLA compile-cache location for CPU runs.
+"""Where the persistent XLA compile cache lives, and the CPU rig's flags.
 
-One definition shared by tests/conftest.py, bench.py's rehearsal, and
-scripts/convergence.py — the three CPU entrypoints must agree or their
-caches silently diverge.  Deliberately import-light (no jax, nothing
-heavy): conftest calls this before it pins the platform.
+One rule, shared by every entry point (``chip_smoke.py``, the benches,
+``launch.py``'s spawned ranks, ``tests/conftest.py``, the scripts):
 
-Why not the repo's ``.jax_cache``: XLA:CPU persists AOT-compiled
-executables keyed by the *compiling* machine's features; loading one on
-a host without those features logs ``cpu_aot_loader`` errors and can
-SIGILL/SIGABRT mid-run.  The repo cache stays reserved for the real-TPU
-path, whose Mosaic binaries are host-independent.
+- ``JAX_COMPILATION_CACHE_DIR`` set  → the program sets NOTHING in code.
+  JAX reads the variable itself, and whoever exported it (a driver, a
+  chip command that runs two processes against one cache) owns the
+  location and the thresholds.
+- unset → ``<checkout>/.jax_cache`` (git-ignored).  The path is part of
+  the cache key's neighbourhood — a directory that moves (temp dir, pid,
+  time) never hits — so it is fixed relative to this file and therefore
+  identical across processes of one checkout.
 
-Keyed by CPU-FEATURE FINGERPRINT, host, and user — r4 diagnosed round
-3's nondeterministic mid-suite ``Fatal Python error: Aborted`` (a
-faulthandler dump finally caught it inside a compiled module in
-``run_validation``): every rig in this environment is hostname ``vm``,
-so a hostname key let rounds running on different physical machine
-types share one cache, and stale AOT executables from a
-different-microarchitecture host loaded with "machine type ... doesn't
-match" warnings and aborted under load.  Hashing the cpuinfo flags set
-separates those machines; host+user stay in the key for shared-tempdir
-hygiene (a cache dir created by user A is not writable by user B).
+Deliberately import-light (no jax): conftest calls :func:`cpu_xla_flags`
+before jax is imported.
 """
 
-# XLA:CPU collective-call rendezvous TERMINATES the process ("Exiting to
-# ensure a consistent program state") when its worker threads don't all
-# arrive within the timeout — on this 1-core rig concurrent
-# 8-fake-device JAX processes starve each other past it, which is the
-# r3/r4 nondeterministic mid-suite SIGABRT. PROVEN in r4 by setting the
-# flag to 5s and watching rendezvous.cc terminate with "of 5 seconds
-# exceeded ... only 7 of them arrived"; a 600s setting then died to a
-# contention window that lasted ~10 min, confirming the arithmetic
-# (kill = stuck-warn 20s + this timeout). CI semantics want "hang until
-# the outer `timeout` kills the whole run, never abort mid-suite" —
-# so the value is effectively-infinite, and the real rule is: NEVER run
-# two heavy JAX CPU processes concurrently on this rig. (The stale-AOT
-# "machine type doesn't match" log spam is mostly XLA's own
-# prefer-no-scatter/gather hint flags and appears on every cached
-# load; the cpuinfo-fingerprint cache key stays as cheap hygiene.)
-# 1200 s, not infinite: a SOLO run later stalled the same rendezvous
-# with every thread futex-parked (a real in-XLA deadlock of overlapped
-# async executions, now also fenced at the train->val boundary in
-# models/base.py run_validation) — an infinite timeout turns that into
-# a silent suite-budget-eating hang, while 1200 s survives any
-# plausible transient starvation and converts a true deadlock into a
-# diagnosable rendezvous.cc F-log abort after 20 min.
+import os
+
+# XLA:CPU's collective rendezvous TERMINATES the process when its
+# participants do not all arrive within its default timeout; several
+# fake-device JAX processes sharing a few cores (pytest-xdist workers,
+# spawned ranks) can starve each other past it.  1200 s survives any
+# plausible starvation while a true deadlock still aborts diagnosably.
 CPU_RENDEZVOUS_FLAG = (
     "--xla_cpu_collective_call_terminate_timeout_seconds=1200"
 )
 
-import getpass
-import hashlib
-import os
-import platform
-import subprocess
-import sys
-import tempfile
-
-_rendezvous_flag_ok = None  # per-process memo of the probe below
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def _jaxlib_version() -> str:
-    try:  # jaxlib.version is import-light (no backend machinery)
-        from jaxlib import version
-
-        return version.__version__
-    except Exception:
-        return "unknown"
-
-
-def rendezvous_flag_supported() -> bool:
-    """Whether the installed jaxlib's XLA parses CPU_RENDEZVOUS_FLAG.
-
-    XLA *aborts the process* (parse_flags_from_env.cc F-log) on an
-    unknown flag in XLA_FLAGS, so appending the rendezvous guard on a
-    jaxlib that predates it (observed: 0.4.x rejects it) kills every
-    CPU entrypoint at first backend init — the whole suite, bench
-    rehearsals, convergence runs.  There is no Python-level flag query,
-    so this probes once in a SUBPROCESS (the abort must not take this
-    process down) and caches the verdict in tempdir keyed by jaxlib
-    version + CPU fingerprint, making the probe a once-per-environment
-    cost instead of once per run."""
-    global _rendezvous_flag_ok
-    if _rendezvous_flag_ok is not None:
-        return _rendezvous_flag_ok
-    marker = os.path.join(
-        tempfile.gettempdir(),
-        f"theanompi_xla_flagprobe_{_jaxlib_version()}_{_cpu_fingerprint()}",
-    )
-    try:
-        with open(marker) as f:
-            _rendezvous_flag_ok = f.read().strip() == "1"
-        return _rendezvous_flag_ok
-    except OSError:
-        pass
-    code = (
-        "import os;"
-        f"os.environ['XLA_FLAGS']='{CPU_RENDEZVOUS_FLAG}';"
-        "import jax;"
-        "jax.config.update('jax_platforms','cpu');"
-        "jax.devices()"
-    )
-    try:
-        ok = (
-            subprocess.run(
-                [sys.executable, "-c", code],
-                capture_output=True, timeout=240,
-            ).returncode == 0
+def repo_cache_dir() -> str:
+    """The fixed in-checkout cache path used when ``CACHE_ENV`` is unset."""
+    return os.path.abspath(
+        os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), os.pardir,
+            ".jax_cache",
         )
-    except (subprocess.SubprocessError, OSError):
-        ok = False  # can't prove support -> don't risk the F-abort
-    _rendezvous_flag_ok = ok
-    try:
-        tmp = marker + f".{os.getpid()}"
-        with open(tmp, "w") as f:
-            f.write("1" if ok else "0")
-        os.replace(tmp, marker)
-    except OSError:
-        pass  # uncached probes re-run; never fail the caller
-    return ok
-
-
-def _cpu_fingerprint() -> str:
-    """Hash of the host's CPU feature flags (codegen-relevant identity).
-    Order-insensitive; falls back to the machine arch string."""
-    flags = ""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    flags = " ".join(sorted(line.split(":", 1)[1].split()))
-                    break
-    except OSError:
-        pass
-    basis = flags or platform.machine() or "unknown"
-    return hashlib.sha256(basis.encode()).hexdigest()[:10]
-
-
-def cpu_cache_dir() -> str:
-    try:
-        user = getpass.getuser()
-    except (KeyError, OSError):  # no passwd entry (containers)
-        user = str(os.getuid()) if hasattr(os, "getuid") else "user"
-    return os.path.join(
-        tempfile.gettempdir(),
-        f"theanompi_jax_cache_{_cpu_fingerprint()}_"
-        f"{platform.node() or 'host'}_{user}",
     )
 
 
-def legacy_jaxlib() -> bool:
-    """jaxlib < 0.5: the era before the modern ``jax.shard_map`` surface.
-    On these, re-loading a persistently-cached CPU executable SEGFAULTS
-    inside the compiled call (reproduced in this container with 0.4.36
-    on a FRESH cache dir: probe compiles the step, the post-probe
-    recompile deserializes the just-written entry, the next execution
-    dies) — so the persistent compile cache must stay off."""
-    try:
-        parts = tuple(
-            int(x) for x in _jaxlib_version().split(".")[:2]
-        )
-    except ValueError:
-        return False  # unparseable = assume modern
-    return parts < (0, 5)
-
-
-def disable_cache_if_legacy(jax_mod) -> bool:
-    """Force the persistent compile cache OFF on a legacy jaxlib, even
-    when ``JAX_COMPILATION_CACHE_DIR`` is set in the environment.
-
-    Spawned worker processes (``launch.py`` --dist-* children, the
-    elastic chaos drill's respawns) inherit the env var from test/CI
-    harnesses, and jax honors it natively without ever consulting
-    :func:`configure_compile_cache`'s no-op guard — so a respawned
-    rank would RELOAD an executable its predecessor cached and die of
-    the legacy segfault this module documents.  An explicit config
-    update outranks the env var.  Returns True when the cache was
-    force-disabled."""
-    if not legacy_jaxlib():
-        return False
-    jax_mod.config.update("jax_compilation_cache_dir", None)
-    return True
-
-
-def configure_compile_cache(jax_mod, use_repo_cache: bool) -> str:
-    """Apply the repo's ONE persistent-compile-cache policy and return
-    the chosen dir. ``use_repo_cache=True`` = the committed ``.jax_cache``
-    (real-TPU runs only: Mosaic executables are host-independent, and
-    warm entries are what make the scarce bench window cheap);
-    False = the per-host-fingerprint tempdir (everything CPU — see the
-    module docstring for why foreign AOT entries are dangerous).
-    Takes the caller's ``jax`` module so this file stays import-light.
-
-    No-op on a legacy jaxlib (:func:`legacy_jaxlib`): cached-executable
-    reloads segfault there, and cold compiles beat dead processes."""
-    if legacy_jaxlib():
-        return ""
-    cache = (
-        os.path.abspath(
-            os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                         os.pardir, ".jax_cache")
-        )
-        if use_repo_cache
-        else cpu_cache_dir()
-    )
+def configure_compile_cache(jax_mod) -> str:
+    """Apply the rule in the module docstring; returns the directory the
+    cache will use.  Takes the caller's ``jax`` module so this file
+    stays import-light."""
+    env = os.environ.get(CACHE_ENV)
+    if env:
+        return env
+    cache = repo_cache_dir()
     jax_mod.config.update("jax_compilation_cache_dir", cache)
     jax_mod.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax_mod.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
@@ -211,21 +55,14 @@ def configure_compile_cache(jax_mod, use_repo_cache: bool) -> str:
 
 
 def cpu_xla_flags(existing: str = "", fake_devices=8) -> str:
-    """The CPU entrypoints' shared XLA_FLAGS recipe: the fake-device
-    mesh (``fake_devices=None`` to skip — convergence.py sizes devices
-    via the config API instead) plus the rendezvous-termination guard.
+    """The CPU entry points' shared XLA_FLAGS recipe: the fake-device
+    mesh (``fake_devices=None`` to skip) plus the rendezvous guard.
     Idempotent: flags already present are not appended twice."""
     flags = existing or ""
     if fake_devices and "xla_force_host_platform_device_count" not in flags:
         flags = (
             f"{flags} --xla_force_host_platform_device_count={fake_devices}"
         ).strip()
-    if (
-        "collective_call_terminate_timeout" not in flags
-        and rendezvous_flag_supported()
-    ):
-        # version-gated: see rendezvous_flag_supported — an unknown flag
-        # in XLA_FLAGS is a process-killing F-abort, strictly worse than
-        # running without the rendezvous guard
+    if "collective_call_terminate_timeout" not in flags:
         flags = f"{flags} {CPU_RENDEZVOUS_FLAG}".strip()
     return flags

@@ -10,14 +10,6 @@ a process would force an extra copy through shared memory) pulls host
 batches from the provider, shards them onto the mesh with ``device_put``
 (async under JAX dispatch), and keeps ``depth`` batches in flight so the
 ICI/MXU step, not input, bounds iteration time.
-
-Legacy-jaxlib note: pre-``jax.shard_map`` jaxlibs (0.4.x) have a CPU
-client that SEGFAULTS when one thread runs ``device_put`` while another
-executes a compiled program — exactly this loader's steady state
-(observed killing the suite in this container's image). Under
-``runtime.jax_compat.LEGACY_JAX`` the loader degrades to synchronous
-in-line placement: same iterator contract, no thread, no prefetch
-overlap — correctness over throughput on the rigs that need it.
 """
 
 from __future__ import annotations
@@ -26,10 +18,7 @@ import queue
 import threading
 from typing import Callable, Iterator, Optional
 
-import jax
-
 from theanompi_tpu import observability as obs
-from theanompi_tpu.runtime import jax_compat
 
 _REG = obs.get_registry()
 _BATCHES = _REG.counter(
@@ -57,13 +46,6 @@ class PrefetchLoader:
         depth: int = 2,
     ):
         self._place = place
-        self._sync_it = None
-        if jax_compat.LEGACY_JAX:
-            # no worker thread: this jaxlib's CPU client is not safe
-            # against device_put concurrent with compiled execution
-            # (module docstring) — place batches in-line instead
-            self._sync_it = iter(batches)
-            return
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._err: Optional[BaseException] = None
         self._thread = threading.Thread(
@@ -88,13 +70,6 @@ class PrefetchLoader:
         return self
 
     def __next__(self):
-        if self._sync_it is not None:
-            # sync degrade: load+place in-line, attributed as the
-            # consumer's 'load' time (there is no hidden pipeline)
-            with obs.span("data_load_place"):
-                placed = self._place(next(self._sync_it))
-            _BATCHES.inc(mode="sync")
-            return placed
         # 'data_wait' is the consumer-visible stall: ~0 while the
         # prefetch pipeline keeps up, one load-time wide when it starves
         with obs.span("data_wait"):

@@ -7,9 +7,10 @@ files read by a spawned loader process (SURVEY.md §3.6).  Our equivalents:
   written by :func:`write_raw_shard`, shapes carried in a ``meta.json``
   sidecar per directory (no HDF5 C dependency).
 - **native ring loader**: ``native/shard_loader.cpp`` (C++ reader thread
-  + pre-allocated ring, ctypes ABI). Auto-built with ``make`` on first
-  use; :class:`RawShardReader` falls back to NumPy reads when no
-  toolchain is present.
+  + pre-allocated ring, ctypes ABI). Built with ``make`` from that
+  source on first use — a stray prebuilt ``.so`` is never trusted;
+  :class:`RawShardReader` uses NumPy reads (with a warning) only when
+  the machine has no toolchain at all.
 """
 
 from __future__ import annotations
@@ -17,7 +18,9 @@ from __future__ import annotations
 import ctypes
 import json
 import os
+import shutil
 import subprocess
+import warnings
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -31,36 +34,48 @@ _lib_tried = False
 
 
 def _load_lib():
-    """Load (building/rebuilding if needed) the native loader; None if
-    unavailable. ``make`` is invoked unconditionally so a stale ``.so``
-    gets rebuilt whenever ``shard_loader.cpp`` is newer (it is a no-op
-    when up to date)."""
+    """Build the native loader from ``native/shard_loader.cpp`` and
+    load it; None only when the machine has no C++ toolchain (then
+    :class:`RawShardReader` uses its NumPy reader, and says so).
+
+    The library always comes from the source git holds: ``make`` runs
+    unconditionally (a no-op when the ``.so`` is newer than the
+    ``.cpp``), and a ``.so`` that happens to lie in ``native/`` is NEVER
+    loaded when the build did not run or did not succeed — the file is
+    git-ignored, so it may be anything.  A toolchain that is present
+    but fails to build is a bug and raises."""
     global _lib, _lib_tried
     if _lib_tried:
         return _lib
     _lib_tried = True
-    try:
-        # Serialize the (re)build across processes: N worker ranks start
-        # together, and an unlocked `make` race could dlopen a partially
-        # written .so. Every process takes the lock before its make; any
-        # process that reaches CDLL has therefore waited out all writers.
-        import fcntl
-
-        with open(_LIB_PATH + ".lock", "w") as lockf:
-            fcntl.flock(lockf, fcntl.LOCK_EX)
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR, "-s"],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-    except (OSError, subprocess.SubprocessError):
-        if not os.path.exists(_LIB_PATH):
-            return None  # no toolchain AND no prebuilt library
-    try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    cxx = os.environ.get("CXX", "g++")
+    if shutil.which("make") is None or shutil.which(cxx) is None:
+        warnings.warn(
+            f"no C++ toolchain (make + {cxx}) to build "
+            "native/shard_loader.cpp: raw shards are read by the NumPy "
+            "reader",
+            RuntimeWarning,
+            stacklevel=2,
+        )
         return None
+    # Serialize the (re)build across processes: N worker ranks start
+    # together, and an unlocked `make` race could dlopen a partially
+    # written .so. Every process takes the lock before its make; any
+    # process that reaches CDLL has therefore waited out all writers.
+    import fcntl
+
+    with open(_LIB_PATH + ".lock", "w") as lockf:
+        fcntl.flock(lockf, fcntl.LOCK_EX)
+        built = subprocess.run(
+            ["make", "-C", _NATIVE_DIR, "-s"],
+            capture_output=True, text=True, timeout=120,
+        )
+    if built.returncode != 0:
+        raise RuntimeError(
+            "native/shard_loader.cpp failed to build:\n"
+            + (built.stderr or built.stdout)[-2000:]
+        )
+    lib = ctypes.CDLL(_LIB_PATH)
     lib.tnp_version.restype = ctypes.c_int
     lib.tnp_loader_open.restype = ctypes.c_void_p
     lib.tnp_loader_open.argtypes = [

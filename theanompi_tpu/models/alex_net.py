@@ -50,6 +50,7 @@ class AlexNet(TpuModel):
             image_size=int(cfg.image_size),
             n_classes=int(cfg.n_classes),
             n_synth_batches=int(cfg.n_synth_batches),
+            n_synth_val_batches=int(cfg.get("n_synth_val_batches", 4)),
             seed=int(cfg.seed),
             crop_size=cfg.crop_size,
             mirror=bool(cfg.mirror),

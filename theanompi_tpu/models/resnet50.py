@@ -69,6 +69,7 @@ class ResNet50(TpuModel):
             image_size=int(cfg.image_size),
             n_classes=int(cfg.n_classes),
             n_synth_batches=int(cfg.n_synth_batches),
+            n_synth_val_batches=int(cfg.get("n_synth_val_batches", 4)),
             seed=int(cfg.seed),
             mean_subtract=bool(cfg.get("mean_subtract", True)),
         )

@@ -94,6 +94,7 @@ __all__ = [
     "bucket_quantile",
     "counter_deltas",
     "counter_event",
+    "count_xla_compiles",
     "counter_values",
     "disable_request_tracking",
     "disable_tracing",
@@ -150,6 +151,41 @@ def publish_event(kind: str, fields: dict) -> None:
         tracer.instant(kind, dict(fields) if fields else None)
     for fn in _subscribers:
         fn(kind, fields)
+
+
+_XLA_PROGRAMS = get_registry().counter(
+    "xla_programs_total",
+    "programs handed to the XLA compiler or fetched from its persistent "
+    "cache (one per new jit signature)",
+)
+_XLA_CACHE_HITS = get_registry().counter(
+    "xla_cache_hits_total",
+    "of xla_programs_total, those the persistent compile cache served",
+)
+_xla_counting = False
+
+
+def count_xla_compiles() -> None:
+    """Feed jax's own compile events into the two counters above
+    (idempotent).  Counters ride every ``Recorder`` epoch row as deltas,
+    so "this epoch compiled nothing" and "the second run was served from
+    the cache" are read off the record instead of guessed from timings."""
+    global _xla_counting
+    if _xla_counting:
+        return
+    _xla_counting = True
+    import jax.monitoring
+
+    def on_duration(event, duration_secs, **kwargs):
+        if event == "/jax/core/compile/backend_compile_duration":
+            _XLA_PROGRAMS.inc()
+
+    def on_event(event, **kwargs):
+        if event == "/jax/compilation_cache/cache_hits":
+            _XLA_CACHE_HITS.inc()
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
 
 
 def counter_values() -> dict:

@@ -1485,7 +1485,7 @@ def render_report(report: dict) -> str:
 # the request doctor: one retained request → a phase attribution
 # ---------------------------------------------------------------------------
 
-# the phase taxonomy, in REPORT order.  One definition: the
+# the phase list, in REPORT order.  One definition: the
 # instrumentation sites (scheduler/fleet) emit the ``req_*`` spans,
 # the tracer's tail retention buffers them, and this table is where
 # the agreement on what they MEAN lives.
@@ -1707,7 +1707,7 @@ def check_request_thresholds(
                         f"{p99['latency_s']:.4f}s latency is "
                         "unattributed > "
                         f"{100 * max_p99_unattributed_frac:.1f}% — "
-                        "instrumentation gap in the phase taxonomy"
+                        "instrumentation gap in the phase list"
                     ),
                 })
     return v

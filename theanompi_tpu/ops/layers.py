@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from theanompi_tpu.ops import platform
+
 Params = Any
 State = Any
 Shape = Tuple[int, ...]
@@ -272,7 +274,7 @@ def _conv_operand_dtypes(x, w, compute_dtype):
     the conv result to, or None."""
     if compute_dtype is None:
         return x, w, None
-    if jax.default_backend() == "tpu":
+    if platform.on_tpu():
         return x.astype(compute_dtype), w.astype(compute_dtype), None
     return x.astype(jnp.float32), w.astype(jnp.float32), compute_dtype
 
@@ -446,32 +448,11 @@ class MaxPool(Layer):
         if self.grad_impl == "mask":
             return _maxpool_mask(x, self.window, self.stride, self.padding), state
         if self.grad_impl == "pallas":
-            from theanompi_tpu.ops.pallas_pool import (
-                maxpool_pallas, plane_fits_vmem,
-            )
+            from theanompi_tpu.ops.pallas_pool import maxpool_pallas
 
-            h, w = x.shape[1], x.shape[2]
-            if not plane_fits_vmem(h, w):
-                # the kernel's grid blocks over batch only — a plane
-                # past the VMEM row budget cannot be block-resident and
-                # Mosaic would fail to compile. Fall back to the native
-                # select-and-scatter backward rather than crash
-                # (ADVICE r5 item 1); warn once per layer instance.
-                if not getattr(self, "_pallas_fallback_warned", False):
-                    self._pallas_fallback_warned = True
-                    import warnings
-
-                    warnings.warn(
-                        f"MaxPool grad_impl='pallas': {h}x{w} plane "
-                        "exceeds the kernel's VMEM row budget — falling "
-                        "back to the 'native' backward for this layer",
-                        RuntimeWarning,
-                        stacklevel=2,
-                    )
-                return (
-                    _maxpool_fwd_raw(x, self.window, self.stride, self.padding),
-                    state,
-                )
+            # a plane past the kernel's VMEM budget raises in its
+            # backward (pallas_pool.maxpool_bwd) — never a silent swap
+            # to the native path
             return maxpool_pallas(x, self.window, self.stride, self.padding), state
         return _maxpool_fwd_raw(x, self.window, self.stride, self.padding), state
 

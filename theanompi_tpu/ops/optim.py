@@ -324,9 +324,19 @@ def param_shaped_entries(state: OptState, params_treedef) -> tuple:
 
 
 def set_lr(state: OptState, lr: float) -> OptState:
-    """Host-side lr mutation between steps (reference: shared-var set)."""
+    """Host-side lr mutation between steps (reference: shared-var set).
+
+    The new scalar goes where the old one lived: a fresh uncommitted
+    scalar among committed step outputs is a second argument signature,
+    and the train step then compiles twice — once for the first
+    iteration after every ``set_lr``, once for the rest (seen as a second
+    ``jit(shard_step)`` compile in PR 21's bring-up)."""
     new = dict(state)
-    new["lr"] = jnp.asarray(lr, jnp.float32)
+    lr = jnp.asarray(lr, jnp.float32)
+    old = state.get("lr")
+    if isinstance(old, jax.Array) and old.committed:
+        lr = jax.device_put(lr, old.sharding)
+    new["lr"] = lr
     return new
 
 

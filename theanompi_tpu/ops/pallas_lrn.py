@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from theanompi_tpu.ops import platform
+
 _ROWS = 512  # rows (= B·H·W elements) per grid step; VMEM ~ ROWS·C·4B·few
 
 
@@ -103,7 +105,7 @@ def _rowblock_call(kernel, out_dtype, size, alpha, beta, k, *arrays):
         grid=(mp // _ROWS,),
         in_specs=[spec] * len(flats),
         out_specs=spec,
-        interpret=(jax.default_backend() == "cpu"),
+        interpret=not platform.on_tpu(),
     )(*flats)
     return out[:m].reshape(x.shape)
 

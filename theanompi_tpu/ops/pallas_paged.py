@@ -23,17 +23,17 @@ skipped entirely (``pl.when``), mirroring the flash kernels' masked-
 block elision; within the boundary block, rows past the length mask
 to ``-inf`` exactly like the XLA path's ``att_mask``.
 
-``interpret=True`` off-TPU (the ``pallas_flash._on_tpu`` device gate)
+``interpret=True`` on the CPU (the ``ops.platform.on_tpu`` gate)
 so CPU CI exercises the same kernel code — the tier-1 contract is
 allclose against the XLA gather path on both fp32 and int8 pools.
 
 Scope: the kernel is a SINGLE-SHARD program.  ``supported()`` gates on
 one device — a dp-sharded pool or tp-sharded heads would need a
-shard_map wrapper this jaxlib's pallas lowering does not compose with,
-so the engine keeps the XLA path there (see docs/serving.md for the
-fallback matrix).  On-chip, the small serving head counts also violate
-the (32, 128) int8 tile floor — real-TPU enablement is a next-window
-item; interpret-mode correctness is what tier-1 pins today.
+shard_map wrapper that is not built, so the engine selects the XLA
+path there (see docs/serving.md for the selection matrix).  Both pool
+dtypes compile under Mosaic for a v5e at the serving widths (block 32,
+8 heads, head 64 — the int8 block is exactly one (32, 128)-tiled
+plane per head pair; ``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -47,20 +47,18 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from theanompi_tpu.ops.pallas_flash import _NEG_INF, _on_tpu, resolve_scale
+from theanompi_tpu.ops import platform
+from theanompi_tpu.ops.pallas_flash import _NEG_INF, resolve_scale
 
 
 def supported(mesh=None) -> bool:
     """Whether the fused kernel can serve this pool.
 
     Single-device only: ``pallas_call`` under jit has no partitioning
-    rule on this jaxlib, so a pool sharded over dp rows or tp heads
-    must keep the XLA gather (GSPMD partitions that one for free).
+    rule, so a pool sharded over dp rows or tp heads takes the XLA
+    gather (GSPMD partitions that one for free).
     """
-    try:
-        n = mesh.devices.size if mesh is not None else len(jax.devices())
-    except RuntimeError:
-        return False
+    n = mesh.devices.size if mesh is not None else len(jax.devices())
     return int(n) == 1
 
 
@@ -237,7 +235,7 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, hd), jnp.float32),
-        interpret=(not _on_tpu()) if interpret is None else interpret,
+        interpret=(not platform.on_tpu()) if interpret is None else interpret,
     )(
         jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
         *args,

@@ -9,17 +9,24 @@ each one is a full input-sized HBM read-modify-write plus stride-2
 slice relayouts (the r5 layout diagnosis in NOTES.md).
 
 This kernel runs the SAME shifted-mask math but entirely in VMEM per
-batch block: one HBM read of x, one of (y, dy) at output resolution,
-one HBM write of dx.  The per-offset gather (strided window sample)
-and scatter (interior-dilated placement) are expressed as matmuls with
-0/1 selection matrices built by iota in registers — the ``pallas_lrn``
-``_win_sum`` idiom — because cross-sublane reshapes/strided slices are
-exactly the data movements Mosaic lowers poorly; a (rows, h)×(h, oh)
-band matmul instead rides the MXU, and 0/1 × value sums a single term
-per output, so the selection is EXACT in fp32.  The AlexNet/GoogLeNet-
-era pools have small spatial extents (≤ 32×32), so a block holds the
-FULL spatial plane and no halo exchange is needed; the grid walks the
-batch axis.
+(batch, channel) block: one HBM read of x, one of (y, dy) at output
+resolution, one HBM write of dx.  Each window offset (di, dj) is one
+STRIDED view of the VMEM-resident plane — ``ref[:, ds(di, oh, sh),
+ds(dj, ow, sw), :]`` — read to compare against ``y`` and read-modify-
+written to scatter the cotangent.  H is an untiled leading dim (a
+stride there is address arithmetic), W rides the sublanes (Mosaic's
+``strided_load``/``strided_store``), C rides the lanes untouched.  The
+compare is an exact fp32 equality, so no precision knob is involved.
+
+What Mosaic demands of that shape (found by compiling for a described
+v5e, PR 21 — the earlier band-matmul formulation never finished
+compiling): strided access needs a 32-bit plane whose lane dim is one
+128-lane tile, so x is widened into an fp32 VMEM scratch first and the
+grid walks channels in blocks of 128 (C ≤ 128 is one block; a wider C
+that is not a multiple of 128 is zero-padded up to one).  The plane
+itself is never split: a block holds the FULL (h, w) extent, so no
+halo exchange is needed — and a plane too large for the budget is an
+error here (:func:`plane_fits_vmem`), not a Mosaic refusal.
 
 Tie semantics match ``_maxpool_mask_bwd``: the cotangent is split
 EQUALLY across tied window maxima (select-and-scatter routes to the
@@ -41,130 +48,98 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# rows (= H·W positions) of the input plane per batch-block; the f32
-# working set per block is ~4 buffers × rows × C × 4B (x, acc, and the
-# transient per-offset products) — 4096 rows × 96ch ≈ 6 MB, inside the
-# v5e VMEM budget with headroom for double buffering
-_ROW_BUDGET = 4096
+from theanompi_tpu.ops import platform
 
-
-def _select_band(out_len: int, in_len: int, offset: int, stride: int,
-                 dtype=jnp.float32) -> jnp.ndarray:
-    """(out_len, in_len) 0/1 matrix with ``B[p, offset + p*stride] = 1``
-    — built by iota in registers (never touches HBM).  Right-applied it
-    GATHERS the strided window sample; its transpose SCATTERS values
-    back to the dilated+offset positions."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (out_len, in_len), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (out_len, in_len), 1)
-    return (cols == offset + rows * stride).astype(dtype)
+_LANES = 128  # channel block: strided VMEM access wants one lane tile
+# VMEM the blocks of one grid step may take, out of the 16 MiB a kernel
+# is scoped to by default on v5e.  Per image of a block, in fp32-plane
+# units: the two fp32 scratch planes plus the double-buffered x and dx
+# blocks (counted at 4 B even when they are bf16 — headroom for the
+# (y, dy, count) values at output resolution).
+_VMEM_BUDGET = 8 << 20
+_PLANES_PER_IMAGE = 6
 
 
-def _pool_bwd_kernel(x_ref, y_ref, dy_ref, dx_ref, *, window, stride):
-    kh, kw = window
-    sh, sw = stride
-    x = x_ref[...].astype(jnp.float32)
-    y = y_ref[...].astype(jnp.float32)
-    dy = dy_ref[...].astype(jnp.float32)
-    nb, h, w, c = x.shape
-    oh, ow = y.shape[1:3]
-    span_h = (oh - 1) * sh + 1
-    span_w = (ow - 1) * sw + 1
-
-    offsets = [
-        (di, dj)
-        for di in range(kh)
-        for dj in range(kw)
-        if di + span_h <= h and dj + span_w <= w
-    ]
-
-    # HIGHEST precision is LOAD-BEARING on every band matmul: the
-    # kernel's correctness hinges on bit-exact `window_sample == y`
-    # equality, and the MXU's default f32 matmul rounds operands
-    # through bf16 (see pallas_flash.py on exact-f32 multiplies) —
-    # a max with >8 mantissa bits would then match NO tap and its
-    # window's cotangent mass would silently vanish.
-    _EXACT = dict(
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    bands = {
-        (di, dj): (_select_band(oh, h, di, sh), _select_band(ow, w, dj, sw))
-        for di, dj in offsets
-    }
-
-    def window_sample(di, dj):
-        """x sample each window reads at offset (di, dj): (nb,oh,ow,c),
-        via two exact 0/1 band matmuls (gather = B_h · x · B_wᵀ)."""
-        bh, bw = bands[(di, dj)]
-        # contract H: (oh,h) × (nb,h,w,c) over h
-        xs = jnp.einsum("ph,nhwc->npwc", bh, x, **_EXACT)
-        # contract W: (ow,w) × (nb,oh,w,c) over w
-        return jnp.einsum("qw,npwc->npqc", bw, xs, **_EXACT)
-
-    # pass 1 (VMEM-resident): ties per window, for the mass-conserving
-    # equal split
-    cnt = jnp.zeros(y.shape, jnp.float32)
-    for di, dj in offsets:
-        cnt = cnt + (window_sample(di, dj) == y).astype(jnp.float32)
-    dyc = dy / cnt  # every window has >= 1 max
-
-    acc = jnp.zeros(x.shape, jnp.float32)
-    for di, dj in offsets:
-        contrib = jnp.where(window_sample(di, dj) == y, dyc, 0.0)
-        # scatter = the same bands transposed: Bᵀ_h · contrib · B_w
-        bh, bw = bands[(di, dj)]
-        up = jnp.einsum("ph,npqc->nhqc", bh, contrib, **_EXACT)
-        acc = acc + jnp.einsum("qw,nhqc->nhwc", bw, up, **_EXACT)
-    dx_ref[...] = acc.astype(dx_ref.dtype)
+def _image_bytes(h: int, w: int) -> int:
+    """VMEM one image of a block takes: ``_PLANES_PER_IMAGE`` fp32
+    (h, w, 128-lane) planes, W padded to the 8-sublane tile."""
+    return _PLANES_PER_IMAGE * h * (-(-w // 8) * 8) * _LANES * 4
 
 
 def plane_fits_vmem(h: int, w: int) -> bool:
-    """Whether one (h, w) spatial plane fits the kernel's per-block VMEM
-    budget.  The grid walks the BATCH axis only, so even at nb=1 the
-    whole plane plus the fp32 accumulator must be VMEM-resident — past
-    the row budget Mosaic fails to compile with no fallback (ADVICE r5
-    item 1; in-repo pools are <= 32x32 and comfortably inside)."""
-    return h * w <= _ROW_BUDGET
+    """Whether one (h, w) spatial plane fits the kernel's VMEM budget.
+    The grid walks batch and channels only, so even a one-image block
+    keeps the whole plane resident (ADVICE r5 item 1; in-repo pools at
+    128 px are <= 32x32 and comfortably inside)."""
+    return _image_bytes(h, w) <= _VMEM_BUDGET
+
+
+def _pool_bwd_kernel(x_ref, y_ref, dy_ref, dx_ref, xf_ref, acc_ref,
+                     *, window, stride):
+    kh, kw = window
+    sh, sw = stride
+    oh, ow = y_ref.shape[1:3]
+    xf_ref[...] = x_ref[...].astype(jnp.float32)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    y = y_ref[...].astype(jnp.float32)
+    dy = dy_ref[...].astype(jnp.float32)
+    # VALID pooling: every (di, dj) tap of every window is in bounds
+    taps = [
+        (slice(None), pl.ds(di, oh, stride=sh), pl.ds(dj, ow, stride=sw),
+         slice(None))
+        for di in range(kh) for dj in range(kw)
+    ]
+    # pass 1: ties per window, for the mass-conserving equal split
+    cnt = jnp.zeros(y.shape, jnp.float32)
+    for tap in taps:
+        cnt = cnt + (xf_ref[tap] == y).astype(jnp.float32)
+    dyc = dy / cnt  # every window has >= 1 max
+    # pass 2: scatter-add through the same strided views
+    for tap in taps:
+        acc_ref[tap] = acc_ref[tap] + jnp.where(xf_ref[tap] == y, dyc, 0.0)
+    dx_ref[...] = acc_ref[...].astype(dx_ref.dtype)
 
 
 def maxpool_bwd(x, y, dy, window, stride) -> jnp.ndarray:
-    """dx for a VALID max pool, via the batch-blocked Pallas kernel."""
+    """dx for a VALID max pool, via the (batch, channel)-blocked kernel."""
     n, h, w, c = x.shape
     oh, ow = y.shape[1:3]
     if not plane_fits_vmem(h, w):
         raise ValueError(
-            f"maxpool_bwd: {h}x{w} spatial plane ({h * w} rows) exceeds "
-            f"the kernel's VMEM row budget ({_ROW_BUDGET}); the grid "
-            "blocks over batch only, so a plane this large cannot be "
-            "VMEM-resident — use grad_impl='native' for this pool"
+            f"maxpool_bwd: a {h}x{w} spatial plane needs "
+            f"{_image_bytes(h, w)} bytes of VMEM per "
+            f"image, over the kernel's budget ({_VMEM_BUDGET}); the grid "
+            "blocks over batch and channels only, so a plane this large "
+            "cannot be VMEM-resident — use grad_impl='native' for this "
+            "pool"
         )
-    # clamp to n: without it a small batch pads UP to the row budget
-    # (e.g. batch 4 on a 7x7 plane -> 83 rows, ~20x wasted work)
-    nb = max(1, min(n, _ROW_BUDGET // (h * w)))
-    pad = (-n) % nb
-    if pad:
-        zx = ((0, pad), (0, 0), (0, 0), (0, 0))
-        x = jnp.pad(x, zx)
-        # padded batch rows: y=0 matches x=0 at every offset, dy=0 so
-        # their dx contribution is exactly 0 — no masking needed
-        y = jnp.pad(y, zx)
-        dy = jnp.pad(dy, zx)
-    np_ = n + pad
-    in_specs = [
-        pl.BlockSpec((nb, h, w, c), lambda i: (i, 0, 0, 0)),
-        pl.BlockSpec((nb, oh, ow, c), lambda i: (i, 0, 0, 0)),
-        pl.BlockSpec((nb, oh, ow, c), lambda i: (i, 0, 0, 0)),
-    ]
+    cb = c if c <= _LANES else _LANES
+    # clamp to n: without it a small batch pads UP to the budget
+    nb = max(1, min(n, _VMEM_BUDGET // _image_bytes(h, w)))
+    pad_n, pad_c = (-n) % nb, (-c) % cb
+    if pad_n or pad_c:
+        # padded rows/channels: y=0 matches x=0 at every tap and dy=0,
+        # so their dx is exactly 0 — no masking needed
+        zx = ((0, pad_n), (0, 0), (0, 0), (0, pad_c))
+        x, y, dy = (jnp.pad(a, zx) for a in (x, y, dy))
+    np_, cp = n + pad_n, c + pad_c
+    spec_x = pl.BlockSpec((nb, h, w, cb), lambda i, j: (i, 0, 0, j))
+    spec_y = pl.BlockSpec((nb, oh, ow, cb), lambda i, j: (i, 0, 0, j))
     out = pl.pallas_call(
         partial(_pool_bwd_kernel, window=window, stride=stride),
-        out_shape=jax.ShapeDtypeStruct((np_, h, w, c), x.dtype),
-        grid=(np_ // nb,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((nb, h, w, c), lambda i: (i, 0, 0, 0)),
-        interpret=(jax.default_backend() == "cpu"),
+        out_shape=jax.ShapeDtypeStruct((np_, h, w, cp), x.dtype),
+        grid=(np_ // nb, cp // cb),
+        in_specs=[spec_x, spec_y, spec_y],
+        out_specs=spec_x,
+        scratch_shapes=[
+            pltpu.VMEM((nb, h, w, cb), jnp.float32),  # x widened to fp32
+            pltpu.VMEM((nb, h, w, cb), jnp.float32),  # dx accumulator
+        ],
+        interpret=not platform.on_tpu(),
     )(x, y, dy)
-    return out[:n]
+    return out[:n, :, :, :c]
 
 
 def _require_valid(padding):

@@ -159,10 +159,16 @@ class _RemoteServer:
         self.generation: Optional[int] = None
 
     def join(self, rank: Optional[int] = None):
+        # a long connect ladder for THIS request only: ranks start
+        # together and the server builds its model before it listens,
+        # so a worker that is ready first meets a refused connection —
+        # with the default 3 attempts in ~0.2 s that was a start-up race
+        # lost about one run in six (backoff doubles to 2 s: ~1 min)
         reply = request(
             self.address,
             {"kind": "join", "rank": self.rank if rank is None else rank},
             timeout=self.timeout_s,
+            connect_retries=30,
         )
         self.generation = reply.get("generation", self.generation)
         self._last_tau = reply.get("tau", self._last_tau)

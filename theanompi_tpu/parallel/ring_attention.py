@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from theanompi_tpu.runtime import jax_compat as _jax_compat  # noqa: F401
-
 SEQ_AXIS = "sp"  # canonical sequence-parallel mesh axis name
 
 _NEG_INF = -1e30  # finite mask value: keeps exp() NaN-free on all-masked rows

@@ -33,8 +33,6 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from theanompi_tpu.runtime import jax_compat as _jax_compat  # noqa: F401
-
 from theanompi_tpu.parallel.ring_attention import SEQ_AXIS, full_attention
 
 

@@ -1,4 +1,3 @@
-from theanompi_tpu.runtime import jax_compat  # noqa: F401  (installs shims)
 from theanompi_tpu.runtime.mesh import (  # noqa: F401
     init_distributed,
     make_mesh,

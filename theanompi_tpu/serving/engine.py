@@ -41,6 +41,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -117,6 +118,24 @@ def _validate_buckets(buckets, max_len: int) -> Tuple[int, ...]:
     if out[-1] > max_len:
         raise ValueError(f"bucket {out[-1]} exceeds max_len={max_len}")
     return out
+
+
+def host_input(x, dtype=None):
+    """A device array from a PRIVATE host copy of ``x`` — for handing
+    scheduler state to a jitted program.
+
+    Dispatch returns before the program has read its inputs, and on the
+    CPU client a NumPy buffer given to jax is shared, not copied.  A
+    scheduler that mutates ``_lengths``/``_tables``/``_tokens`` in place
+    on the next line therefore raced the program it had just launched:
+    tokens that differed run to run (PR 21).  ``jnp.asarray`` shares the
+    caller's buffer outright; ``jnp.array`` shares it too and then
+    copies ON the device, asynchronously — a narrower window, still a
+    race (a request's third token alternated 30/28 between identical
+    runs until the copy moved here).  So the copy is taken by NumPy,
+    synchronously, before jax sees anything; what jax may then alias is
+    a buffer nobody else holds.  The arrays are a few hundred bytes."""
+    return jnp.asarray(np.array(x, dtype=dtype, copy=True))
 
 
 class ServingEngine:
@@ -336,8 +355,6 @@ class ServingEngine:
         and run the compiled prefill.  Returns (cache, logits (V,)).
         ``rid`` (request id) rides the span args only — request-trace
         routing, zero effect on the compiled dispatch."""
-        import numpy as np
-
         toks = np.asarray(tokens, dtype=np.int32).reshape(-1)
         n = int(toks.size)
         if n < 1:
@@ -349,7 +366,7 @@ class ServingEngine:
         extra = {"rid": rid} if rid is not None else {}
         with obs.span("prefill_dispatch", bucket=b, true_len=n, **extra):
             return self._prefill_jit(
-                params, cache, jnp.asarray(padded),
+                params, cache, host_input(padded),
                 jnp.int32(slot), jnp.int32(n),
             )
 
@@ -417,8 +434,8 @@ class ServingEngine:
         host arrays (S,) — see ``_decode_fn``."""
         return self._decode_jit(
             params, cache,
-            jnp.asarray(tokens, dtype=jnp.int32),
-            jnp.asarray(active, dtype=bool),
+            host_input(tokens, jnp.int32),
+            host_input(active, bool),
         )
 
     # ------------------------------------------------------------------
@@ -428,8 +445,6 @@ class ServingEngine:
         """Greedy-decode ``n_new`` tokens after ``prompt`` on slot 0.
         The scheduler is the real serving path; this is the minimal
         parity/smoke surface."""
-        import numpy as np
-
         params = params if params is not None else self.model.params
         cache = self.init_cache()
         cache, logits = self.prefill(params, cache, 0, prompt)

@@ -60,10 +60,10 @@ Decode-speed layers on top (ISSUE 11):
 - **paged_attn='pallas'** — the decode tick's attention runs the
   fused ``ops.pallas_paged`` kernel: block tables scalar-prefetched
   into the kernel, K/V blocks gathered inside it (int8 dequant
-  in-VMEM), online softmax over the block stream.  Falls back to the
-  XLA gather path whenever the kernel cannot serve the pool
-  (multi-device mesh — see ``pallas_paged.supported``); numerics are
-  pinned allclose between the two paths.
+  in-VMEM), online softmax over the block stream.  A pool the kernel
+  cannot serve (multi-device mesh — see ``pallas_paged.supported``)
+  is refused at construction; ``'auto'`` picks by that same rule.
+  Numerics are pinned allclose between the two paths.
 
 Correctness contract (tests/test_serving_paged.py): greedy decode
 through block tables is token-identical to the contiguous engine and
@@ -86,7 +86,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from theanompi_tpu import observability as obs
 from theanompi_tpu.runtime.mesh import DATA_AXIS, TP_AXIS
 from theanompi_tpu.serving import metrics as smetrics
-from theanompi_tpu.serving.engine import _NEG_INF, ServingEngine
+from theanompi_tpu.serving.engine import (
+    _NEG_INF, ServingEngine, host_input,
+)
 
 TRASH_BLOCK = 0  # reserved physical block: masked/inactive writes land here
 
@@ -298,9 +300,9 @@ class PagedServingEngine(ServingEngine):
       byte, greedy drift bounded by the bench probe).
     - ``paged_attn`` — ``'xla'`` (gathered-image attention, the
       GSPMD-partitionable default), ``'pallas'`` (fused in-kernel
-      gather where supported), or ``'auto'``.  Unsupported pools fall
-      back to XLA — ``paged_attn_effective`` records what actually
-      runs.
+      gather; a multi-device pool is refused at construction), or
+      ``'auto'`` (the kernel on a single-device pool, XLA on a sharded
+      one) — ``paged_attn_effective`` records which runs.
     """
 
     is_paged = True
@@ -376,15 +378,19 @@ class PagedServingEngine(ServingEngine):
 
         self.paged_attn = paged_attn
         kernel_ok = pallas_paged.supported(self.mesh)
-        # 'pallas' is a REQUEST, not a demand: an unsupported pool
-        # (multi-device mesh) keeps the GSPMD-partitionable XLA path —
-        # same numerics contract, no crash at engine build
+        if paged_attn == "pallas" and not kernel_ok:
+            # a demand the pool cannot meet is an error at engine
+            # build, never a quiet swap of kernels; 'auto' is the
+            # spelling that lets the pool's layout choose
+            raise ValueError(
+                "paged_attn='pallas' needs a single-device pool (the "
+                f"kernel is a single-shard program); this mesh has "
+                f"{self.mesh.devices.size} devices — use 'auto' or 'xla'"
+            )
+        # the ONE selection rule: fused kernel on a single-device pool,
+        # GSPMD-partitionable XLA gather on a sharded one
         self.paged_attn_effective = (
-            "pallas" if paged_attn in ("pallas", "auto") and kernel_ok
-            else "xla"
-        )
-        self.paged_attn_fallback = (
-            paged_attn == "pallas" and not kernel_ok
+            "pallas" if paged_attn != "xla" and kernel_ok else "xla"
         )
         # pool rows shard over dp only when every per-device shard is a
         # whole number of blocks (a split block would tear the
@@ -732,9 +738,9 @@ class PagedServingEngine(ServingEngine):
         with obs.span("prefill_chunk_dispatch", rows=len(rows), bucket=c):
             state, logits = self._paged_prefill_jit(
                 params, state,
-                jnp.asarray(tokens), jnp.asarray(tables),
-                jnp.asarray(p0), jnp.asarray(true_len),
-                jnp.asarray(active),
+                host_input(tokens), host_input(tables),
+                host_input(p0), host_input(true_len),
+                host_input(active),
             )
         return state, logits
 
@@ -756,11 +762,11 @@ class PagedServingEngine(ServingEngine):
                       width=int(np.asarray(tokens).shape[1])):
             state, logits = self._paged_verify_jit(
                 params, state,
-                jnp.asarray(tokens, dtype=jnp.int32),
-                jnp.asarray(tables, dtype=jnp.int32),
-                jnp.asarray(p0, dtype=jnp.int32),
-                jnp.asarray(true_len, dtype=jnp.int32),
-                jnp.asarray(active, dtype=bool),
+                host_input(tokens, jnp.int32),
+                host_input(tables, jnp.int32),
+                host_input(p0, jnp.int32),
+                host_input(true_len, jnp.int32),
+                host_input(active, bool),
             )
         return state, logits
 
@@ -769,10 +775,10 @@ class PagedServingEngine(ServingEngine):
         """One decode tick; host arrays in, ``(state, logits)`` out."""
         return self._paged_decode_jit(
             params, state,
-            jnp.asarray(tokens, dtype=jnp.int32),
-            jnp.asarray(tables, dtype=jnp.int32),
-            jnp.asarray(lengths, dtype=jnp.int32),
-            jnp.asarray(active, dtype=bool),
+            host_input(tokens, jnp.int32),
+            host_input(tables, jnp.int32),
+            host_input(lengths, jnp.int32),
+            host_input(active, bool),
         )
 
     # ------------------------------------------------------------------

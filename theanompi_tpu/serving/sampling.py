@@ -32,6 +32,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from theanompi_tpu.serving.engine import host_input
+
 _NEG_INF = -1e30  # engine's finite mask value (engine._NEG_INF)
 
 
@@ -112,9 +114,9 @@ class Sampler:
 
         out = self._batch_fn(
             jnp.asarray(logits),
-            jnp.asarray(keys, dtype=jnp.uint32),
-            jnp.asarray(temperatures, dtype=jnp.float32),
-            jnp.asarray(top_ks, dtype=jnp.int32),
+            host_input(keys, jnp.uint32),
+            host_input(temperatures, jnp.float32),
+            host_input(top_ks, jnp.int32),
         )
         return np.asarray(out)
 

@@ -199,6 +199,11 @@ def run_trial(
         "error": None,
         "cached": False,
     }
+    # The parent that spawns a bench stays OFF jax: a chip belongs to
+    # one process at a time, and a parent that had touched it would
+    # hold it against the child.  This package imports no jax
+    # (tests/test_chip_smoke.py pins `"jax" not in sys.modules`), and
+    # the trials run one at a time.
     try:
         proc = subprocess.run(
             list(bench_cmd),
