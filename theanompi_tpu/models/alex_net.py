@@ -77,29 +77,30 @@ class AlexNet(TpuModel):
         net = L.Sequential(
             [
                 L.Conv2d(96, 11, stride=4, padding="SAME", compute_dtype=dt,
-                         s2d=s2d_stem),
-                L.Relu(),
-                L.LRN(**lrn),
-                L.MaxPool(3, stride=2, grad_impl=pg),
-                L.Conv2d(256, 5, padding="SAME", compute_dtype=dt),
-                L.Relu(),
-                L.LRN(**lrn),
-                L.MaxPool(3, stride=2, grad_impl=pg),
-                L.Conv2d(384, 3, padding="SAME", compute_dtype=dt),
-                L.Relu(),
-                L.Conv2d(384, 3, padding="SAME", compute_dtype=dt),
-                L.Relu(),
-                L.Conv2d(256, 3, padding="SAME", compute_dtype=dt),
-                L.Relu(),
-                L.MaxPool(3, stride=2, grad_impl=pg),
-                L.Flatten(),
-                L.Dense(4096, compute_dtype=dt),
-                L.Relu(),
-                L.Dropout(drop),
-                L.Dense(4096, compute_dtype=dt),
-                L.Relu(),
-                L.Dropout(drop),
-                L.Dense(int(cfg.n_classes), compute_dtype=dt, output_dtype=jnp.float32),
+                         s2d=s2d_stem).named("conv1"),
+                L.Relu().named("relu1"),
+                L.LRN(**lrn).named("lrn1"),
+                L.MaxPool(3, stride=2, grad_impl=pg).named("pool1"),
+                L.Conv2d(256, 5, padding="SAME", compute_dtype=dt).named("conv2"),
+                L.Relu().named("relu2"),
+                L.LRN(**lrn).named("lrn2"),
+                L.MaxPool(3, stride=2, grad_impl=pg).named("pool2"),
+                L.Conv2d(384, 3, padding="SAME", compute_dtype=dt).named("conv3"),
+                L.Relu().named("relu3"),
+                L.Conv2d(384, 3, padding="SAME", compute_dtype=dt).named("conv4"),
+                L.Relu().named("relu4"),
+                L.Conv2d(256, 3, padding="SAME", compute_dtype=dt).named("conv5"),
+                L.Relu().named("relu5"),
+                L.MaxPool(3, stride=2, grad_impl=pg).named("pool5"),
+                L.Flatten().named("flatten"),
+                L.Dense(4096, compute_dtype=dt).named("fc6"),
+                L.Relu().named("relu6"),
+                L.Dropout(drop).named("drop6"),
+                L.Dense(4096, compute_dtype=dt).named("fc7"),
+                L.Relu().named("relu7"),
+                L.Dropout(drop).named("drop7"),
+                L.Dense(int(cfg.n_classes), compute_dtype=dt,
+                        output_dtype=jnp.float32).named("fc8"),
             ]
         )
         self.lr_schedule = optim.step_decay(

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from theanompi_tpu import observability as obs
 from theanompi_tpu.data.loader import prefetch_to_mesh
 from theanompi_tpu.ops import losses
 from theanompi_tpu.ops import optim as optim_lib
@@ -38,6 +39,10 @@ from theanompi_tpu.ops.layers import Layer, count_params
 from theanompi_tpu.parallel.exchanger import BSP_Exchanger
 from theanompi_tpu.runtime.config import Config
 from theanompi_tpu.runtime.mesh import DATA_AXIS, DCN_AXIS, make_mesh, replicate
+
+# the tracer imports no jax: the program's jax-importing modules hand it
+# the profiler's annotation, so boundary spans show in any profile
+obs.install_annotation_hook(jax.profiler.TraceAnnotation)
 
 _METRICS_SYNC: Optional[bool] = None
 
@@ -311,11 +316,15 @@ class TpuModel:
         return err, err5
 
     def loss_and_metrics(self, params, net_state, x, y, train: bool, rng):
-        logits, new_state = self.net.apply(
-            params, net_state, self._cast_input(x), train=train, rng=rng
-        )
-        loss = losses.softmax_cross_entropy(logits, y)
-        err, err5 = self._metrics(logits, y)
+        # named scopes are metadata on the same operations (the backward
+        # pass inherits the forward's): a profile groups by them
+        with jax.named_scope("forward"):
+            logits, new_state = self.net.apply(
+                params, net_state, self._cast_input(x), train=train, rng=rng
+            )
+        with jax.named_scope("loss"):
+            loss = losses.softmax_cross_entropy(logits, y)
+            err, err5 = self._metrics(logits, y)
         return loss, (err, err5, new_state)
 
     # ------------------------------------------------------------------
@@ -653,13 +662,14 @@ class TpuModel:
                     # already reduced; done_mask passes them through and
                     # this call sweeps up only the leftovers (stem,
                     # embeddings, head, norms)
-                    grads = maybe_clip(
-                        exchanger.reduce_grads(
+                    with jax.named_scope("exchange"):
+                        grads = exchanger.reduce_grads(
                             grads, param_specs, rng=ex_key,
                             done_mask=indag_mask,
                         )
-                    )
-                params, opt_state = opt.update(params, grads, opt_state)
+                    grads = maybe_clip(grads)
+                with jax.named_scope("update"):
+                    params, opt_state = opt.update(params, grads, opt_state)
                 if ef:
                     # AFTER update: optimizers rebuild their state dict
                     # from known keys, which would silently drop ef_wire
@@ -774,6 +784,12 @@ class TpuModel:
             )
 
     def train_iter(self, count: int, recorder) -> Tuple[float, float]:
+        # boundary span over the whole step on the host; the recorder's
+        # `wait` and `calc` are its children
+        with obs.span("train_iter", boundary=True, iter=count):
+            return self._train_iter(count, recorder)
+
+    def _train_iter(self, count: int, recorder) -> Tuple[float, float]:
         if self.train_fn is None:
             self.compile_train()
         if self._train_it is None:

@@ -230,7 +230,7 @@ class LSGAN(TpuModel):
         return self.val_fn
 
     # -- contract -------------------------------------------------------
-    def train_iter(self, count: int, recorder) -> Tuple[float, float]:
+    def _train_iter(self, count: int, recorder) -> Tuple[float, float]:
         if self.train_fn is None:
             self.compile_train()
         if self._train_it is None:

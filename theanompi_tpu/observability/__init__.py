@@ -7,7 +7,15 @@ three primitives:
 
 - ``trace``   — thread-safe span tracer with Chrome-trace/Perfetto
   export (``with span("prefill", slot=i): ...``); no-op when disabled,
-  so instrumentation lives in hot loops permanently.
+  so instrumentation lives in hot loops permanently.  One level is
+  always on: **boundary spans** (``span(name, boundary=True)``: a
+  scheduler tick and its dispatches, a training step and its phases —
+  ``BOUNDARY_SPANS``) are recorded with tracing off, carry ``id`` and
+  ``parent``, feed the flight rings, and open a
+  ``jax.profiler.TraceAnnotation`` once a jax-importing module has
+  installed the hook, so ``jax.profiler.trace`` alone puts the
+  program's spans into a profile.  ``get_tracer().boundary_spans(since,
+  until)`` returns them on ``time.perf_counter``.
 - ``metrics`` — a registry of labeled counters / gauges / fixed-bucket
   histograms with atomic snapshot, JSON and Prometheus-text exposition.
 - ``flight``  — per-thread ring buffers of recent spans/events, dumped
@@ -34,8 +42,8 @@ summaries, memory snapshots, restarts — feeds the bus unchanged.
 Pure stdlib: importable without jax on the path (like ``analysis/``) —
 the post-mortem machinery must work when the accelerator stack is the
 thing that died.  Tracing enables via ``enable_tracing()`` or env
-``THEANOMPI_OBS_TRACE=1``; metrics and flight recording are always on
-(bounded, cheap).
+``THEANOMPI_OBS_TRACE=1``; metrics, flight recording and the boundary
+spans are always on (bounded, cheap).
 """
 
 from __future__ import annotations
@@ -59,6 +67,7 @@ from theanompi_tpu.observability.metrics import (
     percentile,
 )
 from theanompi_tpu.observability.trace import (
+    BOUNDARY_SPANS,
     Tracer,
     add_span,
     counter_event,
@@ -68,6 +77,7 @@ from theanompi_tpu.observability.trace import (
     flow_begin,
     flow_end,
     get_tracer,
+    install_annotation_hook,
     instant,
     merge_raw_traces,
     raw_to_chrome,
@@ -84,6 +94,7 @@ from theanompi_tpu.observability.trace import (
 )
 
 __all__ = [
+    "BOUNDARY_SPANS",
     "Counter",
     "FlightRecorder",
     "Gauge",
@@ -108,6 +119,7 @@ __all__ = [
     "get_flight_recorder",
     "get_registry",
     "get_tracer",
+    "install_annotation_hook",
     "instant",
     "merge_raw_traces",
     "percentile",
@@ -126,6 +138,10 @@ __all__ = [
     "traced",
     "worst_requests",
 ]
+
+# boundary spans reach the flight rings with tracing off: a post-mortem
+# holds the last ticks or steps, not events alone
+get_tracer().span_sinks.append(get_flight_recorder().record_span)
 
 _EVENTS = get_registry().counter(
     "events_total", "structured events through the observability bus"

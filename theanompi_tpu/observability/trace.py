@@ -12,13 +12,39 @@ Contracts:
 - **Pure stdlib** — importable with no jax on the path (like
   ``analysis/``): the crashed-worker post-mortem path must never
   depend on the library that crashed.
-- **Disabled is a no-op** — ``span()`` with tracing off returns a
-  shared singleton whose enter/exit do nothing, so instrumentation
-  stays in hot loops permanently (tier-1 guards the per-span cost;
-  tests/test_observability.py::test_disabled_span_overhead).
+- **Disabled records boundary spans only** — ``span()`` with tracing
+  off returns a shared singleton whose enter/exit do nothing, so
+  instrumentation stays in hot loops permanently (tier-1 guards the
+  per-span cost; tests/test_observability.py::test_disabled_span_overhead).
+  The one exception is the **boundary level**: ``span(name,
+  boundary=True)`` is recorded whether or not tracing is enabled.
+  Boundary spans sit where one layer hands work to the next (a
+  scheduler tick and its dispatches, a training step and its phases:
+  see ``BOUNDARY_SPANS``) — a handful per tick or step, never one per
+  lane, block or request.  Each costs one
+  small dict, one lock and one ``deque.append`` (a few microseconds;
+  tests/test_observability_boundary.py holds it under 20 µs) against
+  ticks and steps of tens of milliseconds.
 - **Monotonic clocks** — timestamps come from ``time.perf_counter``
   (never wall clock), relative to the tracer's epoch, so spans across
   threads order correctly and NTP steps can't fold a trace.
+  ``boundary_spans()`` hands them back on ``perf_counter`` itself, the
+  clock a driver around the program reads, so a window's spans are
+  selected by time.
+- **Two clocks for a boundary span** — besides the buffer's
+  ``perf_counter`` record, a boundary span opens whatever the
+  *annotation hook* makes for its duration: the first jax-importing
+  module of the program installs ``jax.profiler.TraceAnnotation``
+  (``install_annotation_hook``; this module never imports jax), so in
+  any profile taken with ``jax.profiler.trace`` the program's spans lie
+  in the host plane, on the profile's own clock, above the device
+  operations.  With no profiler session the annotation is a no-op in
+  C++.  The tracer also keeps the pair (``time.time_ns()``, ``clock()``)
+  read once at its creation: ``wall_offset_ns`` added to a
+  ``perf_counter`` reading in nanoseconds gives Unix nanoseconds.
+- **Parents** — a boundary span carries ``id`` and ``parent`` (the
+  boundary span open on the same thread when it began; a thread-local
+  stack), so a layer's self time is its duration minus its children's.
 - **Bounded buffer** — a ``deque(maxlen=...)`` of finished spans; a
   week-long run keeps the newest window instead of OOMing the host.
 - **Track ids** — ``pid`` is the worker/process track (defaults to
@@ -57,6 +83,7 @@ Contracts:
 
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
@@ -65,6 +92,37 @@ from functools import wraps
 from typing import Any, Callable, Dict, List, Optional
 
 DEFAULT_BUFFER = 100_000
+
+# The boundary spans of the program, by layer (docs/observability.md has
+# the arguments each carries and the metric that reads it).
+BOUNDARY_SPANS = (
+    # serving: ContinuousBatchingScheduler.step and what it calls
+    "tick", "admit", "prefill", "prefill_chunk_dispatch", "decode_step",
+    "spec_verify", "pick",
+    # training: TpuModel.train_iter, the Recorder's phases, the print
+    "train_iter", "wait", "calc", "print",
+)
+
+# ---- the boundary level's per-thread parent stack and annotation hook ----
+_IDS = itertools.count(1)
+_LOCAL = threading.local()
+_ANNOTATE: Optional[Callable[..., Any]] = None
+
+
+def _stack() -> list:
+    try:
+        return _LOCAL.stack
+    except AttributeError:
+        _LOCAL.stack = []
+        return _LOCAL.stack
+
+
+def install_annotation_hook(factory: Optional[Callable[..., Any]]) -> None:
+    """``factory(name, **args)`` returns a context manager that a boundary
+    span holds open for its duration.  The program's jax-importing
+    modules pass ``jax.profiler.TraceAnnotation``; ``None`` removes it."""
+    global _ANNOTATE
+    _ANNOTATE = factory
 
 
 class _NoopSpan:
@@ -80,6 +138,9 @@ class _NoopSpan:
         return False
 
     def set(self, **args) -> None:
+        pass
+
+    def cancel(self) -> None:
         pass
 
 
@@ -108,6 +169,50 @@ class _Span:
         t.add_span(self._name, self._t0, t.clock(), self._args or None)
         return False
 
+    def cancel(self) -> None:
+        """Leave the span without recording it (a phase that was started
+        again before it was ended)."""
+
+
+class _BoundarySpan(_Span):
+    """A span of the boundary level: recorded with tracing off, knows
+    its parent, and holds the annotation hook's context open."""
+
+    __slots__ = ("_id", "_parent", "_ann")
+
+    def __enter__(self):
+        stack = _stack()
+        self._parent = stack[-1] if stack else None
+        self._id = next(_IDS)
+        stack.append(self._id)
+        hook = _ANNOTATE
+        if hook is None:
+            self._ann = None
+        else:
+            self._ann = hook(self._name, **self._args)
+            self._ann.__enter__()
+        self._t0 = self._tracer.clock()
+        return self
+
+    def _close(self, exc=(None, None, None)) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = _stack()
+        if self._id in stack:
+            # children left open by an exception go with their parent
+            del stack[stack.index(self._id):]
+
+    def __exit__(self, *exc):
+        t = self._tracer
+        end = t.clock()
+        self._close(exc)
+        t._record(self._name, self._t0, end, self._args or None,
+                  self._id, self._parent)
+        return False
+
+    def cancel(self) -> None:
+        self._close()
+
 
 class Tracer:
     """Thread-safe span collector with Chrome-trace export.
@@ -135,6 +240,8 @@ class Tracer:
         self._lock = threading.Lock()
         self._buf: deque = deque(maxlen=int(buffer))
         self._epoch = clock()
+        # one reading of both clocks: perf_counter (ns) + this = Unix ns
+        self.wall_offset_ns = time.time_ns() - int(self._epoch * 1e9)
         # thread ident -> (small tid, thread name at registration)
         self._tracks: Dict[int, tuple] = {}
         self.dropped = 0  # events evicted by the bound (visible, not silent)
@@ -456,9 +563,11 @@ class Tracer:
         args: Optional[dict] = None,
     ) -> None:
         """Record a completed span from explicit ``clock()`` timestamps
-        — the path ``Recorder.end`` uses (it already holds t0/dt)."""
-        if not self.enabled:
-            return
+        (a no-op while tracing is disabled)."""
+        if self.enabled:
+            self._record(name, start, end, args)
+
+    def _record(self, name, start, end, args, span_id=None, parent=None):
         ev = {
             "ph": "X",
             "name": name,
@@ -468,13 +577,16 @@ class Tracer:
         }
         if args:
             ev["args"] = args
+        if span_id is not None:
+            ev["id"] = span_id
+            ev["parent"] = parent
         with self._lock:
             tid = ev["tid"] = self._track_locked()
             if self._req_tracking:
                 # request buffers fill BEFORE the sampling drop: a
                 # tail-retained request's story must never be holey
                 self._route_request_locked(ev)
-            if self.sample_rate > 1:
+            if self.sample_rate > 1 and span_id is None:
                 seq = self._span_seq.get(tid, 0)
                 self._span_seq[tid] = seq + 1
                 if seq % self.sample_rate:
@@ -578,8 +690,11 @@ class Tracer:
         }
         self._point_event(ev, {**series, "value": float(value)})
 
-    def span(self, name: str, **args):
-        """Context manager measuring a region; no-op when disabled."""
+    def span(self, name: str, boundary: bool = False, **args):
+        """Context manager measuring a region; with tracing disabled a
+        no-op unless ``boundary``."""
+        if boundary:
+            return _BoundarySpan(self, name, args)
         if not self.enabled:
             return _NOOP
         return _Span(self, name, args)
@@ -588,6 +703,31 @@ class Tracer:
     def snapshot(self) -> List[dict]:
         with self._lock:
             return list(self._buf)
+
+    def boundary_spans(
+        self, since: Optional[float] = None, until: Optional[float] = None
+    ) -> List[dict]:
+        """The buffered boundary spans whose START lies in ``[since,
+        until)``, oldest first, with ``start``/``end`` in seconds on the
+        tracer's own clock (``perf_counter``: what a driver around the
+        program reads), ``id``, ``parent``, ``tid`` and ``args``."""
+        with self._lock:
+            events = [ev for ev in self._buf
+                      if ev["ph"] == "X" and "parent" in ev]
+            epoch = self._epoch
+        out = []
+        for ev in events:
+            start = epoch + ev["ts"] / 1e6
+            if (since is not None and start < since) or (
+                    until is not None and start >= until):
+                continue
+            out.append({
+                "name": ev["name"], "start": start,
+                "end": start + ev["dur"] / 1e6, "id": ev["id"],
+                "parent": ev["parent"], "tid": ev["tid"],
+                "args": ev.get("args") or {},
+            })
+        return out
 
     def _meta_events(self) -> List[dict]:
         out = []
@@ -650,6 +790,9 @@ class Tracer:
             "process_name": self.process_name,
             "tracks": {str(tid): name for tid, name in tracks},
             "dropped": self.dropped,
+            # Unix nanoseconds of ts == 0: puts this file's events on a
+            # profile's clock
+            "epoch_unix_ns": int(self._epoch * 1e9) + self.wall_offset_ns,
         }
         if self.sample_rate > 1:
             header["sample_rate"] = self.sample_rate
@@ -902,10 +1045,13 @@ def get_tracer() -> Tracer:
     return _TRACER
 
 
-def span(name: str, **args):
+def span(name: str, boundary: bool = False, **args):
     """``with span("prefill", slot=i): ...`` — the one-line hot-path
-    instrumentation idiom.  Returns the shared no-op when disabled."""
+    instrumentation idiom.  Returns the shared no-op when disabled,
+    unless ``boundary`` (module docstring: the boundary level)."""
     t = _TRACER
+    if boundary:
+        return _BoundarySpan(t, name, args)
     if not t.enabled:
         return _NOOP
     return _Span(t, name, args)

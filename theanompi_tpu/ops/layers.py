@@ -92,6 +92,15 @@ def normal_init(std):
 class Layer:
     """Descriptor base. Subclasses override init/apply."""
 
+    _scope: Optional[str] = None  # see ``named``
+
+    def named(self, name: str) -> "Layer":
+        """Name this layer's operations in a profile: ``Sequential``
+        applies it under ``jax.named_scope(name)`` (metadata only; the
+        backward pass inherits it).  Returns ``self`` for chaining."""
+        self._scope = name
+        return self
+
     def init(self, key, in_shape: Shape):
         return {}, {}, in_shape
 
@@ -746,7 +755,10 @@ class Sequential(Layer):
             sub = None
             if rng is not None:
                 rng, sub = jax.random.split(rng)
-            x, s = layer.apply(params[i], state[i], x, train=train, rng=sub)
+            scope = layer._scope or f"{type(layer).__name__.lower()}{i}"
+            with jax.named_scope(scope):
+                x, s = layer.apply(
+                    params[i], state[i], x, train=train, rng=sub)
             new_state.append(s)
         return x, new_state
 

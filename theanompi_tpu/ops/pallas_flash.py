@@ -152,6 +152,7 @@ def _flash_forward(q, k, v, causal, scale, precision=None):
             pl.BlockSpec((1, bq, 1), lambda bh, qi: (bh, qi, 0)),
         ),
         interpret=not platform.on_tpu(),
+        name="flash_attn_fwd",
     )(qr, kr, vr)
     return out, lse[..., 0]  # both in (B*H, ...) layout
 
@@ -284,6 +285,7 @@ def flash_backward_rows(qr, kr, vr, do, lse, delta, causal, scale,
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda bhi, qi: (bhi, qi, 0)),
         interpret=not platform.on_tpu(),
+        name="flash_attn_bwd_dq",
     )(qr, kr, vr, do, lse3, dlt3)
 
     dk, dv = pl.pallas_call(
@@ -309,6 +311,7 @@ def flash_backward_rows(qr, kr, vr, do, lse, delta, causal, scale,
             pl.BlockSpec((1, bk, d), lambda bhi, kc: (bhi, kc, 0)),
         ),
         interpret=not platform.on_tpu(),
+        name="flash_attn_bwd_dkv",
     )(qr, kr, vr, do, lse3, dlt3)
     return dq, dk, dv
 

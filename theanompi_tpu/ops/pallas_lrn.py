@@ -88,8 +88,9 @@ def _bwd_kernel(x_ref, dy_ref, dx_ref, *, size, alpha, beta, k):
     dx_ref[...] = dx.astype(dx_ref.dtype)
 
 
-def _rowblock_call(kernel, out_dtype, size, alpha, beta, k, *arrays):
-    """Run a (rows, C)-blocked kernel over flattened (M, C) activations."""
+def _rowblock_call(name, kernel, out_dtype, size, alpha, beta, k, *arrays):
+    """Run a (rows, C)-blocked kernel over flattened (M, C) activations;
+    ``name`` is the kernel's name in a profile."""
     x = arrays[0]
     c = x.shape[-1]
     m = x.size // c
@@ -106,6 +107,7 @@ def _rowblock_call(kernel, out_dtype, size, alpha, beta, k, *arrays):
         in_specs=[spec] * len(flats),
         out_specs=spec,
         interpret=not platform.on_tpu(),
+        name=name,
     )(*flats)
     return out[:m].reshape(x.shape)
 
@@ -113,7 +115,7 @@ def _rowblock_call(kernel, out_dtype, size, alpha, beta, k, *arrays):
 @partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
 def lrn(x, size=5, alpha=1e-4, beta=0.75, k=1.0):
     """Fused cross-channel LRN over the last axis of ``x`` (NHWC)."""
-    return _rowblock_call(_fwd_kernel, x.dtype, size, alpha, beta, k, x)
+    return _rowblock_call("lrn_fwd", _fwd_kernel, x.dtype, size, alpha, beta, k, x)
 
 
 def _lrn_fwd(x, size, alpha, beta, k):
@@ -121,7 +123,7 @@ def _lrn_fwd(x, size, alpha, beta, k):
 
 
 def _lrn_bwd(size, alpha, beta, k, x, dy):
-    return (_rowblock_call(_bwd_kernel, x.dtype, size, alpha, beta, k, x, dy),)
+    return (_rowblock_call("lrn_bwd", _bwd_kernel, x.dtype, size, alpha, beta, k, x, dy),)
 
 
 lrn.defvjp(_lrn_fwd, _lrn_bwd)

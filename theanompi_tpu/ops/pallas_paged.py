@@ -236,6 +236,8 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, hd), jnp.float32),
         interpret=(not platform.on_tpu()) if interpret is None else interpret,
+        # the device operation's name in a profile starts with this
+        name="paged_decode_attn_i8" if quant else "paged_decode_attn",
     )(
         jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
         *args,

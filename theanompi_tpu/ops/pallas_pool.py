@@ -138,6 +138,7 @@ def maxpool_bwd(x, y, dy, window, stride) -> jnp.ndarray:
             pltpu.VMEM((nb, h, w, cb), jnp.float32),  # dx accumulator
         ],
         interpret=not platform.on_tpu(),
+        name="maxpool_bwd",
     )(x, y, dy)
     return out[:n, :, :, :c]
 

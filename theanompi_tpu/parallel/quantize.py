@@ -258,6 +258,7 @@ def _run_quant_kernel(x, kernel, out_dtype, seed=None):
             pl.BlockSpec((_ROWS, 1), lambda i: (i, 0)),
         ),
         interpret=not platform.on_tpu(),
+        name=kernel.__name__.lstrip("_"),  # quant_kernel, quant_sr_kernel, ...
     )(*args)
     return q2.reshape(*lead, BLOCK), s2.reshape(lead)
 
@@ -310,5 +311,6 @@ def pallas_dequantize_blocks(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
         ],
         out_specs=pl.BlockSpec((_ROWS, BLOCK), lambda i: (i, 0)),
         interpret=not platform.on_tpu(),
+        name=kernel.__name__.lstrip("_"),  # dequant_kernel, dequant_fp16_kernel
     )(q2, s2)
     return o2.reshape(*lead, BLOCK)

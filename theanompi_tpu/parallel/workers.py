@@ -292,8 +292,7 @@ class BSP_Worker:
                 model.reset_train_iter(epoch)
                 for _ in range(model.data.n_batch_train):
                     count += 1
-                    with obs.span("train_iter", iter=count):
-                        model.train_iter(count, rec)
+                    model.train_iter(count, rec)
                     _ITERS.inc(rule="bsp")
                     rec.print_train_info(count)
                     if self._watchdog is not None:

@@ -26,6 +26,9 @@ from typing import Dict, List, Optional
 from theanompi_tpu import observability as _obs
 
 PHASES = ("calc", "comm", "wait", "load")
+# the phases recorded as boundary spans (always on, children of the
+# model's `train_iter` span); `comm` and `load` stay ordinary spans
+BOUNDARY_PHASES = ("calc", "wait")
 
 
 class Recorder:
@@ -59,6 +62,7 @@ class Recorder:
                 )
 
         self._t0: Dict[str, float] = {}
+        self._spans: Dict[str, object] = {}  # phase -> its open trace span
         # accumulated seconds per phase since last print
         self._acc: Dict[str, float] = {p: 0.0 for p in PHASES}
         # full history rows for offline plotting (reference dumps a record
@@ -80,18 +84,24 @@ class Recorder:
 
     # ---- timing segments ------------------------------------------------
     def start(self, what: str = "calc") -> None:
+        # every start/end pair is also a trace span — the phase columns
+        # become a timeline for free.  `calc` and `wait` are boundary
+        # spans (recorded with tracing off, and annotations in a
+        # profile); the others are no-ops unless tracing is enabled.
+        stale = self._spans.pop(what, None)
+        if stale is not None:
+            stale.cancel()  # started again before it was ended
+        span = _obs.span(what, boundary=what in BOUNDARY_PHASES)
+        self._spans[what] = span.__enter__()
         self._t0[what] = time.perf_counter()
 
     def end(self, what: str = "calc") -> float:
         t0 = self._t0.pop(what, None)
         if t0 is None:
             return 0.0
-        now = time.perf_counter()
-        dt = now - t0
+        dt = time.perf_counter() - t0
         self._acc[what] = self._acc.get(what, 0.0) + dt
-        # every start/end pair is also a trace span (no-op when tracing
-        # is off) — the phase columns become a timeline for free
-        _obs.add_span(what, t0, now)
+        self._spans.pop(what).__exit__(None, None, None)
         return dt
 
     # ---- epoch ----------------------------------------------------------
@@ -154,6 +164,12 @@ class Recorder:
         self._train_n += 1
 
     def print_train_info(self, count: int, force: bool = False) -> None:
+        # boundary span: at a print boundary this is the one sync of the
+        # window (`float` of the accumulated cost), else next to nothing
+        with _obs.span("print", boundary=True):
+            self._print_train_info(count, force)
+
+    def _print_train_info(self, count: int, force: bool) -> None:
         if (count % self.print_freq != 0 and not force) or self._train_n == 0:
             return
         n = self._train_n

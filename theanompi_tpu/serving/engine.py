@@ -48,6 +48,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from theanompi_tpu import observability as obs
 from theanompi_tpu.runtime.mesh import DATA_AXIS, TP_AXIS
 
+# the tracer imports no jax: the program's jax-importing modules hand it
+# the profiler's annotation, so boundary spans show in any profile
+obs.install_annotation_hook(jax.profiler.TraceAnnotation)
+
 _NEG_INF = -1e30  # same finite mask value as parallel.ring_attention
 
 _PREFILLS = obs.get_registry().counter(
@@ -264,7 +268,8 @@ class ServingEngine:
     def _proj(self, x, w):
         if self.compute_dtype is not None:
             x = x.astype(self.compute_dtype)
-            w = w.astype(self.compute_dtype)
+            with jax.named_scope("cast_weights"):
+                w = w.astype(self.compute_dtype)
         y = jnp.dot(x, w, preferred_element_type=jnp.float32)
         if self.compute_dtype is not None:
             y = y.astype(self.compute_dtype)
@@ -274,8 +279,9 @@ class ServingEngine:
         w1, w2 = bp["mlp_in"]["w"], bp["mlp_out"]["w"]
         if self.compute_dtype is not None:
             x = x.astype(self.compute_dtype)
-            w1 = w1.astype(self.compute_dtype)
-            w2 = w2.astype(self.compute_dtype)
+            with jax.named_scope("cast_weights"):
+                w1 = w1.astype(self.compute_dtype)
+                w2 = w2.astype(self.compute_dtype)
         h = jnp.dot(x, w1, preferred_element_type=jnp.float32)
         h = jax.nn.gelu(h + bp["mlp_in"]["b"])
         if self.compute_dtype is not None:
@@ -296,7 +302,8 @@ class ServingEngine:
         w = head["w"]
         if self.compute_dtype is not None:
             x = x.astype(self.compute_dtype)
-            w = w.astype(self.compute_dtype)
+            with jax.named_scope("cast_weights"):
+                w = w.astype(self.compute_dtype)
         y = jnp.dot(x, w, preferred_element_type=jnp.float32)
         return y.astype(jnp.float32) + head["b"]
 

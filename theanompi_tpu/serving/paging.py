@@ -163,9 +163,8 @@ class BlockPool:
         if r < 1:
             raise ValueError(f"release of unallocated block {block}")
         if r == 1:
-            with obs.span("block_free", block=block):
-                del self._ref[block]
-                self._free.append(block)
+            del self._ref[block]
+            self._free.append(block)
             self._publish()
         else:
             self._ref[block] = r - 1
@@ -558,9 +557,10 @@ class PagedServingEngine(ServingEngine):
         bs = self.block_size
         h, hd = self.n_heads, self.head_dim
         positions = p0[:, None] + jnp.arange(c_)[None, :]  # (P, C)
-        x = self._embed(
-            emb, pos, tokens, jnp.minimum(positions, self.max_len - 1)
-        )  # (P, C, D)
+        with jax.named_scope("embed"):
+            x = self._embed(
+                emb, pos, tokens, jnp.minimum(positions, self.max_len - 1)
+            )  # (P, C, D)
         blk_idx = jnp.minimum(positions // bs, self.blocks_per_seq - 1)
         blk = jnp.take_along_axis(tables, blk_idx, axis=1)  # (P, C)
         valid = active[:, None] & (
@@ -580,33 +580,42 @@ class PagedServingEngine(ServingEngine):
             self._kv_compute_dtype() if self.kv_dtype == "int8" else pk.dtype
         )
         new_k, new_v, new_ks, new_vs = [], [], [], []
+        # named scopes are metadata on the same operations: a profile
+        # groups by them (layer<i>/qkv, .../cast_weights inside it, ...)
         for i, bp in enumerate(blocks):
-            y = self._ln(bp["ln1"], x)
-            q = self._proj(y, bp["attn"]["wq"]).reshape(p_, c_, h, hd)
-            k = self._proj(y, bp["attn"]["wk"]).reshape(p_, c_, h, hd)
-            v = self._proj(y, bp["attn"]["wv"]).reshape(p_, c_, h, hd)
-            pk_l, pks_l = self._kv_write(
-                pk[i], None if pks is None else pks[i],
-                k.reshape(p_ * c_, h, hd), wr,
-            )
-            pv_l, pvs_l = self._kv_write(
-                pv[i], None if pvs is None else pvs[i],
-                v.reshape(p_ * c_, h, hd), wr,
-            )
-            kc = self._kv_image(pk_l, pks_l, gr, p_, img_dt)
-            vc = self._kv_image(pv_l, pvs_l, gr, p_, img_dt)
-            s = jnp.einsum(
-                "pchd,pthd->phct", q, kc,
-                preferred_element_type=jnp.float32,
-            ) * self.scale
-            s = jnp.where(mask[:, None, :, :], s, _NEG_INF)
-            prob = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum(
-                "phct,pthd->pchd", prob.astype(vc.dtype), vc,
-                preferred_element_type=jnp.float32,
-            ).astype(y.dtype)
-            x = x + self._proj(o.reshape(p_, c_, h * hd), bp["attn"]["wo"])
-            x = x + self._mlp(bp, self._ln(bp["ln2"], x))
+            with jax.named_scope(f"layer{i}"):
+                with jax.named_scope("qkv"):
+                    y = self._ln(bp["ln1"], x)
+                    q = self._proj(y, bp["attn"]["wq"]).reshape(p_, c_, h, hd)
+                    k = self._proj(y, bp["attn"]["wk"]).reshape(p_, c_, h, hd)
+                    v = self._proj(y, bp["attn"]["wv"]).reshape(p_, c_, h, hd)
+                with jax.named_scope("pool_update"):
+                    pk_l, pks_l = self._kv_write(
+                        pk[i], None if pks is None else pks[i],
+                        k.reshape(p_ * c_, h, hd), wr,
+                    )
+                    pv_l, pvs_l = self._kv_write(
+                        pv[i], None if pvs is None else pvs[i],
+                        v.reshape(p_ * c_, h, hd), wr,
+                    )
+                with jax.named_scope("paged_attn"):
+                    kc = self._kv_image(pk_l, pks_l, gr, p_, img_dt)
+                    vc = self._kv_image(pv_l, pvs_l, gr, p_, img_dt)
+                    s = jnp.einsum(
+                        "pchd,pthd->phct", q, kc,
+                        preferred_element_type=jnp.float32,
+                    ) * self.scale
+                    s = jnp.where(mask[:, None, :, :], s, _NEG_INF)
+                    prob = jax.nn.softmax(s, axis=-1)
+                    o = jnp.einsum(
+                        "phct,pthd->pchd", prob.astype(vc.dtype), vc,
+                        preferred_element_type=jnp.float32,
+                    ).astype(y.dtype)
+                with jax.named_scope("attn_out"):
+                    x = x + self._proj(
+                        o.reshape(p_, c_, h * hd), bp["attn"]["wo"])
+                with jax.named_scope("mlp"):
+                    x = x + self._mlp(bp, self._ln(bp["ln2"], x))
             new_k.append(pk_l)
             new_v.append(pv_l)
             new_ks.append(pks_l)
@@ -615,13 +624,14 @@ class PagedServingEngine(ServingEngine):
         if self.kv_dtype == "int8":
             out["ks"] = jnp.stack(new_ks)
             out["vs"] = jnp.stack(new_vs)
-        if all_logits:
-            logits = self._head(lnf, head, x)  # (P, C, V)
-        else:
-            last = jnp.take_along_axis(
-                x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
-            )[:, 0]  # (P, D)
-            logits = self._head(lnf, head, last)
+        with jax.named_scope("head"):
+            if all_logits:
+                logits = self._head(lnf, head, x)  # (P, C, V)
+            else:
+                last = jnp.take_along_axis(
+                    x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
+                )[:, 0]  # (P, D)
+                logits = self._head(lnf, head, last)
         return out, logits
 
     def _paged_decode_fn(
@@ -640,9 +650,10 @@ class PagedServingEngine(ServingEngine):
         bs = self.block_size
         h, hd = self.n_heads, self.head_dim
         pos_idx = lengths  # (S,) position of the incoming token
-        x = self._embed(
-            emb, pos, tokens, jnp.minimum(pos_idx, self.max_len - 1)
-        )  # (S, D)
+        with jax.named_scope("embed"):
+            x = self._embed(
+                emb, pos, tokens, jnp.minimum(pos_idx, self.max_len - 1)
+            )  # (S, D)
         blk = jnp.take_along_axis(
             tables,
             jnp.minimum(pos_idx // bs, self.blocks_per_seq - 1)[:, None],
@@ -661,38 +672,45 @@ class PagedServingEngine(ServingEngine):
         if use_pallas:
             from theanompi_tpu.ops import pallas_paged
         new_k, new_v, new_ks, new_vs = [], [], [], []
-        for i, bp in enumerate(blocks):
-            y = self._ln(bp["ln1"], x)
-            q = self._proj(y, bp["attn"]["wq"]).reshape(s_, h, hd)
-            k = self._proj(y, bp["attn"]["wk"]).reshape(s_, h, hd)
-            v = self._proj(y, bp["attn"]["wv"]).reshape(s_, h, hd)
-            pk_l, pks_l = self._kv_write(
-                pk[i], None if pks is None else pks[i], k, wr
-            )
-            pv_l, pvs_l = self._kv_write(
-                pv[i], None if pvs is None else pvs[i], v, wr
-            )
-            if use_pallas:
-                o = pallas_paged.paged_decode_attention(
-                    q, pk_l, pv_l, tables, pos_idx,
-                    block_size=bs, scale=self.scale,
-                    k_scale=pks_l, v_scale=pvs_l,
-                ).astype(y.dtype)
-            else:
-                kc = self._kv_image(pk_l, pks_l, gr, s_, img_dt)
-                vc = self._kv_image(pv_l, pvs_l, gr, s_, img_dt)
-                s = jnp.einsum(
-                    "shd,sthd->sht", q, kc,
-                    preferred_element_type=jnp.float32,
-                ) * self.scale
-                s = jnp.where(att_mask[:, None, :], s, _NEG_INF)
-                prob = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum(
-                    "sht,sthd->shd", prob.astype(vc.dtype), vc,
-                    preferred_element_type=jnp.float32,
-                ).astype(y.dtype)
-            x = x + self._proj(o.reshape(s_, h * hd), bp["attn"]["wo"])
-            x = x + self._mlp(bp, self._ln(bp["ln2"], x))
+        for i, bp in enumerate(blocks):  # scopes as in _paged_chunk_fn
+            with jax.named_scope(f"layer{i}"):
+                with jax.named_scope("qkv"):
+                    y = self._ln(bp["ln1"], x)
+                    q = self._proj(y, bp["attn"]["wq"]).reshape(s_, h, hd)
+                    k = self._proj(y, bp["attn"]["wk"]).reshape(s_, h, hd)
+                    v = self._proj(y, bp["attn"]["wv"]).reshape(s_, h, hd)
+                with jax.named_scope("pool_update"):
+                    pk_l, pks_l = self._kv_write(
+                        pk[i], None if pks is None else pks[i], k, wr
+                    )
+                    pv_l, pvs_l = self._kv_write(
+                        pv[i], None if pvs is None else pvs[i], v, wr
+                    )
+                with jax.named_scope("paged_attn"):
+                    if use_pallas:
+                        o = pallas_paged.paged_decode_attention(
+                            q, pk_l, pv_l, tables, pos_idx,
+                            block_size=bs, scale=self.scale,
+                            k_scale=pks_l, v_scale=pvs_l,
+                        ).astype(y.dtype)
+                    else:
+                        kc = self._kv_image(pk_l, pks_l, gr, s_, img_dt)
+                        vc = self._kv_image(pv_l, pvs_l, gr, s_, img_dt)
+                        s = jnp.einsum(
+                            "shd,sthd->sht", q, kc,
+                            preferred_element_type=jnp.float32,
+                        ) * self.scale
+                        s = jnp.where(att_mask[:, None, :], s, _NEG_INF)
+                        prob = jax.nn.softmax(s, axis=-1)
+                        o = jnp.einsum(
+                            "sht,sthd->shd", prob.astype(vc.dtype), vc,
+                            preferred_element_type=jnp.float32,
+                        ).astype(y.dtype)
+                with jax.named_scope("attn_out"):
+                    x = x + self._proj(
+                        o.reshape(s_, h * hd), bp["attn"]["wo"])
+                with jax.named_scope("mlp"):
+                    x = x + self._mlp(bp, self._ln(bp["ln2"], x))
             new_k.append(pk_l)
             new_v.append(pv_l)
             new_ks.append(pks_l)
@@ -701,7 +719,9 @@ class PagedServingEngine(ServingEngine):
         if self.kv_dtype == "int8":
             out["ks"] = jnp.stack(new_ks)
             out["vs"] = jnp.stack(new_vs)
-        return out, self._head(lnf, head, x)
+        with jax.named_scope("head"):
+            logits = self._head(lnf, head, x)
+        return out, logits
 
     # ------------------------------------------------------------------
     # host entries
@@ -721,21 +741,27 @@ class PagedServingEngine(ServingEngine):
             )
         c = self.pick_chunk_bucket(max(len(r["tokens"]) for r in rows))
         p_ = self.prefill_rows
-        tokens = np.zeros((p_, c), np.int32)
-        tables = np.zeros((p_, self.blocks_per_seq), np.int32)
-        p0 = np.zeros((p_,), np.int32)
-        true_len = np.zeros((p_,), np.int32)
-        active = np.zeros((p_,), bool)
-        for i, r in enumerate(rows):
-            n = len(r["tokens"])
-            tokens[i, :n] = r["tokens"]
-            tables[i, :len(r["table"])] = r["table"]
-            p0[i] = int(r["p0"])
-            true_len[i] = n
-            active[i] = True
-        smetrics.PREFILL_CHUNKS.inc(bucket=str(c))
-        smetrics.PREFILL_TOKENS.inc(int(true_len.sum()))
-        with obs.span("prefill_chunk_dispatch", rows=len(rows), bucket=c):
+        # boundary span: the host arrays and the jitted call; its counts
+        # say how much of what the program computes is padding
+        with obs.span("prefill_chunk_dispatch", boundary=True,
+                      rows=len(rows), bucket=c, rows_computed=p_,
+                      computed_tokens=p_ * c) as span:
+            tokens = np.zeros((p_, c), np.int32)
+            tables = np.zeros((p_, self.blocks_per_seq), np.int32)
+            p0 = np.zeros((p_,), np.int32)
+            true_len = np.zeros((p_,), np.int32)
+            active = np.zeros((p_,), bool)
+            for i, r in enumerate(rows):
+                n = len(r["tokens"])
+                tokens[i, :n] = r["tokens"]
+                tables[i, :len(r["table"])] = r["table"]
+                p0[i] = int(r["p0"])
+                true_len[i] = n
+                active[i] = True
+            useful = int(true_len.sum())
+            span.set(useful_tokens=useful)
+            smetrics.PREFILL_CHUNKS.inc(bucket=str(c))
+            smetrics.PREFILL_TOKENS.inc(useful)
             state, logits = self._paged_prefill_jit(
                 params, state,
                 host_input(tokens), host_input(tables),

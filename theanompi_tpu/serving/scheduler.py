@@ -190,6 +190,7 @@ class ContinuousBatchingScheduler:
         # on pool backpressure, and rids finished this tick — their
         # buffers close at the END of step() so the tick's phase spans
         # land inside them first
+        self._n_ticks = 0  # the `tick` boundary span's number
         self._req_enq: Dict[str, float] = {}
         self._bp_since: Optional[float] = None
         self._req_done: List[tuple] = []
@@ -403,6 +404,12 @@ class ContinuousBatchingScheduler:
         path needs — one dispatch picks a request's NEXT ``k+1`` tokens
         at indices ``len(output) + [0, k]``, each with the exact key the
         non-speculative path would have used at that index."""
+        # boundary span: the wait for the program that made the
+        # logits, the transfer to the host and the draw
+        with obs.span("pick", boundary=True, rows=len(picks)):
+            return self._pick_tokens_now(picks, logits)
+
+    def _pick_tokens_now(self, picks, logits):
         import jax.numpy as jnp
 
         if not any(p is not None and p[0].temperature > 0.0 for p in picks):
@@ -516,7 +523,8 @@ class ContinuousBatchingScheduler:
                     slot.request.output[-1] if self._active[i] else 0
                 )
             was_active = self._active.copy()
-            with obs.span("decode_step", active=int(was_active.sum())):
+            with obs.span("decode_step", boundary=True,
+                          active=int(was_active.sum())):
                 self.cache, logits = self.engine.decode_step(
                     self.params, self.cache, self._tokens, self._active
                 )
@@ -550,6 +558,18 @@ class ContinuousBatchingScheduler:
         (after evicting idle cached prefixes) defers admission to a
         later tick — backpressure, never a crash — and preserves FIFO
         (nothing behind the stuck head jumps the queue)."""
+        if not self.queue:
+            return
+        with obs.span("admit", boundary=True) as span:
+            queued = len(self.queue)
+            refused = self.stats["backpressure_events"]
+            self._admit_free_slots()
+            span.set(
+                admitted=queued - len(self.queue),
+                refused=self.stats["backpressure_events"] - refused,
+            )
+
+    def _admit_free_slots(self) -> None:
         for i, slot in enumerate(self.slots):
             if slot.request is not None or not self.queue:
                 continue
@@ -607,6 +627,12 @@ class ContinuousBatchingScheduler:
         ][: self.engine.prefill_rows]
         if not pending:
             return 0
+        # boundary span over the whole prefill half of the tick: row
+        # prep, the dispatch (its own span inside), the pick, the emit
+        with obs.span("prefill", boundary=True, rows=len(pending)) as span:
+            return self._prefill_pending(pending, span)
+
+    def _prefill_pending(self, pending: List[int], span) -> int:
         track = obs.request_tracking_active()
         if track:
             # rids up front: a lane that completes AND finishes this
@@ -625,11 +651,10 @@ class ContinuousBatchingScheduler:
             rows.append({
                 "tokens": chunk, "p0": s.n_fed, "table": s.blocks,
             })
-        with obs.span("prefill", rows=len(rows),
-                      n_tokens=sum(len(r["tokens"]) for r in rows)):
-            self.state, logits = self.engine.prefill_chunks(
-                self.params, self.state, rows
-            )
+        span.set(n_tokens=sum(len(r["tokens"]) for r in rows))
+        self.state, logits = self.engine.prefill_chunks(
+            self.params, self.state, rows
+        )
         self.stats["prefill_chunks"] += 1
         produced = 0
         completing: List[int] = []
@@ -689,7 +714,8 @@ class ContinuousBatchingScheduler:
             self._tokens[i] = (
                 slot.request.output[-1] if decoding[i] else 0
             )
-        with obs.span("decode_step", active=int(decoding.sum())):
+        with obs.span("decode_step", boundary=True,
+                      active=int(decoding.sum())):
             self.state, logits = self.engine.decode_step_paged(
                 self.params, self.state, self._tokens,
                 self._tables, self._lengths, decoding,
@@ -768,7 +794,8 @@ class ContinuousBatchingScheduler:
             tokens[i, 0] = last[i]
             tokens[i, 1:1 + k_eff[i]] = props[i, :k_eff[i]]
             true_len[i] = k_eff[i] + 1
-        with obs.span("spec_verify", active=int(decoding.sum()),
+        with obs.span("spec_verify", boundary=True,
+                      active=int(decoding.sum()),
                       proposed=int(k_eff.sum())):
             self.state, logits = self.engine.verify_chunks(
                 self.params, self.state, tokens, self._tables, p0,
@@ -842,6 +869,17 @@ class ContinuousBatchingScheduler:
     def step(self) -> int:
         """One tick: admissions, (paged) chunked prefill, then one
         decode step.  Returns the number of tokens generated."""
+        self._n_ticks += 1
+        # boundary span over the whole tick; its children are `admit`,
+        # `prefill`, `decode_step`/`spec_verify` and `pick`, so its self
+        # time is the Python between the programs
+        with obs.span("tick", boundary=True, n=self._n_ticks,
+                      active=self.n_active, queued=len(self.queue)) as span:
+            produced = self._tick()
+            span.set(produced=produced)
+        return produced
+
+    def _tick(self) -> int:
         track = obs.request_tracking_active()
         if track:
             # whole-tick phase accounting: a decoding lane spends real
