@@ -8,6 +8,7 @@ training step and its phases.  Device kernels carry stable names.
 """
 
 import ast
+import math
 import os
 import subprocess
 import sys
@@ -271,11 +272,17 @@ def test_recorder_phases_are_boundary_children_of_train_iter(tracing_off):
     assert by["print"]["parent"] is None
 
 
-def test_paged_tick_spans_nest_and_count_the_padding(tracing_off):
+@pytest.mark.parametrize("n_slots, lengths", [
+    (3, (5, 21, 9, 13)),
+    (6, (5, 21, 9, 13, 7, 30, 11)),  # more lanes than the program has rows
+])
+def test_paged_tick_spans_nest_and_count_the_padding(tracing_off, n_slots,
+                                                     lengths):
     """One tiny paged run: ``tick`` > ``prefill`` >
     ``prefill_chunk_dispatch``; the dispatch spans' ``useful_tokens`` sum
-    to the scheduler's own ``prefill_tokens`` and ``computed_tokens`` is
-    ``prefill_rows x bucket``."""
+    to the scheduler's own ``prefill_tokens``, ``computed_tokens`` is
+    ``prefill_rows x bucket`` (the program's narrow width, not the
+    lanes'), and a ``prefill`` span says how many calls it made."""
     import jax
 
     from theanompi_tpu.models.transformer import TransformerLM
@@ -288,10 +295,14 @@ def test_paged_tick_spans_nest_and_count_the_padding(tracing_off):
                batch_size=2, n_synth_train=2, n_synth_val=1, comm_probe=False,
                print_freq=10_000)
     model = TransformerLM(config=cfg, mesh=make_mesh(devices=jax.devices()[:1]))
-    engine = PagedServingEngine(model, n_slots=3, max_len=64, block_size=8,
-                                buckets=(8, 16, 64), prefill_chunk=16)
+    from theanompi_tpu.serving.paging import PREFILL_ROWS
+
+    engine = PagedServingEngine(model, n_slots=n_slots, max_len=64,
+                                block_size=8, buckets=(8, 16, 64),
+                                prefill_chunk=16)
+    assert engine.prefill_rows == min(n_slots, PREFILL_ROWS)
     sched = ContinuousBatchingScheduler(engine)
-    for i, n in enumerate((5, 21, 9, 13)):
+    for i, n in enumerate(lengths):
         sched.submit(Request(id=f"r{i}", prompt=list(range(1, n + 1)),
                              max_new_tokens=4))
     tracing_off.clear()
@@ -304,7 +315,7 @@ def test_paged_tick_spans_nest_and_count_the_padding(tracing_off):
     tick_spans = [s for s in spans if s["name"] == "tick"]
     assert len(tick_spans) == ticks
     assert [s["args"]["n"] for s in tick_spans] == list(range(1, ticks + 1))
-    assert sum(s["args"]["produced"] for s in tick_spans) == 4 * 4
+    assert sum(s["args"]["produced"] for s in tick_spans) == 4 * len(lengths)
     dispatches = [s for s in spans if s["name"] == "prefill_chunk_dispatch"]
     assert len(dispatches) == sched.stats["prefill_chunks"] > 0
     for d in dispatches:
@@ -321,6 +332,13 @@ def test_paged_tick_spans_nest_and_count_the_padding(tracing_off):
     prefills = [s for s in spans if s["name"] == "prefill"]
     assert (sum(p["args"]["n_tokens"] for p in prefills)
             == sched.stats["prefill_tokens"])
+    for p in prefills:
+        mine = [d for d in dispatches if d["parent"] == p["id"]]
+        assert p["args"]["calls"] == len(mine) == math.ceil(
+            p["args"]["rows"] / engine.prefill_rows)
+        assert sum(d["args"]["rows"] for d in mine) == p["args"]["rows"]
+    assert max(p["args"]["calls"] for p in prefills) == math.ceil(
+        n_slots / PREFILL_ROWS)
     # every other child of a tick is one of the boundary names, and a
     # decode's pick hangs under the tick itself
     for s in spans:
@@ -328,7 +346,7 @@ def test_paged_tick_spans_nest_and_count_the_padding(tracing_off):
     picks = [s for s in spans if s["name"] == "pick"]
     assert {by_id[p["parent"]]["name"] for p in picks} == {"tick", "prefill"}
     admits = [s for s in spans if s["name"] == "admit"]
-    assert sum(a["args"]["admitted"] for a in admits) == 4
+    assert sum(a["args"]["admitted"] for a in admits) == len(lengths)
     # self time is never negative and closes: children lie inside
     own = _self_times(spans)
     assert all(v >= -1e-9 for v in own.values())

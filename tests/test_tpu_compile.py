@@ -198,9 +198,10 @@ def test_paged_engine_programs_compile_at_serving_widths(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     s, nb, c = eng.n_slots, eng.blocks_per_seq, eng.chunk_buckets[-1]
+    r = eng.prefill_rows  # the prefill program's own, narrow width
     eng._paged_prefill_jit.lower(
-        params, state, arg(jnp.int32, s, c), arg(jnp.int32, s, nb),
-        arg(jnp.int32, s), arg(jnp.int32, s), arg(jnp.bool_, s),
+        params, state, arg(jnp.int32, r, c), arg(jnp.int32, r, nb),
+        arg(jnp.int32, r), arg(jnp.int32, r), arg(jnp.bool_, r),
     ).compile()
     text = eng._paged_decode_jit.lower(
         params, state, arg(jnp.int32, s), arg(jnp.int32, s, nb),

@@ -33,10 +33,12 @@ Three pieces:
   *data* (device arrays), never as shapes, so admission, retirement
   and table growth cause ZERO recompiles — one decode program ever,
   one prefill program per chunk bucket.  Prefill is **batched and
-  chunked**: up to ``prefill_rows`` sequences advance by up to
-  ``prefill_chunk`` tokens in ONE padded call per tick, so a burst of
-  arrivals shares a dispatch and a giant prompt cannot hide the TTFT
-  of everyone queued behind it.
+  chunked**: the program has a narrow fixed shape (``prefill_rows`` =
+  ``PREFILL_ROWS`` lanes × the chunk bucket) and a tick feeds every
+  lane that holds unfed prompt tokens, up to ``prefill_chunk`` tokens
+  each, in as many calls of that shape as they need — a trickle of
+  arrivals pays for the rows it feeds, and a giant prompt cannot hide
+  the TTFT of everyone queued behind it.
 
 Decode-speed layers on top (ISSUE 11):
 
@@ -93,6 +95,23 @@ from theanompi_tpu.serving.engine import (
 TRASH_BLOCK = 0  # reserved physical block: masked/inactive writes land here
 
 KV_DTYPES = ("fp32", "int8")
+
+# Lanes of the prefill program.  A row is dear (per layer a key and a
+# value image of t_pad positions, float32 scores over them, a scatter
+# of its chunk into the pool), and in steady traffic a tick has one to
+# three lanes to feed, so the program is narrow and a tick with more
+# pending lanes calls it again (``scheduler._prefill_pending``).  One
+# width, not a ladder: every (rows, bucket) pair is a program to build
+# and to warm, and a pair first met under load would compile there.
+# What a burst pays for it is one call's fixed cost (the weights read
+# and cast, the pool's copy) per ``PREFILL_ROWS`` arrivals; that fixed
+# cost is the decode program's too, and is what holding the weights in
+# the compute dtype and updating the pool in place take away, after
+# which eight narrow calls cost what one wide call does.  Read on a
+# v5e with GPT-2 XL (PERF.md, PR 26): a call of 2 / 4 / 8 / 32 rows
+# takes 68 / 75 / 106 / 277 ms beside a decode tick of 74; 2 serves a
+# trickle 2 % faster than 4 and doubles what a burst pays.
+PREFILL_ROWS = 4
 
 
 class BlockPool:
@@ -289,7 +308,10 @@ class PagedServingEngine(ServingEngine):
       exactly what the contiguous one could, and operators shrink it
       (or raise ``n_slots``) to bank the long-tail savings.
     - ``prefill_rows`` — lanes per batched prefill call (fixed shape;
-      default ``n_slots``).
+      default ``min(n_slots, PREFILL_ROWS)``).  Nothing an operator
+      tunes: the scheduler feeds every pending lane each tick in
+      ``ceil(pending / prefill_rows)`` calls, so the width only says
+      what one call costs (see ``PREFILL_ROWS``).
     - ``prefill_chunk`` — max prompt tokens one prefill call advances
       a sequence by (None = whole prompt in one chunk).  Chunks pad to
       the ``chunk_buckets`` ladder, one compiled program per bucket.
@@ -340,7 +362,9 @@ class PagedServingEngine(ServingEngine):
                 "than max_len rows is fine — requests that could never "
                 "fit are refused at submit()"
             )
-        self.prefill_rows = int(prefill_rows or self.n_slots)
+        self.prefill_rows = int(
+            prefill_rows or min(self.n_slots, PREFILL_ROWS)
+        )
         if prefill_chunk is not None:
             prefill_chunk = int(prefill_chunk)
             if prefill_chunk < 1:

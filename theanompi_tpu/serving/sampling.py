@@ -103,22 +103,27 @@ class Sampler:
         )
         return int(out)
 
-    def pick_batch(self, logits, keys, temperatures, top_ks):
+    def draw_batch(self, logits, keys, temperatures, top_ks):
         """One token id per row of ``logits`` (N, V) in a single
         dispatch.  ``keys`` (N, 2) uint32 raw PRNG keys (row ignored
         where temperature is 0), ``temperatures`` (N,) float,
         ``top_ks`` (N,) int.  Rows with temperature 0 are exact argmax
-        — the greedy hot path rides along for free.  Returns a host
-        int array (N,)."""
-        import numpy as np
-
-        out = self._batch_fn(
+        — the greedy hot path rides along for free.  Returns the
+        device int array (N,), not waited for."""
+        return self._batch_fn(
             jnp.asarray(logits),
             host_input(keys, jnp.uint32),
             host_input(temperatures, jnp.float32),
             host_input(top_ks, jnp.int32),
         )
-        return np.asarray(out)
+
+    def pick_batch(self, logits, keys, temperatures, top_ks):
+        """``draw_batch`` fetched: a host int array (N,)."""
+        import numpy as np
+
+        return np.asarray(
+            self.draw_batch(logits, keys, temperatures, top_ks)
+        )
 
 
 def request_key(seed: Optional[int], rid: str, token_index: int):

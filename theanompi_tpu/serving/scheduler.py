@@ -20,12 +20,13 @@ Two engine families drive through the same scheduler:
   cached prefix blocks (refcounted, prefilled once per distinct
   prefix), and defers — clean backpressure, never a crash — when the
   pool is exhausted (after evicting idle cached prefixes).  Prefill
-  is **chunked and batched**: every tick, up to ``prefill_rows``
-  admitted-but-unprefilled lanes advance by up to ``prefill_chunk``
-  prompt tokens in ONE padded dispatch, interleaved with decode ticks
-  so a giant prompt cannot hide the TTFT of requests queued behind
-  it.  Finishing releases the slot's blocks back to the pool — the
-  same join-on-finish recycling, now also reclaiming memory.
+  is **chunked and batched**: every tick, every
+  admitted-but-unprefilled lane advances by up to ``prefill_chunk``
+  prompt tokens, ``prefill_rows`` lanes to a padded dispatch of the
+  engine's narrow prefill program, interleaved with decode ticks so a
+  giant prompt cannot hide the TTFT of requests queued behind it.
+  Finishing releases the slot's blocks back to the pool — the same
+  join-on-finish recycling, now also reclaiming memory.
 
 Determinism contract (tested): every per-slot computation in the engine
 is independent across the slot axis, so a request's output under any
@@ -407,13 +408,15 @@ class ContinuousBatchingScheduler:
         # boundary span: the wait for the program that made the
         # logits, the transfer to the host and the draw
         with obs.span("pick", boundary=True, rows=len(picks)):
-            return self._pick_tokens_now(picks, logits)
+            return np.asarray(self._draw_tokens(picks, logits))
 
-    def _pick_tokens_now(self, picks, logits):
+    def _draw_tokens(self, picks, logits):
+        """The draw of ``_pick_tokens``, enqueued behind the program
+        that makes ``logits`` and not waited for: a device array."""
         import jax.numpy as jnp
 
         if not any(p is not None and p[0].temperature > 0.0 for p in picks):
-            return np.asarray(jnp.argmax(logits, axis=-1))
+            return jnp.argmax(logits, axis=-1)
         if self._sampler is None:
             from theanompi_tpu.serving.sampling import Sampler
 
@@ -433,7 +436,7 @@ class ContinuousBatchingScheduler:
             keys[i] = np.asarray(
                 request_key(r.seed, r.id, r.token_index0 + idx)
             )
-        return self._sampler.pick_batch(logits, keys, temps, topks)
+        return self._sampler.draw_batch(logits, keys, temps, topks)
 
     def _emit(self, i: int, token: int) -> bool:
         """Append one generated token to slot i's request; True when the
@@ -615,20 +618,20 @@ class ContinuousBatchingScheduler:
             _QUEUE.set(len(self.queue))
 
     def _prefill_tick_paged(self) -> int:
-        """ONE batched, length-bucketed prefill dispatch: every lane
-        still holding unfed prompt tokens advances by one chunk (up to
-        ``prefill_rows`` lanes).  A lane whose prompt completes emits
-        its first token this tick; longer prompts resume next tick,
-        interleaved with decode."""
+        """Every lane still holding unfed prompt tokens advances by one
+        chunk, in as many calls of the engine's narrow prefill program
+        (``prefill_rows`` lanes × the group's own bucket) as the lanes
+        need.  A lane whose prompt completes emits its first token this
+        tick; longer prompts resume next tick, interleaved with decode."""
         pending = [
             i for i, s in enumerate(self.slots)
             if s.request is not None
             and s.n_fed < len(s.request.prompt)
-        ][: self.engine.prefill_rows]
+        ]
         if not pending:
             return 0
         # boundary span over the whole prefill half of the tick: row
-        # prep, the dispatch (its own span inside), the pick, the emit
+        # prep, the dispatches (a span each inside), the picks, the emit
         with obs.span("prefill", boundary=True, rows=len(pending)) as span:
             return self._prefill_pending(pending, span)
 
@@ -644,56 +647,69 @@ class ContinuousBatchingScheduler:
             if self.engine.prefill_chunk is not None
             else self.engine.chunk_buckets[-1]
         )
-        rows = []
+        chunks = {}
         for i in pending:
             s = self.slots[i]
-            chunk = s.request.prompt[s.n_fed:s.n_fed + cap]
-            rows.append({
-                "tokens": chunk, "p0": s.n_fed, "table": s.blocks,
-            })
-        span.set(n_tokens=sum(len(r["tokens"]) for r in rows))
-        self.state, logits = self.engine.prefill_chunks(
-            self.params, self.state, rows
-        )
-        self.stats["prefill_chunks"] += 1
-        produced = 0
-        completing: List[int] = []
-        for r_idx, i in enumerate(pending):
-            s = self.slots[i]
-            s.n_fed += len(rows[r_idx]["tokens"])
-            self._lengths[i] = s.n_fed
-            if s.n_fed >= len(s.request.prompt):
-                completing.append(r_idx)
-            self.stats["prefill_tokens"] += len(rows[r_idx]["tokens"])
-        if completing:
-            picks = self._pick_batch(
-                [
-                    self.slots[pending[r_idx]].request
-                    if r_idx in completing else None
-                    for r_idx in range(self.engine.prefill_rows)
-                ],
-                logits,
+            chunks[i] = s.request.prompt[s.n_fed:s.n_fed + cap]
+        n_tokens = sum(len(c) for c in chunks.values())
+        width = self.engine.prefill_rows
+        # every group's call and draw are enqueued before the first
+        # pick waits: the device runs call n+1 while the host emits
+        # call n's first tokens, which wait for their own call alone
+        calls = []  # (the group's lanes, its completing requests, draw)
+        for g in range(0, len(pending), width):
+            lanes = pending[g:g + width]
+            self.state, logits = self.engine.prefill_chunks(
+                self.params, self.state,
+                [{"tokens": chunks[i], "p0": self.slots[i].n_fed,
+                  "table": self.slots[i].blocks} for i in lanes],
             )
-            for r_idx in completing:
-                i = pending[r_idx]
+            reqs = []  # per lane: its request if the prompt completes
+            for i in lanes:
+                s = self.slots[i]
+                s.n_fed += len(chunks[i])
+                self._lengths[i] = s.n_fed
+                reqs.append(
+                    s.request if s.n_fed >= len(s.request.prompt) else None
+                )
+            drawn = None
+            if any(r is not None for r in reqs):
+                drawn = self._draw_tokens(
+                    [(r, len(r.output)) if r is not None else None
+                     for r in reqs + [None] * (width - len(lanes))],
+                    logits,
+                )
+            calls.append((lanes, reqs, drawn))
+        span.set(n_tokens=n_tokens, calls=len(calls))
+        self.stats["prefill_chunks"] += len(calls)
+        self.stats["prefill_tokens"] += n_tokens
+        produced = 0
+        for lanes, reqs, drawn in calls:  # lane order, so emits are too
+            if drawn is None:
+                continue
+            with obs.span("pick", boundary=True, rows=width):
+                picks = np.asarray(drawn)
+            for i, req, token in zip(lanes, reqs, picks):
+                if req is None:
+                    continue
                 s = self.slots[i]
                 if self.prefix is not None:
-                    self.prefix.insert(s.request.prompt, s.blocks)
+                    self.prefix.insert(req.prompt, s.blocks)
                 s.decoding = True
                 self._active[i] = True
                 produced += 1
-                if self._emit(i, int(picks[r_idx])):
+                if self._emit(i, int(token)):
                     self._finish(i)
         if track:
             # one req_prefill phase span per lane covering the WHOLE
-            # tick (row prep, the dispatch, and the blocking pick) —
-            # host time a dispatch-only span would leave unattributed
+            # prefill half of the tick (row prep, the dispatches, and
+            # the blocking picks) — host time a dispatch-only span
+            # would leave unattributed
             t1 = self.clock()
-            for r_idx in range(len(pending)):
+            for rid, i in zip(rids, pending):
                 obs.add_span(
                     "req_prefill", t0, t1,
-                    {"rid": rids[r_idx],
-                     "n_tokens": len(rows[r_idx]["tokens"])},
+                    {"rid": rid, "n_tokens": len(chunks[i])},
                 )
         return produced
 
