@@ -17,12 +17,18 @@ on a system in its steady state.
 
 What the driver hands the harness (``BENCHMARK.json`` says which of them
 are end-to-end metrics): ``serve_tokens_per_s`` (tokens every tick of
-the window produced over the window), and over every request that
-finished inside the window the median and the 95th percentile of the
-time to the first token (first token minus the moment the client sent
-it, queueing included) and of the time per output token ((finish - first
-token) / (output tokens - 1)): ``serve_ttft_p50_ms``,
-``serve_ttft_p95_ms``, ``serve_tpot_p50_ms``, ``serve_tpot_p95_ms``.
+the window produced over the window), and over the requests that the
+clients sent inside the window the median and the 95th percentile of
+the time to the first token (first token minus the moment the client
+sent it, queueing included; over every such request whose first token
+came inside the window, finished or not) and of the time per output
+token ((finish - first token) / (output tokens - 1); over those that
+also finished inside it with more than one token):
+``serve_ttft_p50_ms``, ``serve_ttft_p95_ms``, ``serve_tpot_p50_ms``,
+``serve_tpot_p95_ms``, with the two sample counts ``n_ttft`` and
+``n_tpot`` beside them.  What set-up sent (its opening tick holds
+``clients`` arrivals at once, which a closed loop never repeats) counts
+in the tokens, in the check of outputs and in no latency.
 
 The check of outputs: once the window has closed and the program's state
 is freed, a sample of the finished requests drawn from the seed, the
@@ -33,6 +39,7 @@ logit lies below the reference's best.
 
 from __future__ import annotations
 
+import bisect
 import time
 
 import jax
@@ -167,6 +174,7 @@ class Driver:
     def window(self, seconds: float, tracer) -> None:
         sched, stats = self.sched, self.sched.stats
         ticks = []  # (start, seconds, tokens, was a prefill tick, resident)
+        calls = []  # prefill calls each tick made (for the tail's print)
         trace_at = float(self.t["trace_after_s"])
         trace_for = float(self.t["trace_s"])
         trace_on = trace_off = None
@@ -195,6 +203,7 @@ class Driver:
                 for s in sched.slots if s.request is not None and s.decoding)
             ticks.append((ts, now - ts, produced,
                           stats["prefill_chunks"] > chunks, resident))
+            calls.append(stats["prefill_chunks"] - chunks)
             if len(ticks) % 32 == 0:
                 self.ctx.memory.sample()
             if trace_on is not None and trace_off is None and now - began >= trace_for:
@@ -209,7 +218,7 @@ class Driver:
             stall += trace_off - now
         t1 = time.perf_counter()
         self.window_s = t1 - t0
-        self.ticks = ticks
+        self.ticks, self.tick_calls = ticks, calls
         self.sent_in_window = sent
         self.finished_ids = [r for r in self.rec.done if r not in done_before]
         # a pause of the host shows here and nowhere else
@@ -233,24 +242,54 @@ class Driver:
         )
 
     # ------------------------------------------------------------------
-    def _latencies(self):
-        """Over every request that finished inside the window; in a
-        traced run over those that the profiler's start and stop (pauses
-        of the host, tenths of a second each) did not touch."""
+    def _latency_ids(self):
+        """The requests sent inside the window that got their first token
+        there; in a traced run those that the profiler's start and stop
+        (pauses of the host, tenths of a second each) did not touch (one
+        still running when the window closes ends past them)."""
         rec = self.rec
+        t0 = self._facts["t0"]
         on, off = self._facts["traced"]
-        ids = [r for r in self.finished_ids
-               if on is None or rec.done[r] < on or rec.sent[r] > off]
+        return [r for r in rec.first
+                if rec.sent[r] >= t0 and (
+                    on is None or rec.sent[r] > off
+                    or rec.done.get(r, float("inf")) < on)]
+
+    def _latencies(self):
+        """Over the requests sent inside the window: the time to the
+        first token of every one that got it there, the time per output
+        token of every one that also finished there with more than one
+        token."""
+        rec, ids = self.rec, self._latency_ids()
         ttft = [1e3 * (rec.first[r] - rec.sent[r]) for r in ids]
         tpot = [1e3 * (rec.done[r] - rec.first[r]) / (rec.n_out[r] - 1)
-                for r in ids if rec.n_out[r] > 1]
+                for r in ids if r in rec.done and rec.n_out[r] > 1]
         return ttft, tpot
+
+    def _slowest_first_tokens(self, n: int = 12):
+        """What sets the tail of the time to the first token: the ``n``
+        longest as [ms, prompt tokens, ms of the tick that emitted it,
+        prefill calls of that tick] (a tick with one call takes two
+        programs' time, one with a second call three; longer is a pause
+        of the host)."""
+        rec = self.rec
+        starts = [t[0] for t in self.ticks]
+        rows = []
+        for r in sorted(self._latency_ids(), reverse=True,
+                        key=lambda r: rec.first[r] - rec.sent[r])[:n]:
+            i = bisect.bisect_right(starts, rec.first[r]) - 1
+            rows.append([1e3 * (rec.first[r] - rec.sent[r]),
+                         len(self.by_id[r].prompt),
+                         1e3 * self.ticks[i][1], self.tick_calls[i]])
+        return rows
 
     def end_to_end_values(self) -> dict:
         ttft, tpot = self._latencies()
         tokens = sum(t[2] for t in self.ticks)
         self._facts.update(ttft_ms=ttft, tpot_ms=tpot, tokens=tokens)
-        out = {"serve_tokens_per_s": tokens / self.window_s}
+        self.ctx.say(slowest_first_tokens=self._slowest_first_tokens())
+        out = {"serve_tokens_per_s": tokens / self.window_s,
+               "n_ttft": len(ttft), "n_tpot": len(tpot)}
         for name, values in (("ttft", ttft), ("tpot", tpot)):
             if len(values) >= 2:
                 out[f"serve_{name}_p50_ms"] = float(np.percentile(values, 50))

@@ -13,11 +13,14 @@ no cell's, configuration's or metric's name.
 
 The run: require the TPU and the cell's chips (else exit 2, no result
 line), place the compile cache, set up and warm the cell's own shapes
-(``setup_s``), measure for ``--seconds`` with compilations counted (one
-inside the window: exit 3, no result line), read the peak memory, free
-the program's state, run the plain reference over what the timed path
-produced, print every number compared beside its limit (standard error,
-and last in the result line), print the result line.
+(``setup_s``: everything from this file's first line to the window's
+start but the one call that claims the chips, whose length is the
+machine's: ``backend_start_s`` in the observation line), measure for
+``--seconds`` with compilations counted (one inside the window: exit 3,
+no result line), read the peak memory, free the program's state, run the
+plain reference over what the timed path produced, print every number
+compared beside its limit (standard error, and last in the result
+line), print the result line.
 """
 
 from __future__ import annotations
@@ -215,7 +218,11 @@ def make_context(cell, seed: int, rehearsal: dict | None,
 
     import jax
 
+    # the first call claims the chips: the runtime's start-up, which is
+    # the machine's and no work of the program or of the benchmark
+    t_claim = time.perf_counter()
     devices = jax.devices()
+    backend_start_s = time.perf_counter() - t_claim
     if rehearsal is None:
         if devices[0].platform != "tpu":
             raise Refused(
@@ -260,6 +267,7 @@ def make_context(cell, seed: int, rehearsal: dict | None,
         reference=load_module("references", cell.config_name),
         flops=load_module("flops", cell.config_name),
         say=say, t0=_T0, memory=MemoryWatch(devices),
+        backend_start_s=backend_start_s,
     )
     return ctx, driver_mod, jax, _COMPILES, cache
 
@@ -285,7 +293,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     compiles.reset()
     ctx.memory.sample()
-    setup_s = time.perf_counter() - _T0
+    since_start_s = time.perf_counter() - _T0
+    setup_s = since_start_s - ctx.backend_start_s
     driver.window(float(seconds), tracer)
     in_window = compiles.n
     if in_window:
@@ -338,6 +347,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                    for k in units}
     say(workload=workload, seed=int(seed), seconds=seconds, trace=int(trace),
         cache=cache, setup_programs=setup_programs, check_s=check_s,
+        backend_start_s=ctx.backend_start_s, since_start_s=since_start_s,
         end_to_end=end_to_end, where=numbers.get("_where"))
     result = dict(correct=bool(correct), attempted=int(attempted),
                   failed=int(failed), metrics=metrics, device=device)

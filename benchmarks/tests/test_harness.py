@@ -353,3 +353,163 @@ def test_reader_that_finds_nothing_returns_nothing():
     ctx.args = {"pattern": r"^fusion\.545$"}
     share = run.load_module("readers", "op_exposed").read(ctx)
     assert 0.0 < share < 100.0
+
+
+# ---------------------------------------------------------------------------
+# what the serve driver's latencies count: the window's own requests
+# ---------------------------------------------------------------------------
+
+T0 = 100.0  # the window opens
+
+
+def _serve_driver(traced=(None, None)):
+    """The serve driver's latency arithmetic over a hand-filled recorder:
+    no model, no scheduler, no jax array."""
+    mod = run.load_module("drivers", "serve_paged")
+    d = object.__new__(mod.Driver)
+    d.rec = mod.Recorder()
+    d._facts = {"t0": T0, "traced": traced}
+    return d
+
+
+def _request(d, rid, sent, first=None, done=None, n_out=None):
+    d.rec.admitted(rid, 8, sent)
+    if first is not None:
+        d.rec.first_token(rid, first)
+    if done is not None:
+        d.rec.finished(rid, n_out, done)
+
+
+LATENCY_CASES = {
+    # sent by set-up, finished inside the window: in neither list
+    "sent_before_the_window": (
+        dict(sent=T0 - 0.5, first=T0 - 0.4, done=T0 + 3.0, n_out=11), [], []),
+    # set-up's burst: first token inside the window too, still in neither
+    "sent_before_first_token_inside": (
+        dict(sent=T0 - 0.01, first=T0 + 0.6, done=T0 + 3.0, n_out=11), [], []),
+    "sent_and_finished_inside": (
+        dict(sent=T0 + 1.0, first=T0 + 1.08, done=T0 + 2.08, n_out=11),
+        [80.0], [100.0]),
+    # a request need not have finished to have a time to its first token
+    "first_token_and_no_finish": (
+        dict(sent=T0 + 1.0, first=T0 + 1.08), [80.0], []),
+    "one_token_answer": (
+        dict(sent=T0 + 1.0, first=T0 + 1.08, done=T0 + 1.08, n_out=1),
+        [80.0], []),
+    "sent_and_no_first_token_yet": (dict(sent=T0 + 1.0), [], []),
+    "sent_as_the_window_opens": (
+        dict(sent=T0, first=T0 + 0.074, done=T0 + 0.274, n_out=3),
+        [74.0], [100.0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LATENCY_CASES))
+def test_serve_latencies_count_requests_sent_inside_the_window(case):
+    request, want_ttft, want_tpot = LATENCY_CASES[case]
+    d = _serve_driver()
+    _request(d, "r0", **request)
+    ttft, tpot = d._latencies()
+    assert ttft == pytest.approx(want_ttft) and tpot == pytest.approx(want_tpot)
+
+
+TRACED = (T0 + 6.0, T0 + 10.5)  # the profiler's start began, its stop ended
+TRACED_CASES = {
+    # (request, kept)
+    "finished_before_the_profiler_started": (
+        dict(sent=T0 + 1.0, first=T0 + 1.08, done=T0 + 5.9, n_out=40), True),
+    "running_when_the_profiler_started": (
+        dict(sent=T0 + 5.0, first=T0 + 5.08, done=T0 + 7.0, n_out=20), False),
+    "sent_inside_the_traced_stretch": (
+        dict(sent=T0 + 8.0, first=T0 + 8.08, done=T0 + 12.0, n_out=40), False),
+    "sent_after_the_profiler_stopped": (
+        dict(sent=T0 + 10.6, first=T0 + 10.68, done=T0 + 14.0, n_out=30), True),
+    # still running when the window closes: it ends past the profiler's
+    # stop, so the profiler touched it if it was sent before that
+    "unfinished_sent_before_the_stop": (
+        dict(sent=T0 + 9.0, first=T0 + 9.08), False),
+    "unfinished_sent_after_the_stop": (
+        dict(sent=T0 + 44.0, first=T0 + 44.08), True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TRACED_CASES))
+def test_traced_run_drops_what_the_profiler_touched(case):
+    request, kept = TRACED_CASES[case]
+    d = _serve_driver(traced=TRACED)
+    _request(d, "r0", **request)
+    ttft, tpot = d._latencies()
+    assert len(ttft) == (1 if kept else 0)
+    assert len(tpot) == (1 if kept and "done" in request else 0)
+    # and untraced every one of them counts
+    d._facts["traced"] = (None, None)
+    assert len(d._latencies()[0]) == 1
+
+
+def test_serve_latencies_over_a_window_that_opens_on_set_ups_burst():
+    """Set-up's twelve, all finished inside the window, and four of the
+    window's own, of which one is unfinished and one a one-token answer:
+    the lists hold the window's own and nothing of the burst."""
+    d = _serve_driver()
+    for i in range(12):  # set-up's burst: slow first tokens, all finished
+        _request(d, f"s{i}", sent=T0 - 7.0, first=T0 - 7.0 + 0.15 + 0.04 * i,
+                 done=T0 + 1.0 + i, n_out=50)
+    _request(d, "a", sent=T0 + 1.0, first=T0 + 1.074, done=T0 + 6.074, n_out=51)
+    _request(d, "b", sent=T0 + 2.0, first=T0 + 2.081, done=T0 + 4.081, n_out=21)
+    _request(d, "c", sent=T0 + 3.0, first=T0 + 3.088, done=T0 + 3.088, n_out=1)
+    _request(d, "d", sent=T0 + 44.9, first=T0 + 44.973)
+    ttft, tpot = d._latencies()
+    assert sorted(ttft) == pytest.approx([73.0, 74.0, 81.0, 88.0])
+    assert sorted(tpot) == pytest.approx([100.0, 100.0])
+
+
+@pytest.mark.parametrize("cell", SERVE_CELLS)
+@pytest.mark.parametrize("trace", [0, 1])
+def test_serve_cell_reports_the_sample_counts_of_its_latencies(cell, trace):
+    """``run.py`` prints what the driver hands it as ``end_to_end`` in the
+    observation line before the result line (the result line's keys are
+    the contract's): ``n_ttft`` and ``n_tpot`` are there, and neither
+    counts more requests than the window sent."""
+    rc, lines, err = drive(cell, 2**31 + 4321, trace)
+    assert rc == 0, err
+    last = json.loads(lines[-1])
+    said = [json.loads(l) for l in lines[:-1]]
+    e2e = next(s["end_to_end"] for s in reversed(said) if "end_to_end" in s)
+    assert 2 <= e2e["n_tpot"] <= e2e["n_ttft"] <= last["attempted"]
+    assert "n_ttft" not in last["metrics"] and "n_tpot" not in last["metrics"]
+    slowest = next(s["slowest_first_tokens"] for s in said
+                   if "slowest_first_tokens" in s)
+    assert slowest == sorted(slowest, reverse=True) and len(slowest) <= 12
+    # (a toy prompt spans several chunks, so it can outlast its last tick)
+    assert all(ms > 0 and prompt >= 1 and tick_ms > 0 and calls >= 1
+               for ms, prompt, tick_ms, calls in slowest)
+    if not trace:
+        # the percentiles are those of the counted lists
+        assert e2e["serve_ttft_p50_ms"] <= e2e["serve_ttft_p95_ms"]
+        assert slowest[0][0] >= e2e["serve_ttft_p95_ms"]
+
+
+def test_setup_s_leaves_out_the_runtimes_own_start_up(monkeypatch):
+    """``setup_s`` runs from the process's start to the window's start less
+    the one call that claims the chips (``jax.devices()``, the machine's
+    start-up of the runtime); the observation line gives both parts."""
+    import time
+
+    import jax
+
+    real = jax.devices
+
+    def slow_devices(*a, **kw):
+        time.sleep(0.4)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(jax, "devices", slow_devices)
+    cell = sorted(c for c in CELLS if CELLS[c]["chips"] == 1)[0]
+    rc, lines, err = drive(cell, 2**31 + 777, 0)
+    assert rc == 0, err
+    last = json.loads(lines[-1])
+    said = next(json.loads(l) for l in reversed(lines[:-1])
+                if "backend_start_s" in l)
+    assert 0.4 <= said["backend_start_s"] < 2.0
+    assert last["metrics"]["setup_s"]["value"] == pytest.approx(
+        said["since_start_s"] - said["backend_start_s"])
+    assert said["end_to_end"]["setup_s"] == last["metrics"]["setup_s"]["value"]
