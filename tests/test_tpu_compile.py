@@ -208,3 +208,63 @@ def test_paged_engine_programs_compile_at_serving_widths(
         arg(jnp.int32, s), arg(jnp.bool_, s),
     ).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_latent_stage_programs_fit_one_v5e_chip(one_chip, as_tpu):
+    """The decode program and the widest prefill program of the
+    benchmark's ``xing4.0-29b-a4b`` stage, at the sizes of its
+    configuration file (published widths, 4.79 G bfloat16 weights, the
+    12,289-block latent pool), compile for a v5e with their four kernels
+    inside and fit the chip: arguments, temporaries and the outputs that
+    are not the donated pool's stay under 16 GiB."""
+    import json
+    import os
+
+    from theanompi_tpu.models.transformer import TransformerLM
+    from theanompi_tpu.serving import PagedServingEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "xing4.0-29b-a4b.json")) as f:
+        cfg = json.load(f)
+    pc = dict(cfg["program_config"], seed=0)
+    model = TransformerLM(
+        config=pc,
+        mesh=TransformerLM.build_mesh(devices=jax.devices()[:1], config=pc),
+    )
+    assert 4.79e9 < model.n_params < 4.80e9 and model.opt_state is None
+    eng = PagedServingEngine(model, **cfg["engine"])
+    assert eng.paged_attn_effective == "pallas" and eng.prefill_rows == 1
+    # 393,216 usable rows, stored 640 wide: 3.02 GB over the six layers
+    assert eng.kv_block_bytes() * eng.n_blocks == 12289 * 32 * 6 * 640 * 2
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one_chip),
+        model.params)
+    state = _described(jax.eval_shape(eng.init_state), one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s, nb, c = eng.n_slots, eng.blocks_per_seq, eng.chunk_buckets[-1]
+    r = eng.prefill_rows
+    assert (s, nb, c) == (32, 544, 2048)
+    prefill = eng._paged_prefill_jit.lower(
+        params, state, arg(jnp.int32, r, c), arg(jnp.int32, r, nb),
+        arg(jnp.int32, r), arg(jnp.int32, r), arg(jnp.bool_, r),
+    ).compile()
+    decode = eng._paged_decode_jit.lower(
+        params, state, arg(jnp.int32, s), arg(jnp.int32, s, nb),
+        arg(jnp.int32, s), arg(jnp.bool_, s),
+    ).compile()
+    for program in (prefill, decode):
+        m = program.memory_analysis()
+        held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+        assert held < HBM_BYTES, held
+        # the pool is updated in place: no copy of it among the temporaries
+        assert m.temp_size_in_bytes < 2 * 1024 ** 3, m.temp_size_in_bytes
+    text = decode.as_text()
+    for kernel in ("mla_paged_decode", "moe_grouped_mm_gate", "mhc_pre",
+                   "mhc_post"):
+        assert kernel in text, kernel
+    assert "moe_grouped_mm_down" in prefill.as_text()

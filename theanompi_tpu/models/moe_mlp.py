@@ -2,8 +2,9 @@
 
 No reference analog (Theano-MPI is data-parallel only; SURVEY.md §3.4)
 — demonstrator for the beyond-reference ``ep`` mesh axis: tokens shard
-over (dp, ep), expert FFN weights shard over ``ep``, and routing runs
-through one all-to-all pair per step (``parallel.moe.MoeMlp``).
+over (dp, ep), expert FFN weights shard over ``ep``, and every device computes its
+own experts' part for everybody's tokens between an all-gather and a
+reduce-scatter (``parallel.moe.MoeMlp``: no capacity, nothing dropped).
 Gradients reduce over (dp, ep) with expert-sharded leaves skipping
 ``ep`` via ``param_specs`` — the same per-leaf mechanism as tensor and
 pipeline parallelism.
@@ -30,7 +31,6 @@ class MoeMlpModel(TpuModel):
         d_hidden=256,
         n_experts=8,
         top_k=1,
-        capacity_factor=1.5,
         moe_aux_coef=0.01,  # weight of the Switch load-balance aux loss
         ep=2,  # expert-parallel degree = mesh ep-axis size
         n_classes=10,
@@ -88,7 +88,6 @@ class MoeMlpModel(TpuModel):
             n_experts=int(cfg.n_experts),
             d_hidden=int(cfg.d_hidden),
             top_k=int(cfg.top_k),
-            capacity_factor=float(cfg.capacity_factor),
             ep_axis=EP_AXIS if self.ep_size > 1 else None,
             ep_size=self.ep_size,
             compute_dtype=(
@@ -100,7 +99,7 @@ class MoeMlpModel(TpuModel):
                 L.Flatten(),
                 L.Dense(d),
                 L.Relu(),
-                L.Residual(self.moe),  # dropped tokens fall back to identity
+                L.Residual(self.moe),
                 L.Dense(int(cfg.n_classes)),
             ]
         )

@@ -24,7 +24,12 @@ Contracts:
   lane, block or request.  Each costs one
   small dict, one lock and one ``deque.append`` (a few microseconds;
   tests/test_observability_boundary.py holds it under 20 µs) against
-  ticks and steps of tens of milliseconds.
+  ticks and steps of tens of milliseconds.  A boundary span's arguments
+  are the recorded event's own dict, so ``set()`` after the span has
+  closed still reaches the buffer: the serving scheduler completes a
+  dispatch span that way with counters its program returned, once the
+  program has run (a span that closed without any argument records
+  none, and takes none later).
 - **Monotonic clocks** — timestamps come from ``time.perf_counter``
   (never wall clock), relative to the tracer's epoch, so spans across
   threads order correctly and NTP steps can't fold a trace.

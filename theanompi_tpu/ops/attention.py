@@ -334,3 +334,75 @@ class TransformerBlock(Layer):
             return x, {"moe": ms}
         x = x + self._mlp(params, h2)
         return x, state
+
+
+# ---------------------------------------------------------------------------
+# pieces of the rotary / RMSNorm / gated family (ops.latent_block)
+# ---------------------------------------------------------------------------
+
+def rms_norm(x, scale, eps: float = 1e-6):
+    """``x / sqrt(mean(x²) + eps) · scale`` over the last dim, fp32
+    statistics, result in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature term ``0.1 · mscale · ln(factor) + 1``
+    (1 where the context is not stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float = 10000.0, factor: float = 1.0,
+                  original_max_position: int = 4096,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """The ``dim // 2`` rotary frequencies under YaRN scaling, as
+    DeepSeek-V2/V3's ``YarnRotaryEmbedding`` computes them: a frequency
+    that turns more than ``beta_fast`` times in the original context is
+    kept (extrapolated), one that turns less than ``beta_slow`` times is
+    divided by ``factor`` (interpolated), and a linear ramp over the
+    dimensions between joins the two.  ``factor <= 1``: plain RoPE."""
+    extra = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if factor <= 1.0:
+        return extra
+
+    def correction_dim(rotations):
+        return (dim * math.log(original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3),
+        0.0, 1.0)
+    keep = 1.0 - ramp  # 1 where the frequency is extrapolated
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def rope_interleaved(x, positions, inv_freq, scale: float = 1.0):
+    """Rotate the pairs ``(x[2i], x[2i+1])`` of the last dim by
+    ``positions · inv_freq[i]``.  ``x`` (..., T, [H,] dim) with
+    ``positions`` (..., T) broadcast over a head axis if there is one.
+    The result is laid out de-interleaved (rotated evens, then rotated
+    odds), as the DeepSeek code leaves it: queries and keys go through
+    the same permutation, so their products do not see it."""
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq  # (..., T, dim/2)
+    if x.ndim == ang.ndim + 1:
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    xf = x.astype(jnp.float32)
+    xe, xo = xf[..., 0::2], xf[..., 1::2]
+    return jnp.concatenate(
+        [xe * cos - xo * sin, xe * sin + xo * cos], axis=-1).astype(x.dtype)
+
+
+def gated_ffn(x, w_gate, w_up, w_down):
+    """``(silu(x W_gate) ⊙ (x W_up)) W_down``: operands as they come,
+    fp32 accumulation, the activation in fp32, result in ``x``'s
+    dtype."""
+    g = jnp.dot(x, w_gate.astype(x.dtype), preferred_element_type=jnp.float32)
+    u = jnp.dot(x, w_up.astype(x.dtype), preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jnp.dot(h, w_down.astype(x.dtype),
+                   preferred_element_type=jnp.float32).astype(x.dtype)

@@ -319,7 +319,125 @@ def _pool_cases(size):
     ]
 
 
-_FAMILIES = (_flash_cases, _paged_cases, _wire_cases, _lrn_cases, _pool_cases)
+# ---------------------------------------------------------------------------
+# latent decode attention, grouped expert products, hyper-connections
+# ---------------------------------------------------------------------------
+
+def _mla_cases(size):
+    from theanompi_tpu.ops.pallas_paged import mla_decode_xla, mla_paged_decode
+
+    # real: the serving widths of the latent model (32 lanes, 32 heads,
+    # rows of 512 + 64 stored 640 wide, blocks of 32) over 2,048 positions
+    s, h, c, r, w, bs, nt, nb = (
+        (32, 32, 512, 64, 640, 32, 64, 2049) if size == "real"
+        else (3, 4, 16, 8, 128, 4, 7, 12)
+    )
+
+    def make(dtype):
+        def make_args(key):
+            kq, kr, kp, kt, kl = jax.random.split(key, 5)
+            q_lat = jax.random.normal(kq, (s, h, c), dtype)
+            q_rope = jax.random.normal(kr, (s, h, r), dtype)
+            pool = jax.random.normal(kp, (nb * bs, w), dtype)
+            tables = jax.random.randint(kt, (s, nt), 1, nb, jnp.int32)
+            lengths = jax.random.randint(kl, (s,), 0, nt * bs, jnp.int32)
+            lengths = lengths.at[0].set(0).at[-1].set(nt * bs - 1)
+            return q_lat, q_rope, pool, tables, lengths
+        return make_args
+
+    kw = dict(block_size=bs, scale=(c + r) ** -0.5)
+
+    def kernel(*args):  # several groups a lane, and a ragged last one
+        return mla_paged_decode(*args, group=16 if size == "real" else 3, **kw)
+
+    def oracle(q_lat, q_rope, pool, tables, lengths):
+        f32 = jnp.float32
+        return mla_decode_xla(q_lat.astype(f32), q_rope.astype(f32),
+                              pool.astype(f32), tables, lengths, **kw)
+
+    return [
+        KernelCase("mla_decode_f32", make(jnp.float32), kernel, oracle,
+                   atol=1e-4, rtol=1e-4, kernel_precision="highest",
+                   oracle_precision="highest"),
+        KernelCase("mla_decode_bf16", make(jnp.bfloat16), kernel, oracle,
+                   atol=3e-2, rtol=3e-2, oracle_precision="highest"),
+    ]
+
+
+def _gmm_cases(size):
+    from theanompi_tpu.ops.pallas_gmm import grouped_mm, grouped_mm_xla
+
+    def case(name, dtype, e, k, n, tm, n_tiles, atol, precision):
+        def make_args(key):
+            kx, kw, kt = jax.random.split(key, 3)
+            x = jax.random.normal(kx, (n_tiles * tm, k), dtype)
+            w = (jax.random.normal(kw, (e, k, n), jnp.float32)
+                 * k ** -0.5).astype(dtype)
+            # tiles sorted by expert, some experts absent, a tail of dead tiles
+            te = jnp.sort(jax.random.randint(kt, (n_tiles,), 0, e, jnp.int32))
+            return x, w, te, jnp.array([max(1, (4 * n_tiles) // 5)], jnp.int32)
+
+        def live(out, nv):  # rows of dead tiles are never written
+            rows = jnp.arange(out.shape[0])[:, None] < nv[0] * tm
+            return jnp.where(rows, out, 0)
+
+        def kernel(x, w, te, nv):
+            return live(grouped_mm(x, w, te, nv, tm=tm), nv)
+
+        def oracle(x, w, te, nv):
+            f32 = jnp.float32
+            return live(grouped_mm_xla(x.astype(f32), w.astype(f32), te, tm=tm), nv)
+
+        return KernelCase(name, make_args, kernel, oracle, atol=atol, rtol=atol,
+                          kernel_precision=precision, oracle_precision="highest")
+
+    if size == "real":  # the expert widths: a decode tick's and a prefill call's tiles
+        return [
+            case("gmm_decode_bf16", jnp.bfloat16, 64, 3584, 1024, 16, 68, 5e-2, None),
+            case("gmm_prefill_bf16", jnp.bfloat16, 64, 1024, 3584, 256, 48, 5e-2, None),
+        ]
+    return [
+        case("gmm_decode_bf16", jnp.float32, 5, 24, 16, 8, 9, 1e-4, "highest"),
+        case("gmm_prefill_bf16", jnp.float32, 5, 16, 24, 16, 6, 1e-4, "highest"),
+    ]
+
+
+def _mhc_cases(size):
+    from theanompi_tpu.ops import pallas_mhc as M
+
+    n, iters = 4, 20
+    t, d, dtype = (256, 3584, jnp.bfloat16) if size == "real" else (24, 64, jnp.float32)
+    m = 2 * n + n * n
+    kw = dict(n=n, eps=1e-6, iters=iters, clamp=30.0)
+
+    def make_args(key):
+        kx, kp, kb, ky = jax.random.split(key, 4)
+        x = jax.random.normal(kx, (t, n * d), dtype)
+        phi = (0.02 * jax.random.normal(kp, (m, n * d))).astype(dtype)
+        bias = jax.random.normal(kb, (m,)).at[2 * n:].add(3.0 * jnp.eye(n).reshape(-1))
+        # one token at the clamp's extremes
+        bias_hot = bias.at[2 * n].set(40.0).at[2 * n + 5].set(-40.0)
+        phi_t, ab = M.pack_coefficients(phi, jnp.full((3,), 0.1), bias_hot, n)
+        y = jax.random.normal(ky, (t, d), dtype)
+        return x, phi_t, ab, y
+
+    def both(pre, post):
+        def run(x, phi_t, ab, y):
+            u, coef = pre(x, phi_t, ab, **kw)
+            return u, coef, post(x, y, coef, n=n)
+        return run
+
+    tol = 3e-2 if size == "real" else 1e-5
+    return [
+        KernelCase("mhc_pre_post", make_args, both(M.mhc_pre, M.mhc_post),
+                   both(M.mhc_pre_xla, M.mhc_post_xla), atol=tol, rtol=tol,
+                   kernel_precision=None if size == "real" else "highest",
+                   oracle_precision="highest"),
+    ]
+
+
+_FAMILIES = (_flash_cases, _paged_cases, _wire_cases, _lrn_cases, _pool_cases,
+             _mla_cases, _gmm_cases, _mhc_cases)
 
 
 def cases(size: str = "real"):

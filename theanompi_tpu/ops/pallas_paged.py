@@ -242,3 +242,144 @@ def paged_decode_attention(
         jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
         *args,
     )
+
+
+# ---------------------------------------------------------------------------
+# latent cache: absorbed decode over rows shared by every head
+# ---------------------------------------------------------------------------
+
+def _mla_kernel(tbl_ref, len_ref, ql_ref, qr_ref, *refs, bs, group, nj,
+                scale, c_dim, r_dim):
+    pool_refs, o_ref = refs[:group], refs[group]
+    m_ref, d_ref, acc_ref = refs[group + 1:]
+    s_idx, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        d_ref[...] = jnp.zeros_like(d_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    length = len_ref[s_idx]
+    span = group * bs
+
+    @pl.when(j * span <= length)  # fully-masked groups are elided
+    def _work():
+        rows = jnp.concatenate([r[...] for r in pool_refs], axis=0)
+        c, kr = rows[:, :c_dim], rows[:, c_dim:c_dim + r_dim]
+        nt = (((1,), (1,)), ((), ()))
+        sc = (
+            lax.dot_general(ql_ref[0], c, nt,
+                            preferred_element_type=jnp.float32)
+            + lax.dot_general(qr_ref[0], kr, nt,
+                              preferred_element_type=jnp.float32)
+        ) * scale  # (H, span)
+        pos = j * span + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where(pos <= length, sc, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        d_ref[...] = d_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p.astype(c.dtype), c, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == nj - 1)
+    def _fin():
+        o_ref[0] = (acc_ref[...] / d_ref[...]).astype(o_ref.dtype)
+
+
+def mla_paged_decode(
+    q_lat: jax.Array,
+    q_rope: jax.Array,
+    pool: jax.Array,
+    tables: jax.Array,
+    lengths: jax.Array,
+    *,
+    block_size: int,
+    scale: float,
+    group: int = 16,
+    interpret: Optional[bool] = None,
+):
+    """Absorbed latent attention for one decode tick, one layer:
+    ``softmax((q_lat · c + q_rope · k_rope) · scale) · c`` over each
+    lane's resident rows.
+
+    - ``q_lat`` (S, H, C): the heads' queries carried into the latent
+      space (``q_nope W_kvb[k]ᵀ``); ``q_rope`` (S, H, R).
+    - ``pool`` (rows, W >= C + R): one row a token, ``[c_kv | k_rope |
+      padding]``, shared by every head — a block is read once for all
+      of them.  (``W`` a multiple of 128: the device lays a narrower
+      tall array out column-major, and every call would then copy the
+      whole pool to row-major and back.)
+    - ``tables`` (S, NT), ``lengths`` (S,) as ``paged_decode_attention``.
+
+    A grid step attends to ``group`` blocks: the pool is handed to the
+    call ``group`` times, each with its own table-driven BlockSpec, so
+    the blocks arrive by the pipeline's own double-buffered copies; a
+    block past the lane's last one maps onto the last (no new copy) and
+    its positions are masked.  Returns fp32 (S, H, C): the caller
+    carries it out of the latent space (``· W_kvb[v]``)."""
+    s, h, c_dim = q_lat.shape
+    r_dim = q_rope.shape[-1]
+    bs = int(block_size)
+    g = max(1, min(int(group), int(tables.shape[1])))
+    tables = jnp.asarray(tables, jnp.int32)
+    pad = -tables.shape[1] % g
+    if pad:
+        tables = jnp.pad(tables, ((0, 0), (0, pad)))
+    nj = tables.shape[1] // g
+
+    def pool_map(k):
+        def index(si, j, tbl, ln):
+            return (tbl[si, jnp.minimum(j * g + k, ln[si] // bs)], 0)
+        return index
+
+    def row_map(si, j, tbl, ln):
+        return (si, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # tables, lengths
+        grid=(s, nj),
+        in_specs=[pl.BlockSpec((1, h, c_dim), row_map),
+                  pl.BlockSpec((1, h, r_dim), row_map)]
+        + [pl.BlockSpec((bs, pool.shape[1]), pool_map(k)) for k in range(g)],
+        out_specs=pl.BlockSpec((1, h, c_dim), row_map),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),      # running max
+            pltpu.VMEM((h, 1), jnp.float32),      # running denominator
+            pltpu.VMEM((h, c_dim), jnp.float32),  # output accumulator
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_kernel, bs=bs, group=g, nj=nj,
+                          scale=float(scale), c_dim=c_dim, r_dim=r_dim),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, h, c_dim), jnp.float32),
+        interpret=(not platform.on_tpu()) if interpret is None else interpret,
+        name="mla_paged_decode",
+    )(tables, jnp.asarray(lengths, jnp.int32),
+      q_lat.astype(pool.dtype), q_rope.astype(pool.dtype), *([pool] * g))
+
+
+def mla_decode_xla(q_lat, q_rope, pool, tables, lengths, *, block_size,
+                   scale):
+    """The same over the gathered image of every lane's table (plain
+    XLA: the kernel's oracle and the path of a sharded pool)."""
+    s = q_lat.shape[0]
+    c_dim, r_dim = q_lat.shape[-1], q_rope.shape[-1]
+    bs = int(block_size)
+    rows = (tables[:, :, None] * bs + jnp.arange(bs)[None, None, :])
+    img = jnp.take(pool, rows.reshape(s, -1), axis=0)  # (S, T, W)
+    c, kr = img[..., :c_dim], img[..., c_dim:c_dim + r_dim]
+    sc = (
+        jnp.einsum("shc,stc->sht", q_lat.astype(c.dtype), c,
+                   preferred_element_type=jnp.float32)
+        + jnp.einsum("shr,str->sht", q_rope.astype(c.dtype), kr,
+                     preferred_element_type=jnp.float32)
+    ) * scale
+    mask = jnp.arange(img.shape[1])[None, :] <= lengths[:, None]
+    prob = jax.nn.softmax(jnp.where(mask[:, None, :], sc, _NEG_INF), axis=-1)
+    return jnp.einsum("sht,stc->shc", prob.astype(c.dtype), c,
+                      preferred_element_type=jnp.float32)

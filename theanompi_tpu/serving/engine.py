@@ -158,7 +158,13 @@ class ServingEngine:
         buckets: Optional[Sequence[int]] = None,
     ):
         cfg = model.config
-        if int(cfg.get("moe_experts", 0) or 0):
+        # a 'latent_moe' model (ops.latent_block) brings its own forward
+        # pass, experts included; it is served paged (serving/latent.py)
+        self.latent = str(cfg.get("block", "dense")) == "latent_moe"
+        if self.latent and not getattr(self, "is_paged", False):
+            raise ValueError("a block='latent_moe' model is served by "
+                             "PagedServingEngine")
+        if int(cfg.get("moe_experts", 0) or 0) and not self.latent:
             raise ValueError("serving supports the dense FFN stack only "
                              "(moe_experts=0)")
         if getattr(model, "pp_size", 1) > 1:
@@ -184,7 +190,7 @@ class ServingEngine:
         self.n_slots = int(n_slots)
         train_len = int(cfg.seq_len)
         self.max_len = int(max_len) if max_len is not None else train_len
-        if self.max_len > train_len:
+        if self.max_len > train_len and not self.latent:  # rotary: no table
             raise ValueError(
                 f"max_len={self.max_len} exceeds the learned positional "
                 f"table ({train_len} rows, config seq_len)"

@@ -432,6 +432,21 @@ class PagedServingEngine(ServingEngine):
         )
         self.pool_spec = P(None, row_ax, head_ax, None)
         self.scale_spec = P(None, row_ax, head_ax)
+        # a latent model's pool, programs and counters (serving/latent.py).
+        # `last_counters` is what the newest
+        # program call returned beside its logits (None: no such model),
+        # `last_span` the newest `prefill_chunk_dispatch` span: the
+        # scheduler completes the span once the program has run.
+        self._latent = None
+        self.last_counters = None
+        self.last_span = None
+        if self.latent:
+            if kv_dtype != "fp32":
+                raise ValueError("a latent pool holds the compute dtype "
+                                 "(kv_dtype='fp32')")
+            from theanompi_tpu.serving.latent import LatentPrograms
+
+            self._latent = LatentPrograms(self)
         # trace counter for the spec-decode verify program (one compile
         # ever per chunk width — acceptance churn must retrace nothing)
         self._n_verify_traces = 0
@@ -459,7 +474,10 @@ class PagedServingEngine(ServingEngine):
         adds the per-row/per-head scale planes ``ks``/``vs``.  Lengths
         and block tables stay host-side (tiny ints shipped per call —
         they are *data*, so shipping them can never recompile
-        anything)."""
+        anything).  A latent model: ``kv``, one (rows, kv_rank + rope)
+        array a layer (``serving/latent.py``)."""
+        if self._latent is not None:
+            return self._latent.init_state()
         dt = (
             jnp.int8 if self.kv_dtype == "int8" else self._kv_compute_dtype()
         )
@@ -483,6 +501,8 @@ class PagedServingEngine(ServingEngine):
         """Device bytes ONE pool block occupies across all layers
         (K + V payload, plus the int8 scale planes) — the equal-byte
         currency of the ``detail.kv_quant`` capacity probe."""
+        if self._latent is not None:
+            return self._latent.block_bytes()
         payload = (
             1 if self.kv_dtype == "int8"
             else jnp.dtype(self._kv_compute_dtype()).itemsize
@@ -576,6 +596,9 @@ class PagedServingEngine(ServingEngine):
             self._n_verify_traces += 1
         else:
             self._n_prefill_traces += 1
+        if self._latent is not None:
+            return self._latent.chunk_fn(params, state, tokens, tables, p0,
+                                         true_len, active, all_logits)
         emb, pos, blocks, lnf, head = self._weights(params)
         p_, c_ = tokens.shape
         bs = self.block_size
@@ -669,6 +692,9 @@ class PagedServingEngine(ServingEngine):
         the gather+softmax for the fused kernel (same scatter, same
         mask semantics — allclose-pinned)."""
         self._n_decode_traces += 1  # runs at trace time only
+        if self._latent is not None:
+            return self._latent.decode_fn(params, state, tokens, tables,
+                                          lengths, active)
         emb, pos, blocks, lnf, head = self._weights(params)
         s_ = tokens.shape[0]
         bs = self.block_size
@@ -786,12 +812,13 @@ class PagedServingEngine(ServingEngine):
             span.set(useful_tokens=useful)
             smetrics.PREFILL_CHUNKS.inc(bucket=str(c))
             smetrics.PREFILL_TOKENS.inc(useful)
-            state, logits = self._paged_prefill_jit(
-                params, state,
+            state, logits = self._call(
+                self._paged_prefill_jit, params, state,
                 host_input(tokens), host_input(tables),
                 host_input(p0), host_input(true_len),
                 host_input(active),
             )
+            self.last_span = span
         return state, logits
 
     def verify_chunks(self, params, state, tokens, tables, p0, true_len,
@@ -810,8 +837,8 @@ class PagedServingEngine(ServingEngine):
         smetrics.SPEC_VERIFY_DISPATCHES.inc()
         with obs.span("spec_verify_dispatch", rows=int(np.sum(active)),
                       width=int(np.asarray(tokens).shape[1])):
-            state, logits = self._paged_verify_jit(
-                params, state,
+            state, logits = self._call(
+                self._paged_verify_jit, params, state,
                 host_input(tokens, jnp.int32),
                 host_input(tables, jnp.int32),
                 host_input(p0, jnp.int32),
@@ -820,11 +847,19 @@ class PagedServingEngine(ServingEngine):
             )
         return state, logits
 
+    def _call(self, program, *args):
+        """``(state, logits)`` of a jitted program; what a latent
+        model's program returns beside them (its experts' counters, a
+        device array nobody has waited for) goes to ``last_counters``."""
+        out = program(*args)
+        self.last_counters = out[2] if len(out) > 2 else None
+        return out[0], out[1]
+
     def decode_step_paged(self, params, state, tokens, tables, lengths,
                           active):
         """One decode tick; host arrays in, ``(state, logits)`` out."""
-        return self._paged_decode_jit(
-            params, state,
+        return self._call(
+            self._paged_decode_jit, params, state,
             host_input(tokens, jnp.int32),
             host_input(tables, jnp.int32),
             host_input(lengths, jnp.int32),

@@ -200,6 +200,10 @@ class ContinuousBatchingScheduler:
         # a tick starts at its admission, not the tick edge, so its
         # queue wait is never double-billed as prefill
         self._req_tick_adm: Dict[str, float] = {}
+        # (span, tokens routed, device array) of program calls whose
+        # experts' counters nobody has fetched yet (a latent model's;
+        # `_note_counters`)
+        self._counters: List[tuple] = []
         if self.paged:
             if pool is not None and pool.block_size != engine.block_size:
                 raise ValueError("pool/engine block_size mismatch")
@@ -224,6 +228,11 @@ class ContinuousBatchingScheduler:
             else:
                 self.prefix = None
             self.state = engine.init_state()
+            if getattr(engine, "latent", False):
+                # the latent pool's size and fill, in rows (= tokens)
+                self.stats["latent_rows_capacity"] = (
+                    (self.pool.n_blocks - 1) * engine.block_size)
+                self.stats["latent_rows_resident"] = 0
             self._tables = np.zeros(
                 (engine.n_slots, engine.blocks_per_seq), np.int32
             )
@@ -437,6 +446,27 @@ class ContinuousBatchingScheduler:
                 request_key(r.seed, r.id, r.token_index0 + idx)
             )
         return self._sampler.draw_batch(logits, keys, temps, topks)
+
+    def _note_counters(self, span, tokens_routed: int) -> None:
+        """Remember that ``span``'s program call returned experts'
+        counters (``engine.last_counters``; None for a model without
+        experts: nothing to do)."""
+        counters = getattr(self.engine, "last_counters", None)
+        if counters is not None:
+            self._counters.append((span, int(tokens_routed), counters))
+
+    def _fetch_counters(self) -> None:
+        """Complete the spans of the calls noted so far with
+        ``experts_hit``, ``expert_load_max`` and ``tokens_routed``.
+        Called behind a pick, so every program noted has run and the
+        fetch waits for nothing; a boundary span's arguments are the
+        recorded event's own, so a span that has closed still takes
+        them."""
+        for span, tokens_routed, counters in self._counters:
+            hit, load = np.asarray(counters).tolist()
+            span.set(experts_hit=hit, expert_load_max=load,
+                     tokens_routed=tokens_routed)
+        self._counters.clear()
 
     def _emit(self, i: int, token: int) -> bool:
         """Append one generated token to slot i's request; True when the
@@ -664,6 +694,8 @@ class ContinuousBatchingScheduler:
                 [{"tokens": chunks[i], "p0": self.slots[i].n_fed,
                   "table": self.slots[i].blocks} for i in lanes],
             )
+            self._note_counters(self.engine.last_span,
+                                sum(len(chunks[i]) for i in lanes))
             reqs = []  # per lane: its request if the prompt completes
             for i in lanes:
                 s = self.slots[i]
@@ -731,11 +763,12 @@ class ContinuousBatchingScheduler:
                 slot.request.output[-1] if decoding[i] else 0
             )
         with obs.span("decode_step", boundary=True,
-                      active=int(decoding.sum())):
+                      active=int(decoding.sum())) as span:
             self.state, logits = self.engine.decode_step_paged(
                 self.params, self.state, self._tokens,
                 self._tables, self._lengths, decoding,
             )
+        self._note_counters(span, int(decoding.sum()))
         # the tick wrote each active lane's token at row `length`;
         # advance AFTER the dispatch so next tick writes the next row
         self._lengths[decoding] += 1
@@ -744,6 +777,8 @@ class ContinuousBatchingScheduler:
              for i, s in enumerate(self.slots)],
             logits,
         )
+        if self._counters:
+            self._fetch_counters()
         produced = 0
         for i in range(len(self.slots)):
             if not decoding[i]:
@@ -879,6 +914,8 @@ class ContinuousBatchingScheduler:
             self._spec_tick_paged() if self._spec is not None
             else self._decode_tick_paged()
         )
+        if "latent_rows_resident" in self.stats:
+            self.stats["latent_rows_resident"] = int(self._lengths.sum())
         return produced
 
     # ------------------------------------------------------------------
