@@ -134,9 +134,24 @@ def test_one_tick_feeds_every_pending_lane(narrow, wide, n):
     assert sched.stats["prefill_tokens"] == ref.stats["prefill_tokens"]
 
 
-def test_multichunk_prompts_and_prefix_hits_cross_a_split(narrow, wide):
+@pytest.mark.parametrize("kv_dtype,paged_attn", [
+    ("fp32", "xla"), ("fp32", "pallas"), ("int8", "xla"), ("int8", "pallas"),
+])
+def test_multichunk_prompts_and_prefix_hits_cross_a_split(
+    model, narrow, wide, kv_dtype, paged_attn
+):
     """Six lanes of two or three chunks each, five of them admitted on
-    a cached prefix (``p0 > 0``), in two calls a tick."""
+    a cached prefix (``p0 > 0``: the shared blocks' rows are read from
+    the layers' pool arrays, not written again), in two calls a tick.
+    The narrow engine decodes through the XLA gather or the kernel,
+    over a float or an int8 pool; the wide one always gathers."""
+    if (kv_dtype, paged_attn) != ("fp32", "xla"):
+        narrow = PagedServingEngine(model, kv_dtype=kv_dtype,
+                                    paged_attn=paged_attn, **GEOMETRY)
+        assert narrow.paged_attn_effective == paged_attn
+    if kv_dtype != "fp32":
+        wide = PagedServingEngine(model, prefill_rows=N_SLOTS,
+                                  kv_dtype=kv_dtype, **GEOMETRY)
     shared = _prompt(99, 24)  # three full blocks of 8
 
     def drive(engine):

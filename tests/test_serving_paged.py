@@ -250,8 +250,9 @@ def test_paged_on_tp_mesh_matches(model):
 
 def test_pool_rows_shard_over_dp():
     """On a multi-device dp mesh with a divisible block count, the
-    pool's row axis lands sharded over dp (whole blocks per device);
-    indivisible counts fall back to replication, never crash."""
+    row axis of every layer's pool array lands sharded over dp (whole
+    blocks per device); indivisible counts fall back to replication,
+    never crash."""
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh()  # all 8 fake devices on dp
@@ -260,12 +261,144 @@ def test_pool_rows_shard_over_dp():
         model, n_slots=8, max_len=64, block_size=8, n_blocks=64
     )
     state = eng.init_state()
-    assert eng.pool_spec == P(None, DATA_AXIS, None, None)
-    assert state["k"].sharding.spec == eng.pool_spec
+    assert eng.pool_spec == P(DATA_AXIS, None)
+    assert len(state["k"]) == len(state["v"]) == CFG["n_layers"]
+    for leaf in state["k"] + state["v"]:
+        # 4 heads of 8: 32 numbers a row, stored 128 lanes wide
+        assert leaf.shape == (64 * 8, 128)
+        assert leaf.sharding.spec == eng.pool_spec
     eng2 = PagedServingEngine(
         model, n_slots=8, max_len=64, block_size=8, n_blocks=9
     )
-    assert eng2.pool_spec == P(None, None, None, None)
+    assert eng2.pool_spec == P(None, None)
+    assert eng2.init_state()["k"][0].sharding.spec == eng2.pool_spec
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_pool_heads_shard_over_tp(kv_dtype):
+    """On a dp x tp mesh a row's heads are split over tp (and the rows
+    over dp): the width is the heads' own, unpadded, so that a shard
+    holds whole heads; the int8 scale planes follow the same spec."""
+    from jax.sharding import PartitionSpec as P
+    from theanompi_tpu.runtime.mesh import TP_AXIS
+
+    cfg_tp = dict(CFG, tp=2)
+    tp_model = TransformerLM(config=cfg_tp,
+                             mesh=TransformerLM.build_mesh(config=cfg_tp))
+    eng = PagedServingEngine(tp_model, n_slots=2, max_len=64, block_size=8,
+                             n_blocks=16, kv_dtype=kv_dtype)
+    assert eng.pool_spec == P(DATA_AXIS, TP_AXIS)
+    assert eng.row_width == CFG["d_model"]  # 32: no padding across shards
+    state = eng.init_state()
+    for side in ("k", "v"):
+        for leaf in state[side]:
+            assert leaf.shape == (16 * 8, 32)
+            assert leaf.sharding.spec == eng.pool_spec
+            assert leaf.addressable_shards[0].data.shape == (16 * 8 // 4, 16)
+    if kv_dtype == "int8":
+        for leaf in state["ks"] + state["vs"]:
+            assert leaf.shape == (16 * 8, CFG["n_heads"])
+            assert leaf.sharding.spec == eng.pool_spec
+    want = PagedServingEngine(
+        TransformerLM(config=dict(CFG), mesh=make_mesh(devices=jax.devices()[:1])),
+        n_slots=2, max_len=64, block_size=8, n_blocks=16, kv_dtype=kv_dtype,
+    )
+    want.model.params = jax.device_get(tp_model.params)
+    prompt = [5, 3, 2, 9, 9, 1, 30, 4, 4, 7, 11]
+    assert eng.greedy(list(prompt), 8) == want.greedy(
+        list(prompt), 8, params=want.model.params)
+
+
+# ---------------------------------------------------------------------------
+# the pool's layout: one lane-aligned array a layer, updated in place
+# ---------------------------------------------------------------------------
+
+# (d_model, heads): 4 heads of 8 are 32 numbers a row, stored 128 wide;
+# 2 heads of 64 are 128, a multiple of 128 lanes as they stand
+@pytest.fixture(scope="module", params=[(32, 4), (128, 2)],
+                ids=["width32", "width128"])
+def sized_model(request):
+    d_model, n_heads = request.param
+    return TransformerLM(
+        config=dict(CFG, d_model=d_model, n_heads=n_heads),
+        mesh=make_mesh(devices=jax.devices()[:1]))
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_state_is_one_lane_aligned_array_a_layer(sized_model, kv_dtype):
+    """``k`` and ``v`` (and an int8 pool's ``ks``/``vs``) are lists of
+    one two-dimensional array a layer, a row 128 lanes wide or a
+    multiple; a program returns leaves of the same shapes; and
+    ``kv_block_bytes`` counts what is stored."""
+    eng = PagedServingEngine(sized_model, n_slots=2, max_len=64,
+                             block_size=8, n_blocks=9, kv_dtype=kv_dtype)
+    assert eng.row_width == 128
+    state = eng.init_state()
+    assert sorted(state) == (
+        ["k", "ks", "v", "vs"] if kv_dtype == "int8" else ["k", "v"])
+    rows = 9 * 8
+    for side, leaves in state.items():
+        assert isinstance(leaves, list) and len(leaves) == eng.n_layers
+        for leaf in leaves:
+            assert leaf.shape == (
+                rows, eng.n_heads if side in ("ks", "vs") else 128)
+    assert state["k"][0].dtype == (
+        jnp.int8 if kv_dtype == "int8" else jnp.float32)
+    stored = sum(leaf.nbytes for leaf in jax.tree.leaves(state))
+    assert eng.kv_block_bytes() * eng.n_blocks == stored
+    shapes = jax.tree.map(lambda a: (a.shape, a.dtype), state)
+    tables = np.zeros((2, eng.blocks_per_seq), np.int32)
+    tables[0, 0] = 3
+    old = state
+    state, logits = eng.decode_step_paged(
+        sized_model.params, state, np.array([5, 0], np.int32), tables,
+        np.array([0, 0], np.int32), np.array([True, False]))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), state) == shapes
+    # every leaf was donated to the program, which wrote into it
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(old))
+    # lane 0's token went to row 0 of block 3, every head side by side,
+    # the padding columns left at zero; the idle lane's to the trash block
+    width = eng.n_heads * eng.head_dim
+    k0 = np.asarray(state["k"][0])
+    assert np.any(k0[3 * 8, :width] != 0) and not np.any(k0[3 * 8, width:])
+    assert not np.any(k0[8:3 * 8]) and not np.any(k0[3 * 8 + 1:])
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_kernel_gather_chunks_and_prefix_hits_serve_the_same_tokens(
+    sized_model, kv_dtype
+):
+    """Greedy tokens are identical between the XLA gather and the
+    kernel, between whole-prompt and chunked prefill, and across
+    prefix hits whose blocks two requests share."""
+    def mk(**kw):
+        return PagedServingEngine(
+            sized_model, n_slots=4, max_len=64, buckets=(8, 16, 64),
+            block_size=8, kv_dtype=kv_dtype, **kw)
+
+    shared = list(np.random.RandomState(1).randint(0, 32, size=24))
+
+    def drive(engine):
+        sched = ContinuousBatchingScheduler(engine)
+        sched.submit(Request(id="a", prompt=shared + [7], max_new_tokens=6))
+        for _ in range(2):  # two chunks of 16 at most: a's prefill
+            sched.step()    # completes and inserts its full blocks
+        sched.submit(Request(id="b", prompt=shared + [9], max_new_tokens=6))
+        sched.submit(Request(id="c", prompt=shared + [9, 3],
+                             max_new_tokens=4))
+        sched.submit(Request(id="d", prompt=shared[::-1] + shared[:13],
+                             max_new_tokens=5))
+        return sched.run(), sched.stats["prefix_hit_tokens"]
+
+    want, hit = drive(mk(paged_attn="xla"))
+    assert hit == 48  # b and c each reuse a's three full blocks
+    for kw in (dict(paged_attn="pallas"),
+               dict(paged_attn="xla", prefill_chunk=16),
+               dict(paged_attn="pallas", prefill_chunk=16),
+               dict(paged_attn="xla", prefix_cache=False)):
+        got, got_hit = drive(mk(**kw))
+        assert got == want, kw
+        assert got_hit == (0 if kw.get("prefix_cache") is False else 48)
 
 
 # ---------------------------------------------------------------------------

@@ -365,17 +365,29 @@ def _xla_paged_reference(q, kp, vp, tables, lengths, bs, scale):
     return np.einsum("sht,sthd->shd", p, vc)
 
 
-def test_pallas_paged_kernel_matches_xla_fp32_and_int8():
+def _flat_pool(x, width):
+    """(rows, H, hd) -> the engine's (rows, width) pool: a row's heads
+    side by side, zeros up to ``width``."""
+    x = np.asarray(x).reshape(x.shape[0], -1)
+    return np.pad(x, ((0, 0), (0, width - x.shape[1])))
+
+
+# widths that are no multiple of 128 lanes as they stand (32 and 40
+# numbers a row, stored 128 wide) and ones that are (128; 256 exactly)
+@pytest.mark.parametrize("h,hd", [(4, 8), (5, 8), (2, 64), (4, 64)])
+def test_pallas_paged_kernel_matches_xla_fp32_and_int8(h, hd):
     """The kernel-level allclose pin, exercised in interpret mode:
-    fused in-kernel gather == materialized XLA gather, fp32 and int8
-    pools, including short lengths (masked-block elision)."""
+    fused in-kernel gather over the flat ``(rows, width)`` pool ==
+    materialized XLA gather, fp32 and int8 pools, including short
+    lengths (masked-block elision)."""
     from theanompi_tpu.ops.pallas_paged import paged_decode_attention
     from theanompi_tpu.parallel.quantize import (
         dequantize_blocks, quantize_blocks,
     )
 
     rng = np.random.RandomState(0)
-    s, h, hd, bs, nb, nt = 3, 4, 8, 4, 10, 5
+    s, bs, nb, nt = 3, 4, 10, 5
+    width = -(-h * hd // 128) * 128
     q = rng.randn(s, h, hd).astype(np.float32)
     kp = rng.randn(nb * bs, h, hd).astype(np.float32)
     vp = rng.randn(nb * bs, h, hd).astype(np.float32)
@@ -385,8 +397,10 @@ def test_pallas_paged_kernel_matches_xla_fp32_and_int8():
     lengths = np.array([9, 14, 0], np.int32)  # incl. a length-0 lane
     want = _xla_paged_reference(q, kp, vp, tables, lengths, bs, hd ** -0.5)
     got = np.asarray(paged_decode_attention(
-        q, kp, vp, tables, lengths, block_size=bs
+        q, _flat_pool(kp, width), _flat_pool(vp, width), tables, lengths,
+        block_size=bs,
     ))
+    assert got.shape == (s, h, hd)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
     kq, ks = quantize_blocks(jnp.asarray(kp))
@@ -396,14 +410,16 @@ def test_pallas_paged_kernel_matches_xla_fp32_and_int8():
         np.asarray(dequantize_blocks(vq, vs)), tables, lengths, bs,
         hd ** -0.5,
     )
+    kq, vq = _flat_pool(kq, width), _flat_pool(vq, width)
     got8 = np.asarray(paged_decode_attention(
-        q, np.asarray(kq), np.asarray(vq), tables, lengths,
+        q, kq, vq, tables, lengths,
         block_size=bs, k_scale=np.asarray(ks), v_scale=np.asarray(vs),
     ))
     np.testing.assert_allclose(got8, want8, rtol=1e-5, atol=1e-5)
     with pytest.raises(ValueError, match="k_scale"):
-        paged_decode_attention(q, np.asarray(kq), np.asarray(vq),
-                               tables, lengths, block_size=bs)
+        paged_decode_attention(q, kq, vq, tables, lengths, block_size=bs)
+    with pytest.raises(ValueError, match="rows, width"):  # the old layout
+        paged_decode_attention(q, kp, vp, tables, lengths, block_size=bs)
 
 
 @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
@@ -430,11 +446,11 @@ def test_pallas_engine_decode_allclose_to_xla(model, kv_dtype):
     lens = np.array([len(prompt) - 1, 0], np.int32)
     act = np.array([True, False])
     sx, lx = xla.decode_step_paged(
-        model.params, {k: jnp.array(v) for k, v in state.items()},
+        model.params, jax.tree.map(jnp.array, state),
         toks, sched._tables, lens, act,
     )
     sp, lp = pal.decode_step_paged(
-        model.params, {k: jnp.array(v) for k, v in state.items()},
+        model.params, jax.tree.map(jnp.array, state),
         toks, sched._tables, lens, act,
     )
     np.testing.assert_allclose(

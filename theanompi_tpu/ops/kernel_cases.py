@@ -111,12 +111,17 @@ def _flash_cases(size):
 def _paged_oracle(q, kp, vp, tables, lengths, *, bs, ks=None, vs=None):
     """The XLA gather path of ``serving.paging._paged_decode_fn``:
     materialize each lane's (t_pad, H, hd) image through its block
-    table, dequantize, mask rows past the length, softmax."""
-    s, _, hd = q.shape
+    table from the flat ``(rows, width)`` pool, dequantize, mask rows
+    past the length, softmax."""
+    s, h, hd = q.shape
     rows = (tables[:, :, None] * bs + jnp.arange(bs)[None, None, :])
     rows = rows.reshape(s, -1)
-    kc = jnp.take(kp, rows, axis=0).astype(jnp.float32)
-    vc = jnp.take(vp, rows, axis=0).astype(jnp.float32)
+
+    def image(pool):
+        img = jnp.take(pool, rows, axis=0)[..., :h * hd]
+        return img.reshape(s, -1, h, hd).astype(jnp.float32)
+
+    kc, vc = image(kp), image(vp)
     if ks is not None:
         kc = kc * jnp.take(ks, rows, axis=0)[..., None]
         vc = vc * jnp.take(vs, rows, axis=0)[..., None]
@@ -130,11 +135,17 @@ def _paged_cases(size):
     from theanompi_tpu.ops.pallas_paged import paged_decode_attention
     from theanompi_tpu.parallel.quantize import quantize_blocks
 
-    s, h, hd, bs, nt, nb = (
-        (32, 8, 64, 32, 32, 257) if size == "real" else (3, 4, 8, 4, 5, 10)
+    s, hd, bs, nt, nb = (
+        (32, 64, 32, 32, 257) if size == "real" else (3, 8, 4, 5, 10)
     )
 
-    def make(quant):
+    def flat(x):
+        """(rows, H, hd) -> the pool's (rows, width): heads side by
+        side, padded with zeros to a multiple of 128 lanes."""
+        x = x.reshape(x.shape[0], -1)
+        return jnp.pad(x, ((0, 0), (0, -x.shape[1] % 128)))
+
+    def make(quant, h):
         def make_args(key):
             kq, kk, kv, kt, kl = jax.random.split(key, 5)
             q = jax.random.normal(kq, (s, h, hd), jnp.float32)
@@ -146,9 +157,9 @@ def _paged_cases(size):
             lengths = jax.random.randint(kl, (s,), 0, nt * bs, jnp.int32)
             lengths = lengths.at[0].set(0).at[-1].set(nt * bs - 1)
             if not quant:
-                return q, kp, vp, tables, lengths
+                return q, flat(kp), flat(vp), tables, lengths
             (kq8, ks), (vq8, vs) = quantize_blocks(kp), quantize_blocks(vp)
-            return q, kq8, vq8, tables, lengths, ks, vs
+            return q, flat(kq8), flat(vq8), tables, lengths, ks, vs
         return make_args
 
     def kernel(q, kp, vp, tables, lengths, ks=None, vs=None):
@@ -160,13 +171,20 @@ def _paged_cases(size):
     def oracle(q, kp, vp, tables, lengths, ks=None, vs=None):
         return _paged_oracle(q, kp, vp, tables, lengths, bs=bs, ks=ks, vs=vs)
 
+    # 8 heads: the width is a multiple of 128 as it stands (the tiny
+    # size pads 32 to 128); 25 heads of 64 are GPT-2 XL's 1,600, which
+    # pads to 1,664 (tiny: 5 heads of 8, 40 to 128)
+    h_wide, h_odd = (8, 25) if size == "real" else (4, 5)
     return [
-        KernelCase("paged_decode_f32", make(False), kernel, oracle,
+        KernelCase(name, make(quant, h), kernel, oracle,
                    atol=1e-4, rtol=1e-4, kernel_precision="highest",
-                   oracle_precision="highest"),
-        KernelCase("paged_decode_int8", make(True), kernel, oracle,
-                   atol=1e-4, rtol=1e-4, kernel_precision="highest",
-                   oracle_precision="highest"),
+                   oracle_precision="highest")
+        for name, quant, h in (
+            ("paged_decode_f32", False, h_wide),
+            ("paged_decode_int8", True, h_wide),
+            ("paged_decode_f32_h25", False, h_odd),
+            ("paged_decode_int8_h25", True, h_odd),
+        )
     ]
 
 

@@ -27,13 +27,21 @@ to ``-inf`` exactly like the XLA path's ``att_mask``.
 so CPU CI exercises the same kernel code — the tier-1 contract is
 allclose against the XLA gather path on both fp32 and int8 pools.
 
+The pool is read as the engine stores it: one ``(rows, width)`` array
+a layer, a row holding a token's heads side by side (``width`` =
+heads · head_dim rounded up to 128 lanes), so a block is ``(block_size,
+width)`` dense — no ``(heads, head_dim)`` minor dimensions for the
+device to tile and pad, and no reshape of the pool in front of the
+kernel (which would be a copy of it).  Per-head scores come from the
+flat rows by spreading the query block-diagonally (``_paged_kernel``).
+
 Scope: the kernel is a SINGLE-SHARD program.  ``supported()`` gates on
 one device — a dp-sharded pool or tp-sharded heads would need a
 shard_map wrapper that is not built, so the engine selects the XLA
 path there (see docs/serving.md for the selection matrix).  Both pool
 dtypes compile under Mosaic for a v5e at the serving widths (block 32,
-8 heads, head 64 — the int8 block is exactly one (32, 128)-tiled
-plane per head pair; ``tests/test_tpu_compile.py``).
+head 64, at 8 heads and at GPT-2 XL's 25: 1,600 numbers stored 1,664
+wide; ``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -63,97 +71,78 @@ def supported(mesh=None) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# kernel bodies
+# kernel body
 # ---------------------------------------------------------------------------
 
-def _attend_block(q, k_blk, v_blk, length, j, bs, scale,
-                  m_ref, d_ref, acc_ref):
-    """Fold one (bs, H, hd) K/V block into the online-softmax carry.
+def _head_columns(h, hd, w):
+    """(H, W) bool: column ``c`` of a flat row belongs to head ``h``
+    (``h·hd <= c < (h+1)·hd``); the padding columns belong to none."""
+    col = lax.broadcasted_iota(jnp.int32, (h, w), 1)
+    lo = lax.broadcasted_iota(jnp.int32, (h, w), 0) * hd
+    return (col >= lo) & (col < lo + hd)
 
-    ``q`` (H, hd) fp32; rows of the block live at global positions
-    ``j*bs + [0, bs)`` and mask against ``length`` (the incoming
-    token's position — it attends to itself, like the XLA att_mask).
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *refs,
+                  bs, nt, scale, hd, quant):
+    """One lane's online softmax over its block stream, on flat rows.
+
+    A pool row holds the heads side by side, so per-head scores come
+    from two plain products a block and no reshape that splits lanes:
+    the lane's query is spread block-diagonally (``qh`` (H, W): ``q_h``
+    in head ``h``'s ``hd`` columns, zeros elsewhere), ``qh · Kᵀ`` is the
+    (H, bs) scores, and ``p · V`` is (H, W), of which head ``h``'s own
+    columns are kept at the end.  int8 payloads: the per-row/per-head
+    scales multiply the scores (K) and the probabilities (V) — the
+    same numbers as dequantizing the block first, since row ``h`` of
+    either product only keeps head ``h``'s columns.
     """
-    h, _ = q.shape
-    kb = k_blk.transpose(1, 0, 2)  # (H, bs, hd)
-    vb = v_blk.transpose(1, 0, 2)
-    s = lax.dot_general(
-        q[:, None, :], kb, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )[:, 0, :] * scale  # (H, bs)
-    pos = j * bs + lax.broadcasted_iota(jnp.int32, (h, kb.shape[1]), 1)
-    s = jnp.where(pos <= length, s, _NEG_INF)
-    m_prev = m_ref[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    d_ref[:, 0] = d_ref[:, 0] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + lax.dot_general(
-        p[:, None, :], vb, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )[:, 0, :]
-    m_ref[:, 0] = m_new
-
-
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, d_ref, acc_ref, *, bs, nt, scale):
+    if quant:
+        ks_ref, vs_ref, o_ref, qh_ref, m_ref, d_ref, acc_ref = refs
+    else:
+        o_ref, qh_ref, m_ref, d_ref, acc_ref = refs
     s_idx = pl.program_id(0)
     j = pl.program_id(1)
+    h, w = acc_ref.shape
 
     @pl.when(j == 0)
     def _init():
+        qh_ref[...] = jnp.where(
+            _head_columns(h, hd, w), q_ref[0].astype(jnp.float32), 0.0)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         d_ref[...] = jnp.zeros_like(d_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # the incoming token's position: it attends to itself, like the
+    # XLA att_mask
     length = len_ref[s_idx]
 
     @pl.when(j * bs <= length)  # fully-masked blocks are elided
     def _work():
-        _attend_block(
-            q_ref[0].astype(jnp.float32),
-            k_ref[...].astype(jnp.float32),
-            v_ref[...].astype(jnp.float32),
-            length, j, bs, scale, m_ref, d_ref, acc_ref,
-        )
+        s = lax.dot_general(
+            qh_ref[...], k_ref[...].astype(jnp.float32),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * scale  # (H, bs)
+        if quant:
+            s = s * ks_ref[...].T
+        pos = j * bs + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos <= length, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        d_ref[...] = d_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if quant:
+            p = p * vs_ref[...].T
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p, v_ref[...].astype(jnp.float32),
+            preferred_element_type=jnp.float32)  # (H, W)
+        m_ref[...] = m_new
 
     @pl.when(j == nt - 1)
     def _fin():
-        o_ref[0] = (
-            acc_ref[...] / d_ref[:, 0][:, None]
-        ).astype(o_ref.dtype)
-
-
-def _paged_kernel_i8(tbl_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                     vs_ref, o_ref, m_ref, d_ref, acc_ref,
-                     *, bs, nt, scale):
-    """int8 payload variant: per-row/per-head scales dequantize the
-    block in VMEM — identical recurrence after that."""
-    s_idx = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        d_ref[...] = jnp.zeros_like(d_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[s_idx]
-
-    @pl.when(j * bs <= length)
-    def _work():
-        k_blk = k_ref[...].astype(jnp.float32) * ks_ref[...][..., None]
-        v_blk = v_ref[...].astype(jnp.float32) * vs_ref[...][..., None]
-        _attend_block(
-            q_ref[0].astype(jnp.float32), k_blk, v_blk,
-            length, j, bs, scale, m_ref, d_ref, acc_ref,
-        )
-
-    @pl.when(j == nt - 1)
-    def _fin():
-        o_ref[0] = (
-            acc_ref[...] / d_ref[:, 0][:, None]
-        ).astype(o_ref.dtype)
+        own = jnp.where(
+            _head_columns(h, hd, w), acc_ref[...] / d_ref[...], 0.0)
+        o_ref[0] = jnp.sum(own, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -176,8 +165,14 @@ def paged_decode_attention(
     """softmax(q·Kᵀ·scale)·V over each lane's paged K/V, one layer.
 
     - ``q`` (S, H, hd): the decode tick's single query per lane.
-    - ``k_pool``/``v_pool`` (R, H, hd): the flat row pool for this
-      layer (R = n_blocks · block_size), fp32/compute dtype or int8.
+    - ``k_pool``/``v_pool`` (R, W >= H·hd): the flat row pool for this
+      layer (R = n_blocks · block_size), one row a token, ``[head 0 |
+      head 1 | … | padding]``, fp32/compute dtype or int8.  (``W`` a
+      multiple of 128: the device would pad a narrower row to as much
+      anyway, and it lays a tall array whose rows are no multiple of
+      128 out column-major, which every call would then copy to
+      row-major and back; a ``(R, H, hd)`` pool has its two minor
+      dimensions tiled, 2.6 times the bytes at 25 heads of 64.)
     - ``tables`` (S, NT) int32: per-lane block ids (0 = trash block).
     - ``lengths`` (S,) int32: the incoming token's position; rows at
       positions <= length attend (the token was scattered before the
@@ -185,56 +180,62 @@ def paged_decode_attention(
     - ``k_scale``/``v_scale`` (R, H) fp32: required when the pools are
       int8 — per-row/per-head dequant scales.
 
-    Returns fp32 (S, H, hd).  Numerics contract (tier-1 pinned):
-    allclose to the XLA gather path on both pool dtypes.
+    A grid step fetches one ``(block_size, W)`` block of each pool
+    through the table-driven index map.  Returns fp32 (S, H, hd).
+    Numerics contract (tier-1 pinned): allclose to the XLA gather path
+    on both pool dtypes.
     """
     s, h, hd = q.shape
+    w = int(k_pool.shape[1])
     nt = int(tables.shape[1])
     bs = int(block_size)
     quant = k_pool.dtype == jnp.int8
     if quant and (k_scale is None or v_scale is None):
         raise ValueError("int8 pools need k_scale/v_scale")
+    if k_pool.ndim != 2 or w < h * hd:
+        raise ValueError(
+            f"pools are (rows, width >= heads * head_dim = {h * hd}), "
+            f"got {k_pool.shape}"
+        )
     sc = resolve_scale(scale, hd)
 
     def _pool_map(si, j, tbl, ln):
-        return (tbl[si, j], 0, 0)
-
-    def _scale_map(si, j, tbl, ln):
         return (tbl[si, j], 0)
 
     def _row_map(si, j, tbl, ln):
         return (si, 0, 0)
 
+    # the lane's query as one flat row, like the pool's
+    q_flat = jnp.pad(q.reshape(s, 1, h * hd), ((0, 0), (0, 0), (0, w - h * hd)))
     in_specs = [
-        pl.BlockSpec((1, h, hd), _row_map),          # q
-        pl.BlockSpec((bs, h, hd), _pool_map),        # k block
-        pl.BlockSpec((bs, h, hd), _pool_map),        # v block
+        pl.BlockSpec((1, 1, w), _row_map),           # q
+        pl.BlockSpec((bs, w), _pool_map),            # k block
+        pl.BlockSpec((bs, w), _pool_map),            # v block
     ]
-    args = [q, k_pool, v_pool]
+    args = [q_flat, k_pool, v_pool]
     if quant:
         in_specs += [
-            pl.BlockSpec((bs, h), _scale_map),       # k scales
-            pl.BlockSpec((bs, h), _scale_map),       # v scales
+            pl.BlockSpec((bs, h), _pool_map),        # k scales
+            pl.BlockSpec((bs, h), _pool_map),        # v scales
         ]
         args += [k_scale, v_scale]
-        kernel = functools.partial(_paged_kernel_i8, bs=bs, nt=nt, scale=sc)
-    else:
-        kernel = functools.partial(_paged_kernel, bs=bs, nt=nt, scale=sc)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # tables, lengths
         grid=(s, nt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, hd), _row_map),
+        out_specs=pl.BlockSpec((1, 1, w), _row_map),
         scratch_shapes=[
+            pltpu.VMEM((h, w), jnp.float32),   # block-diagonal query
             pltpu.VMEM((h, 1), jnp.float32),   # running max
             pltpu.VMEM((h, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((h, hd), jnp.float32),  # output accumulator
+            pltpu.VMEM((h, w), jnp.float32),   # output accumulator
         ],
     )
-    return pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, bs=bs, nt=nt, scale=sc, hd=hd,
+                          quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, h, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((s, 1, w), jnp.float32),
         interpret=(not platform.on_tpu()) if interpret is None else interpret,
         # the device operation's name in a profile starts with this
         name="paged_decode_attn_i8" if quant else "paged_decode_attn",
@@ -242,6 +243,7 @@ def paged_decode_attention(
         jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
         *args,
     )
+    return out[:, 0, :h * hd].reshape(s, h, hd)
 
 
 # ---------------------------------------------------------------------------

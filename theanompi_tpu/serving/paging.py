@@ -12,9 +12,13 @@ memory.
 
 Three pieces:
 
-- **BlockPool** — host-side allocator over a device-side flat row pool
-  ``k``/``v`` of shape ``(n_layers, n_blocks * block_size, heads,
-  head_dim)``.  Block 0 is reserved as the *trash block*: masked or
+- **BlockPool** — host-side allocator over a device-side flat row pool:
+  ``k``/``v``, one array a layer of shape ``(n_blocks * block_size,
+  row_width)``, a row holding a token's heads side by side
+  (``heads * head_dim`` numbers, rounded up to 128 lanes — see
+  ``PagedServingEngine.init_state``).  The layers' arrays are separate
+  leaves of the state (never stacked), each donated and updated in
+  place by its program.  Block 0 is reserved as the *trash block*: masked or
   inactive lanes scatter their garbage there, so a freed (reallocated)
   block can never be corrupted by a stale lane.  Refcounted — a block
   shared by N sequences (prefix reuse) frees only when the last
@@ -103,14 +107,15 @@ KV_DTYPES = ("fp32", "int8")
 # pending lanes calls it again (``scheduler._prefill_pending``).  One
 # width, not a ladder: every (rows, bucket) pair is a program to build
 # and to warm, and a pair first met under load would compile there.
-# What a burst pays for it is one call's fixed cost (the weights read
-# and cast, the pool's copy) per ``PREFILL_ROWS`` arrivals; that fixed
-# cost is the decode program's too, and is what holding the weights in
-# the compute dtype and updating the pool in place take away, after
-# which eight narrow calls cost what one wide call does.  Read on a
-# v5e with GPT-2 XL (PERF.md, PR 26): a call of 2 / 4 / 8 / 32 rows
-# takes 68 / 75 / 106 / 277 ms beside a decode tick of 74; 2 serves a
-# trickle 2 % faster than 4 and doubles what a burst pays.
+# What a burst pays for it is one call's fixed cost per
+# ``PREFILL_ROWS`` arrivals; that fixed cost is the decode program's
+# too: the float32 weights read and cast (what holding them in the
+# compute dtype takes away), and until PR 30 a copy of the whole pool.
+# Read on a v5e with GPT-2 XL (PERF.md, PR 26): a call of 2 / 4 / 8 /
+# 32 rows took 68 / 75 / 106 / 277 ms beside a decode tick of 74; 2
+# served a trickle 2 % faster than 4 and doubled what a burst pays.
+# With the pool updated in place (PERF.md, PR 30) a call of 4 rows
+# takes 31 ms beside a decode tick of 19.
 PREFILL_ROWS = 4
 
 
@@ -430,8 +435,15 @@ class PagedServingEngine(ServingEngine):
             if TP_AXIS in self.mesh.shape and int(self.mesh.shape[TP_AXIS]) > 1
             else None
         )
-        self.pool_spec = P(None, row_ax, head_ax, None)
-        self.scale_spec = P(None, row_ax, head_ax)
+        # one layer's pool is (rows, row_width) and its int8 scale plane
+        # (rows, heads); one spec for both: rows over dp, a row's heads
+        # over tp
+        self.pool_spec = P(row_ax, head_ax)
+        # a row holds its heads side by side, rounded up to 128 lanes
+        # (``init_state``); heads split over tp keep their exact width,
+        # so that a shard is a whole number of heads
+        width = self.n_heads * self.head_dim
+        self.row_width = width if head_ax else -(-width // 128) * 128
         # a latent model's pool, programs and counters (serving/latent.py).
         # `last_counters` is what the newest
         # program call returned beside its logits (None: no such model),
@@ -469,32 +481,41 @@ class PagedServingEngine(ServingEngine):
         return self.compute_dtype or jnp.float32
 
     def init_state(self):
-        """Device block pool: ``k``/``v`` of (layers, n_blocks·bs,
-        heads, head_dim), allocated already sharded; ``kv_dtype='int8'``
-        adds the per-row/per-head scale planes ``ks``/``vs``.  Lengths
-        and block tables stay host-side (tiny ints shipped per call —
-        they are *data*, so shipping them can never recompile
-        anything).  A latent model: ``kv``, one (rows, kv_rank + rope)
-        array a layer (``serving/latent.py``)."""
+        """Device block pool: ``k``/``v``, each one array a layer,
+        ``(n_blocks · block_size, row_width)``, allocated already
+        sharded.  A resident token holds one row a layer and side: its
+        heads side by side, ``heads · head_dim`` numbers.  ``row_width``
+        is that rounded up to 128 lanes: the device would pad a narrower
+        row to as much anyway, and it lays a tall array whose rows are
+        no multiple of 128 out column-major, which every program would
+        then copy to row-major and back (a ``(…, heads, head_dim)`` pool
+        is worse still: its two minor dimensions are tiled, 2.6 times
+        the bytes at 25 heads of 64).  The layers' arrays are separate
+        leaves of the state (never stacked), each donated and updated
+        in place by its program.  ``kv_dtype='int8'`` adds the
+        per-row/per-head scale planes ``ks``/``vs``, likewise one
+        ``(rows, heads)`` array a layer.  Lengths and block tables stay
+        host-side (tiny ints shipped per call — they are *data*, so
+        shipping them can never recompile anything).  A latent model:
+        ``kv``, one (rows, kv_rank + rope) array a layer
+        (``serving/latent.py``)."""
         if self._latent is not None:
             return self._latent.init_state()
         dt = (
             jnp.int8 if self.kv_dtype == "int8" else self._kv_compute_dtype()
         )
+        rows = self.n_blocks * self.block_size
+
         sh = NamedSharding(self.mesh, self.pool_spec)
-        shape = (
-            self.n_layers, self.n_blocks * self.block_size,
-            self.n_heads, self.head_dim,
-        )
-        state = {
-            "k": jnp.zeros(shape, dt, device=sh),
-            "v": jnp.zeros(shape, dt, device=sh),
-        }
+
+        def leaves(width, dtype):
+            return [jnp.zeros((rows, width), dtype, device=sh)
+                    for _ in range(self.n_layers)]
+
+        state = {side: leaves(self.row_width, dt) for side in ("k", "v")}
         if self.kv_dtype == "int8":
-            ssh = NamedSharding(self.mesh, self.scale_spec)
-            sshape = shape[:-1]
-            state["ks"] = jnp.zeros(sshape, jnp.float32, device=ssh)
-            state["vs"] = jnp.zeros(sshape, jnp.float32, device=ssh)
+            for side in ("ks", "vs"):
+                state[side] = leaves(self.n_heads, jnp.float32)
         return state
 
     def kv_block_bytes(self) -> int:
@@ -507,11 +528,10 @@ class PagedServingEngine(ServingEngine):
             1 if self.kv_dtype == "int8"
             else jnp.dtype(self._kv_compute_dtype()).itemsize
         )
-        rows = self.block_size * self.n_heads
-        b = 2 * self.n_layers * rows * self.head_dim * payload
+        row = self.row_width * payload  # the stored width, padding and all
         if self.kv_dtype == "int8":
-            b += 2 * self.n_layers * rows * 4  # fp32 scale per (row, head)
-        return b
+            row += self.n_heads * 4  # fp32 scale per (row, head)
+        return 2 * self.n_layers * self.block_size * row
 
     def blocks_at_budget(self, budget_bytes: int) -> int:
         """How many pool blocks fit in ``budget_bytes`` of cache HBM at
@@ -552,31 +572,56 @@ class PagedServingEngine(ServingEngine):
         rows = tables[:, :, None] * bs + jnp.arange(bs)[None, None, :]
         return rows.reshape(tables.shape[0], -1)
 
-    def _kv_write(self, pool_l, scale_l, rows, wr):
-        """Scatter freshly-computed K or V ``rows`` (N, H, hd) into one
-        layer's pool at row indices ``wr``.  fp32 path: a cast +
-        scatter, bit-identical to PR 8.  int8 path: the
-        ``quantize_blocks`` codec over head_dim (per-row/per-head amax
-        scale) — quantized ONCE on write, so every later reader (XLA
-        gather, Pallas kernel, a prefix-sharing sibling) sees the same
-        bytes."""
+    def _pool_leaves(self, state):
+        """The state's leaves as lists that a program replaces layer by
+        layer (``_kv_write``; a float pool has no scale planes: ``None``
+        a layer), and the dtype in which attention images are gathered."""
+        pool = {side: list(leaves) for side, leaves in state.items()}
+        for side in ("ks", "vs"):
+            pool.setdefault(side, [None] * self.n_layers)
+        img_dt = (
+            self._kv_compute_dtype() if self.kv_dtype == "int8"
+            else pool["k"][0].dtype
+        )
+        return pool, img_dt
+
+    def _kv_write(self, pool, side, i, rows, wr):
+        """Scatter freshly-computed K or V ``rows`` (N, H, hd) (``side``
+        ``'k'`` or ``'v'``) into layer ``i``'s ``(rows, row_width)`` pool
+        at row indices ``wr``: each row's heads laid side by side and
+        padded with zeros to ``row_width``, written into the layer's own
+        donated leaf (in place), which takes the old one's place in
+        ``pool``.  Returns the layer's new pool and scale plane.  fp32
+        path: a cast + scatter, the values bit-identical to PR 8.  int8
+        path: the ``quantize_blocks`` codec over head_dim
+        (per-row/per-head amax scale, into the layer's ``(rows, heads)``
+        scale plane) — quantized ONCE on write, so every later reader
+        (XLA gather, Pallas kernel, a prefix-sharing sibling) sees the
+        same bytes."""
+        pool_l, scale_l = pool[side][i], pool[side + "s"][i]
         if self.kv_dtype == "int8":
             from theanompi_tpu.parallel.quantize import quantize_blocks
 
-            q, s = quantize_blocks(rows.astype(jnp.float32))
-            return pool_l.at[wr].set(q), scale_l.at[wr].set(s)
-        return pool_l.at[wr].set(rows.astype(pool_l.dtype)), scale_l
+            rows, s = quantize_blocks(rows.astype(jnp.float32))
+            scale_l = scale_l.at[wr].set(s)
+        flat = rows.astype(pool_l.dtype).reshape(rows.shape[0], -1)
+        flat = jnp.pad(flat, ((0, 0), (0, self.row_width - flat.shape[1])))
+        pool[side][i], pool[side + "s"][i] = pool_l.at[wr].set(flat), scale_l
+        return pool[side][i], scale_l
 
     def _kv_image(self, pool_l, scale_l, gr_flat, n, dtype):
-        """Gather the (n, t_pad, H, hd) attention image for one layer —
-        dequantizing int8 payloads against their gathered scales."""
-        img = jnp.take(pool_l, gr_flat, axis=0)
+        """Gather the attention image for one layer from its ``(rows,
+        row_width)`` pool, and view it as (n, t_pad, H, hd) only after
+        the gather (the lanes' rows, not the pool) — dequantizing int8
+        payloads against their gathered scales."""
+        h, hd = self.n_heads, self.head_dim
+        img = jnp.take(pool_l, gr_flat, axis=0)[:, :h * hd]
+        img = img.reshape(n, self.t_pad, h, hd)
         if self.kv_dtype == "int8":
             sc = jnp.take(scale_l, gr_flat, axis=0)
-            img = img.astype(jnp.float32) * sc[..., None]
-        return img.astype(dtype).reshape(
-            n, self.t_pad, self.n_heads, self.head_dim
-        )
+            img = img.astype(jnp.float32) * sc.reshape(
+                n, self.t_pad, h)[..., None]
+        return img.astype(dtype)
 
     def _paged_chunk_fn(
         self, params, state, tokens, tables, p0, true_len, active,
@@ -620,13 +665,7 @@ class PagedServingEngine(ServingEngine):
         # cached history (earlier chunks / prefix-hit blocks) plus the
         # intra-chunk triangle, exactly like one full-prompt pass
         mask = jnp.arange(self.t_pad)[None, None, :] <= positions[:, :, None]
-        pk, pv = state["k"], state["v"]
-        pks = state.get("ks")
-        pvs = state.get("vs")
-        img_dt = (
-            self._kv_compute_dtype() if self.kv_dtype == "int8" else pk.dtype
-        )
-        new_k, new_v, new_ks, new_vs = [], [], [], []
+        pool, img_dt = self._pool_leaves(state)
         # named scopes are metadata on the same operations: a profile
         # groups by them (layer<i>/qkv, .../cast_weights inside it, ...)
         for i, bp in enumerate(blocks):
@@ -638,13 +677,9 @@ class PagedServingEngine(ServingEngine):
                     v = self._proj(y, bp["attn"]["wv"]).reshape(p_, c_, h, hd)
                 with jax.named_scope("pool_update"):
                     pk_l, pks_l = self._kv_write(
-                        pk[i], None if pks is None else pks[i],
-                        k.reshape(p_ * c_, h, hd), wr,
-                    )
+                        pool, "k", i, k.reshape(p_ * c_, h, hd), wr)
                     pv_l, pvs_l = self._kv_write(
-                        pv[i], None if pvs is None else pvs[i],
-                        v.reshape(p_ * c_, h, hd), wr,
-                    )
+                        pool, "v", i, v.reshape(p_ * c_, h, hd), wr)
                 with jax.named_scope("paged_attn"):
                     kc = self._kv_image(pk_l, pks_l, gr, p_, img_dt)
                     vc = self._kv_image(pv_l, pvs_l, gr, p_, img_dt)
@@ -663,14 +698,7 @@ class PagedServingEngine(ServingEngine):
                         o.reshape(p_, c_, h * hd), bp["attn"]["wo"])
                 with jax.named_scope("mlp"):
                     x = x + self._mlp(bp, self._ln(bp["ln2"], x))
-            new_k.append(pk_l)
-            new_v.append(pv_l)
-            new_ks.append(pks_l)
-            new_vs.append(pvs_l)
-        out = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-        if self.kv_dtype == "int8":
-            out["ks"] = jnp.stack(new_ks)
-            out["vs"] = jnp.stack(new_vs)
+        out = {side: pool[side] for side in state}
         with jax.named_scope("head"):
             if all_logits:
                 logits = self._head(lnf, head, x)  # (P, C, V)
@@ -712,16 +740,10 @@ class PagedServingEngine(ServingEngine):
         wr = jnp.where(active, blk * bs + pos_idx % bs, TRASH_BLOCK)
         gr = self._gather_rows(tables).reshape(-1)  # (S·t_pad,)
         att_mask = jnp.arange(self.t_pad)[None, :] <= pos_idx[:, None]
-        pk, pv = state["k"], state["v"]
-        pks = state.get("ks")
-        pvs = state.get("vs")
-        img_dt = (
-            self._kv_compute_dtype() if self.kv_dtype == "int8" else pk.dtype
-        )
+        pool, img_dt = self._pool_leaves(state)
         use_pallas = self.paged_attn_effective == "pallas"
         if use_pallas:
             from theanompi_tpu.ops import pallas_paged
-        new_k, new_v, new_ks, new_vs = [], [], [], []
         for i, bp in enumerate(blocks):  # scopes as in _paged_chunk_fn
             with jax.named_scope(f"layer{i}"):
                 with jax.named_scope("qkv"):
@@ -730,12 +752,8 @@ class PagedServingEngine(ServingEngine):
                     k = self._proj(y, bp["attn"]["wk"]).reshape(s_, h, hd)
                     v = self._proj(y, bp["attn"]["wv"]).reshape(s_, h, hd)
                 with jax.named_scope("pool_update"):
-                    pk_l, pks_l = self._kv_write(
-                        pk[i], None if pks is None else pks[i], k, wr
-                    )
-                    pv_l, pvs_l = self._kv_write(
-                        pv[i], None if pvs is None else pvs[i], v, wr
-                    )
+                    pk_l, pks_l = self._kv_write(pool, "k", i, k, wr)
+                    pv_l, pvs_l = self._kv_write(pool, "v", i, v, wr)
                 with jax.named_scope("paged_attn"):
                     if use_pallas:
                         o = pallas_paged.paged_decode_attention(
@@ -761,14 +779,7 @@ class PagedServingEngine(ServingEngine):
                         o.reshape(s_, h * hd), bp["attn"]["wo"])
                 with jax.named_scope("mlp"):
                     x = x + self._mlp(bp, self._ln(bp["ln2"], x))
-            new_k.append(pk_l)
-            new_v.append(pv_l)
-            new_ks.append(pks_l)
-            new_vs.append(pvs_l)
-        out = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-        if self.kv_dtype == "int8":
-            out["ks"] = jnp.stack(new_ks)
-            out["vs"] = jnp.stack(new_vs)
+        out = {side: pool[side] for side in state}
         with jax.named_scope("head"):
             logits = self._head(lnf, head, x)
         return out, logits
