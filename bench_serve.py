@@ -7,17 +7,18 @@ through the continuous-batching scheduler, request latency (TTFT /
 TPOT, p50/p99) under a synthetic open-loop Poisson arrival process —
 the standard serving-bench shape (requests arrive on their own clock;
 a backed-up server cannot slow the arrivals down) — and, since the
-paged KV cache landed, two capacity questions the contiguous engine
-could not even pose:
+paged KV cache landed, two capacity questions a cache of one
+worst-case region a slot could not even pose:
 
 - **long-tail concurrency** — at EQUAL cache memory, how many
-  sequences can each engine hold simultaneously under a mixed-length
-  (mostly-short, occasionally-huge) burst?  The contiguous engine
-  reserves ``max_len`` rows per slot, so its answer is its slot
-  count; the paged engine allocates blocks for what a sequence can
-  actually need.  ``detail.paged.long_tail.concurrency_ratio`` is the
-  measured paged/contiguous peak-concurrency ratio (the perf-gate
-  serve leg requires >= 2).
+  sequences can the engine hold simultaneously under a mixed-length
+  (mostly-short, occasionally-huge) burst?  A cache that reserves
+  ``max_len`` rows per slot (the "contiguous" reference) holds its
+  slot count by construction; the paged engine allocates blocks for
+  what a sequence can actually need.
+  ``detail.paged.long_tail.concurrency_ratio`` is the measured paged
+  peak concurrency over that reference's (the perf-gate serve leg
+  requires >= 2).
 - **prefix reuse** — a shared system prompt is prefilled once and its
   immutable blocks refcounted across requests.
   ``detail.paged.prefix`` records the measured hit rate and the
@@ -70,8 +71,8 @@ Protocol:
   values; loader round-trips are covered by tests/test_serving.py).
 - Headline workload: exponential inter-arrival gaps at
   ``arrival_rate_rps``, prompt lengths uniform over the engine's
-  bucket range, fixed ``max_new_tokens`` — driven through the PAGED
-  engine (``THEANOMPI_BENCH_SERVE_ENGINE=contiguous`` to flip back).
+  bucket range, fixed ``max_new_tokens`` — driven through
+  ``PagedServingEngine``.
 - Long-tail workload knob: ``long_tail_frac_long`` controls the
   fraction of near-``max_len`` prompts in the burst (default 0.25 —
   raise it to stress block churn, lower it to stress lane count).
@@ -882,7 +883,7 @@ def main(argv=None):
     from theanompi_tpu.runtime.recorder import Recorder
     from theanompi_tpu.serving import (
         ContinuousBatchingScheduler, PagedServingEngine, Request,
-        ServingEngine, ServingMetrics,
+        ServingMetrics,
     )
 
     cfg = dict(
@@ -892,26 +893,20 @@ def main(argv=None):
         n_synth_val=1, comm_probe=False, print_freq=10_000,
     )
     model = TransformerLM(config=cfg)
-    engine_kind = (
-        os.environ.get("THEANOMPI_BENCH_SERVE_ENGINE") or "paged"
-    ).lower()
-    # contiguous reference: n_slots worst-case regions = the equal-
-    # memory budget every comparison below is pinned to
+    # the reference a paged pool is compared with: a cache that
+    # reserves one worst-case (max_len) region a slot.  n_slots such
+    # regions = the equal-memory budget every comparison below is
+    # pinned to
     contiguous_blocks = knobs["n_slots"] * (
         knobs["max_len"] // knobs["block_size"]
     )
-    if engine_kind == "contiguous":
-        engine = ServingEngine(
-            model, n_slots=knobs["n_slots"], max_len=knobs["max_len"]
-        )
-    else:
-        engine = PagedServingEngine(
-            model, n_slots=knobs["paged_slots"], max_len=knobs["max_len"],
-            block_size=knobs["block_size"],
-            n_blocks=contiguous_blocks + 1,  # +1: reserved trash block
-            prefill_chunk=knobs["prefill_chunk"],
-            kv_dtype=tune_kv_dtype,
-        )
+    engine = PagedServingEngine(
+        model, n_slots=knobs["paged_slots"], max_len=knobs["max_len"],
+        block_size=knobs["block_size"],
+        n_blocks=contiguous_blocks + 1,  # +1: reserved trash block
+        prefill_chunk=knobs["prefill_chunk"],
+        kv_dtype=tune_kv_dtype,
+    )
     rec = Recorder(verbose=False)
     metrics = ServingMetrics(recorder=rec)
     sched = ContinuousBatchingScheduler(engine, metrics=metrics)
@@ -951,118 +946,103 @@ def main(argv=None):
     observability.disable_request_tracking()
 
     # ---- paged capacity probes (CPU bench acceptance evidence) -------
-    paged_detail = None
-    if engine_kind != "contiguous":
-        wl_rng = np.random.RandomState(_SEED_BASE + 1)
-        lt_prompts = _long_tail_prompts(wl_rng, knobs)
-        # paged at EQUAL cache memory: the accounted pool is capped to
-        # exactly the contiguous engine's row budget
-        lt_paged = ContinuousBatchingScheduler(
-            engine, pool=engine.make_pool(contiguous_blocks + 1)
-        )
-        _drive_burst(lt_paged, Request, lt_prompts,
-                     knobs["long_tail_new_tokens"], "lt")
-        # the contiguous engine on the SAME burst (its peak concurrency
-        # is structurally capped at n_slots — measured, not assumed)
-        eng_c = ServingEngine(
-            model, n_slots=knobs["n_slots"], max_len=knobs["max_len"]
-        )
-        lt_contig = ContinuousBatchingScheduler(eng_c)
-        _drive_burst(lt_contig, Request, lt_prompts,
-                     knobs["long_tail_new_tokens"], "lt")
-        ratio = (
-            lt_paged.stats["peak_concurrent"]
-            / max(1, lt_contig.stats["peak_concurrent"])
-        )
+    wl_rng = np.random.RandomState(_SEED_BASE + 1)
+    lt_prompts = _long_tail_prompts(wl_rng, knobs)
+    # paged at EQUAL cache memory: the accounted pool is capped to
+    # exactly the contiguous engine's row budget
+    lt_paged = ContinuousBatchingScheduler(
+        engine, pool=engine.make_pool(contiguous_blocks + 1)
+    )
+    _drive_burst(lt_paged, Request, lt_prompts,
+                 knobs["long_tail_new_tokens"], "lt")
+    # a worst-case region a slot holds one request a slot, whatever
+    # its length: that cache's peak concurrency on the SAME burst is
+    # min(n_slots, requests) by construction
+    contiguous_peak = min(knobs["n_slots"], len(lt_prompts))
+    ratio = lt_paged.stats["peak_concurrent"] / max(1, contiguous_peak)
 
-        # shared-system-prompt workload: one distinct prefix, many
-        # tails; reuse ON vs OFF over the same requests
-        sys_prompt = wl_rng.randint(
-            0, knobs["vocab_size"], size=knobs["prefix_len"]
+    # shared-system-prompt workload: one distinct prefix, many
+    # tails; reuse ON vs OFF over the same requests
+    sys_prompt = wl_rng.randint(
+        0, knobs["vocab_size"], size=knobs["prefix_len"]
+    ).tolist()
+    pf_prompts = [
+        sys_prompt + wl_rng.randint(
+            0, knobs["vocab_size"], size=knobs["prefix_tail"]
         ).tolist()
-        pf_prompts = [
-            sys_prompt + wl_rng.randint(
-                0, knobs["vocab_size"], size=knobs["prefix_tail"]
-            ).tolist()
-            for _ in range(knobs["prefix_requests"])
-        ]
-        pf_sched = ContinuousBatchingScheduler(engine)
-        for j, p in enumerate(pf_prompts):
-            pf_sched.submit(Request(id=f"pf{j}", prompt=list(p),
-                                    max_new_tokens=knobs["prefix_new_tokens"]))
-            pf_sched.step()  # arrivals spaced a tick apart: reuse is
-            # only possible once the first prefix is resident
-        pf_out = pf_sched.run()
-        no_reuse = ContinuousBatchingScheduler(
-            engine, pool=engine.make_pool()
-        )
-        no_reuse.prefix = None  # same engine, reuse disabled
-        for j, p in enumerate(pf_prompts):
-            no_reuse.submit(Request(id=f"pf{j}", prompt=list(p),
-                                    max_new_tokens=knobs["prefix_new_tokens"]))
-            no_reuse.step()
-        nr_out = no_reuse.run()
-        if pf_out != nr_out:  # reuse must never change results
-            sys.exit("[bench_serve] prefix reuse changed outputs")
-        total_prompt_tokens = sum(len(p) for p in pf_prompts)
-        paged_detail = {
-            "block_size": knobs["block_size"],
-            "pool_blocks": contiguous_blocks,
-            "prefill_chunk": knobs["prefill_chunk"],
-            "paged_slots": knobs["paged_slots"],
-            "long_tail": {
-                "n_requests": knobs["long_tail_requests"],
-                "frac_long": knobs["long_tail_frac_long"],
-                "equal_memory_rows": contiguous_blocks
-                * knobs["block_size"],
-                "contiguous_slots": knobs["n_slots"],
-                "contiguous_peak_concurrent":
-                    lt_contig.stats["peak_concurrent"],
-                "paged_peak_concurrent":
-                    lt_paged.stats["peak_concurrent"],
-                "concurrency_ratio": round(ratio, 3),
-                "paged_backpressure_events":
-                    lt_paged.stats["backpressure_events"],
-                "paged_pool_peak_used_blocks": lt_paged.pool.peak_used,
-            },
-            "prefix": {
-                "n_requests": knobs["prefix_requests"],
-                "shared_prefix_len": knobs["prefix_len"],
-                "hits": pf_sched.stats["prefix_hits"],
-                "hit_tokens": pf_sched.stats["prefix_hit_tokens"],
-                "hit_rate": round(
-                    pf_sched.stats["prefix_hit_tokens"]
-                    / total_prompt_tokens, 4
-                ),
-                "prefill_tokens": pf_sched.stats["prefill_tokens"],
-                "prefill_tokens_no_reuse":
-                    no_reuse.stats["prefill_tokens"],
-            },
-        }
+        for _ in range(knobs["prefix_requests"])
+    ]
+    pf_sched = ContinuousBatchingScheduler(engine)
+    for j, p in enumerate(pf_prompts):
+        pf_sched.submit(Request(id=f"pf{j}", prompt=list(p),
+                                max_new_tokens=knobs["prefix_new_tokens"]))
+        pf_sched.step()  # arrivals spaced a tick apart: reuse is
+        # only possible once the first prefix is resident
+    pf_out = pf_sched.run()
+    no_reuse = ContinuousBatchingScheduler(
+        engine, pool=engine.make_pool()
+    )
+    no_reuse.prefix = None  # same engine, reuse disabled
+    for j, p in enumerate(pf_prompts):
+        no_reuse.submit(Request(id=f"pf{j}", prompt=list(p),
+                                max_new_tokens=knobs["prefix_new_tokens"]))
+        no_reuse.step()
+    nr_out = no_reuse.run()
+    if pf_out != nr_out:  # reuse must never change results
+        sys.exit("[bench_serve] prefix reuse changed outputs")
+    total_prompt_tokens = sum(len(p) for p in pf_prompts)
+    paged_detail = {
+        "block_size": knobs["block_size"],
+        "pool_blocks": contiguous_blocks,
+        "prefill_chunk": knobs["prefill_chunk"],
+        "paged_slots": knobs["paged_slots"],
+        "long_tail": {
+            "n_requests": knobs["long_tail_requests"],
+            "frac_long": knobs["long_tail_frac_long"],
+            "equal_memory_rows": contiguous_blocks
+            * knobs["block_size"],
+            "contiguous_slots": knobs["n_slots"],
+            "contiguous_peak_concurrent": contiguous_peak,
+            "paged_peak_concurrent":
+                lt_paged.stats["peak_concurrent"],
+            "concurrency_ratio": round(ratio, 3),
+            "paged_backpressure_events":
+                lt_paged.stats["backpressure_events"],
+            "paged_pool_peak_used_blocks": lt_paged.pool.peak_used,
+        },
+        "prefix": {
+            "n_requests": knobs["prefix_requests"],
+            "shared_prefix_len": knobs["prefix_len"],
+            "hits": pf_sched.stats["prefix_hits"],
+            "hit_tokens": pf_sched.stats["prefix_hit_tokens"],
+            "hit_rate": round(
+                pf_sched.stats["prefix_hit_tokens"]
+                / total_prompt_tokens, 4
+            ),
+            "prefill_tokens": pf_sched.stats["prefill_tokens"],
+            "prefill_tokens_no_reuse":
+                no_reuse.stats["prefill_tokens"],
+        },
+    }
 
     # ---- decode-speed probes (ISSUE 11) -----------------------------
-    spec_detail = None
-    kv_quant_detail = None
-    if engine_kind != "contiguous":
-        kv_quant_detail = _kv_quant_probe(model, engine, knobs, prompts)
-        spec_detail = _spec_probe(knobs)
+    kv_quant_detail = _kv_quant_probe(model, engine, knobs, prompts)
+    spec_detail = _spec_probe(knobs)
 
     # ---- serving-fleet probe (ISSUE 12) -----------------------------
     fleet_detail = None
-    if engine_kind != "contiguous" and n_fleet >= 2:
+    if n_fleet >= 2:
         fleet_detail = _fleet_probe(model, knobs, n_fleet)
 
     # ---- online-learning publish probe (ISSUE 18) -------------------
-    publish_detail = None
-    if engine_kind != "contiguous":
-        publish_detail = _publish_probe(model, knobs)
+    publish_detail = _publish_probe(model, knobs)
 
     summary = metrics.summary()
     n_tokens = summary["n_tokens_out"]
     detail = {
         "chips": jax.device_count(),
         "device_kind": jax.devices()[0].device_kind,
-        "engine": engine_kind,
+        "engine": "paged",
         "model": {k: knobs[k] for k in
                   ("d_model", "n_heads", "n_layers", "vocab_size")},
         "n_slots": engine.n_slots,
@@ -1090,8 +1070,7 @@ def main(argv=None):
     if "engine_stats" in summary:
         detail["engine_stats"] = summary["engine_stats"]
     detail["request_forensics"] = forensics_detail
-    if paged_detail is not None:
-        detail["paged"] = paged_detail
+    detail["paged"] = paged_detail
     if spec_detail is not None:
         detail["spec"] = spec_detail
     if kv_quant_detail is not None:

@@ -333,11 +333,7 @@ def test_rotary_pairs_are_interleaved_and_norm_preserving():
 
 # ---- the engine's guards -----------------------------------------------------
 
-def test_a_latent_model_is_served_paged_and_in_the_compute_dtype(model):
-    from theanompi_tpu.serving import ServingEngine
-
-    with pytest.raises(ValueError, match="PagedServingEngine"):
-        ServingEngine(model)
+def test_a_latent_pool_holds_the_compute_dtype_and_any_length(model):
     with pytest.raises(ValueError, match="compute dtype"):
         PagedServingEngine(model, kv_dtype="int8")
     eng = PagedServingEngine(model, n_slots=2, max_len=4096, block_size=32)

@@ -237,15 +237,36 @@ def test_wall_offset_puts_perf_counter_on_the_unix_clock():
 
 def test_boundary_span_overhead(tracing_off):
     """Sibling of ``test_disabled_span_overhead``: the always-on level
-    must stay cheap enough for a handful of spans per tick or step (the
-    budget is loose, the real cost is a few microseconds)."""
-    n = 20_000
-    t0 = time.perf_counter()
-    for i in range(n):
-        with obs.span("tick", boundary=True, n=i):
-            pass
-    per_span = (time.perf_counter() - t0) / n
-    assert per_span < 20e-6, f"boundary span costs {per_span * 1e6:.2f}µs"
+    must stay cheap enough for a handful of spans per tick or step.  It
+    is judged against a calibration loop timed in the same process (the
+    least a context manager that takes keyword arguments can cost), the
+    best of alternating batches of each, so that a loaded host slows
+    both: a span costs about ten of it (a few microseconds), and may
+    cost forty."""
+    class Bare:
+        def __init__(self, **args):
+            self.args = args
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    def per_entry(make, n=2_000):
+        t0 = time.perf_counter()
+        for i in range(n):
+            with make(i):
+                pass
+        return (time.perf_counter() - t0) / n
+
+    bare, span = [], []
+    for _ in range(10):
+        bare.append(per_entry(lambda i: Bare(n=i)))
+        span.append(per_entry(lambda i: obs.span("tick", boundary=True, n=i)))
+    assert min(span) < 40 * min(bare), (
+        f"boundary span costs {min(span) * 1e6:.2f}µs, "
+        f"{min(span) / min(bare):.1f} bare context managers")
 
 
 # ---------------------------------------------------------------------------

@@ -1021,12 +1021,15 @@ def _lint_current(tmp_path, mutate=None):
 
 def test_gate_lint_leg_green_runs_real_analyzer(fixtures):
     """No PERF_GATE_LINT_CURRENT: the leg analyzes the tree through
-    the incremental cache and must match the committed artifact."""
+    the incremental cache and must match the committed artifact.  That
+    match is what this test is for: the per-pass budget, which has its
+    own tests below, is set to a minute, which no loaded host reaches."""
     base, good, _ = fixtures
     r = _run_gate({
         "PERF_GATE_BENCH_JSON": good,
         "PERF_GATE_BASELINE": base,
         "PERF_GATE_LINT_CURRENT": "",
+        "PERF_GATE_LINT_PASS_BUDGET_MS": "60000",
     })
     assert r.returncode == 0, r.stderr
     assert "lint artifact diff" in r.stderr

@@ -6,6 +6,11 @@ no-cache full-recompute forward for >= 32 steps; the continuous-batching
 scheduler serves >= 3 overlapping requests with outputs identical to
 serial execution; a training checkpoint round-trips into serving with
 values and shardings preserved.
+
+The oracle is ``_recompute_greedy``: the TRAINING forward, recomputed a
+token at a time.  ``tests/test_serving_paged.py`` pins the paged cache's
+own contracts (prefix reuse, backpressure, zero recompiles) against it
+too.
 """
 
 import numpy as np
@@ -13,15 +18,15 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from theanompi_tpu.models.transformer import TransformerLM
-from theanompi_tpu.runtime.mesh import DATA_AXIS, TP_AXIS, make_mesh
+from theanompi_tpu.runtime.mesh import TP_AXIS, make_mesh
 from theanompi_tpu.runtime.recorder import Recorder
 from theanompi_tpu.serving import (
     ContinuousBatchingScheduler,
+    PagedServingEngine,
     Request,
-    ServingEngine,
     ServingMetrics,
     load_engine,
     restore_params_for_serving,
@@ -75,21 +80,29 @@ def test_greedy_kv_decode_matches_recompute_32_steps():
     """The acceptance bar: >= 32 decode steps, argmax-identical to the
     full-recompute baseline, through a non-trivial bucket pad."""
     model = _model()
-    eng = ServingEngine(model, n_slots=2, max_len=64, buckets=(8, 16, 64))
+    eng = PagedServingEngine(model, n_slots=2, max_len=64,
+                             buckets=(8, 16, 64))
     prompt = [3, 1, 4, 1, 5]  # pads 5 -> bucket 8
     got = eng.greedy(prompt, 33)
     want = _recompute_greedy(model, prompt, 33)
     assert got == want
 
 
-def test_prefill_logits_close_to_recompute():
+@pytest.mark.parametrize("chunk", [None, 8], ids=["whole", "chunked"])
+def test_prefill_logits_close_to_recompute(chunk):
     """Beyond argmax: the prefill's last-token logits numerically match
-    the training forward's."""
+    the training forward's, the prompt fed whole (bucket 16) or in two
+    chunks of the bucket-8 program through the same block table."""
     model = _model()
-    eng = ServingEngine(model, n_slots=1, max_len=64, buckets=(16, 64))
+    eng = PagedServingEngine(model, n_slots=1, max_len=64, buckets=(8, 16, 64),
+                             block_size=8, prefill_chunk=chunk)
     prompt = [7, 2, 9, 4, 4, 1, 0, 30, 2, 2, 11]
-    cache = eng.init_cache()
-    _, logits = eng.prefill(model.params, cache, 0, prompt)
+    table = eng.make_pool().alloc(eng.max_seq_blocks(len(prompt)))
+    state, step = eng.init_state(), chunk or len(prompt)
+    for p0 in range(0, len(prompt), step):
+        state, logits = eng.prefill_chunks(model.params, state, [
+            {"tokens": prompt[p0:p0 + step], "p0": p0, "table": table}])
+    logits = logits[0]
 
     t = int(model.config.seq_len)
     buf = np.zeros((1, t), np.int32)
@@ -106,11 +119,46 @@ def test_prefill_logits_close_to_recompute():
 def test_engine_rejects_unservable_configs():
     with pytest.raises(ValueError, match="sp=1"):
         mesh = TransformerLM.build_mesh(config=dict(CFG, sp=2))
-        ServingEngine(_model(mesh=mesh, sp=2))
+        PagedServingEngine(_model(mesh=mesh, sp=2))
     with pytest.raises(ValueError, match="moe"):
-        ServingEngine(_model(moe_experts=1, moe_aux_coef=0.0))
+        PagedServingEngine(_model(moe_experts=1, moe_aux_coef=0.0))
     with pytest.raises(ValueError, match="positional"):
-        ServingEngine(_model(), max_len=128)  # > trained seq_len
+        PagedServingEngine(_model(), max_len=128)  # > trained seq_len
+
+
+def test_a_block_family_is_picked_once_and_refuses_its_own():
+    """The engine looks a model's ``block`` up in ``paging.PROGRAMS`` and
+    nowhere else: a block without programs is refused at construction,
+    and what only one family cannot serve is refused by that family's
+    own constructor (through the engine: above for dense,
+    ``tests/test_latent_lm.py`` for latent)."""
+    from types import SimpleNamespace
+
+    from theanompi_tpu.runtime.config import Config
+    from theanompi_tpu.serving import paging
+    from theanompi_tpu.serving.dense import DensePrograms
+    from theanompi_tpu.serving.latent import LatentPrograms
+
+    assert paging.PROGRAMS == {"dense": DensePrograms,
+                               "latent_moe": LatentPrograms}
+    model = _model()
+    eng = PagedServingEngine(model, n_slots=1, max_len=64)
+    assert type(eng.programs) is DensePrograms and not eng.programs.latent
+    assert LatentPrograms.latent
+    unknown = SimpleNamespace(
+        config=Config(model.config.asdict(), block="sliding_window"),
+        mesh=model.mesh)
+    with pytest.raises(ValueError, match="no serving programs for "
+                                         "block='sliding_window'"):
+        PagedServingEngine(unknown)
+    # the families' constructors, alone
+    with pytest.raises(ValueError, match="positional"):
+        DensePrograms(SimpleNamespace(model=model, max_len=65))
+    with pytest.raises(ValueError, match="moe_experts=0"):
+        DensePrograms(SimpleNamespace(model=SimpleNamespace(
+            config=Config(model.config.asdict(), moe_experts=2)), max_len=64))
+    with pytest.raises(ValueError, match="compute dtype"):
+        LatentPrograms(SimpleNamespace(kv_dtype="int8"))
 
 
 def test_bucket_validation_rejects_non_int_and_duplicates():
@@ -140,33 +188,46 @@ def test_bucket_validation_rejects_non_int_and_duplicates():
 
 def test_engine_construction_rejects_bad_buckets():
     with pytest.raises(TypeError, match="recompile per request"):
-        ServingEngine(_model(), n_slots=1, max_len=64, buckets=(8.0, 64))
+        PagedServingEngine(_model(), n_slots=1, max_len=64, buckets=(8.0, 64))
     with pytest.raises(ValueError, match="duplicate"):
-        ServingEngine(_model(), n_slots=1, max_len=64, buckets=(8, 8, 64))
+        PagedServingEngine(_model(), n_slots=1, max_len=64,
+                           buckets=(8, 8, 64))
     # unsorted input is normalized, not refused
-    eng = ServingEngine(_model(), n_slots=1, max_len=64, buckets=(64, 8))
+    eng = PagedServingEngine(_model(), n_slots=1, max_len=64, buckets=(64, 8))
     assert eng.buckets == (8, 64)
 
 
-def test_prompt_longer_than_buckets_is_refused():
-    eng = ServingEngine(_model(), n_slots=1, max_len=64, buckets=(8,))
+def test_chunk_longer_than_buckets_is_refused():
+    """The scheduler cuts a prompt into chunks of the largest bucket; a
+    caller that hands the engine more than that is refused, not served
+    by a program compiled on the spot."""
+    eng = PagedServingEngine(_model(), n_slots=1, max_len=64, buckets=(8,))
+    assert eng.greedy(list(range(9)), 2) == _recompute_greedy(
+        eng.model, list(range(9)), 2)  # two chunks of the one program
     with pytest.raises(ValueError, match="bucket"):
-        eng.prefill(eng.model.params, eng.init_cache(), 0, list(range(9)))
+        eng.prefill_chunks(eng.model.params, eng.init_state(), [
+            {"tokens": list(range(9)), "p0": 0, "table": [1]}])
 
 
 # ---------------------------------------------------------------------------
 # continuous batching
 # ---------------------------------------------------------------------------
 
-def test_scheduler_interleaved_matches_serial():
+@pytest.mark.parametrize("engine_kwargs", [
+    dict(n_slots=2, buckets=(8, 64)),
+    dict(n_slots=4, buckets=(8, 16, 64), block_size=8, prefill_chunk=16),
+], ids=["whole_prompt", "chunked"])
+def test_scheduler_interleaved_matches_serial(engine_kwargs):
     """>= 3 overlapping requests on fewer slots than requests (forced
     queueing + join-on-finish recycling): per-request outputs must be
-    IDENTICAL to each request run alone."""
+    IDENTICAL to each request run alone — with whole-prompt prefill, and
+    through chunked prefill (a 30-token prompt in chunks of 16,
+    interleaved with the others' decode ticks)."""
     model = _model()
-    eng = ServingEngine(model, n_slots=2, max_len=64, buckets=(8, 64))
+    eng = PagedServingEngine(model, max_len=64, **engine_kwargs)
     reqs = [
         ("a", [1, 2, 3], 7),
-        ("b", [9, 8, 7, 6, 5], 5),
+        ("b", list(np.random.RandomState(7).randint(0, 32, size=30)), 5),
         ("c", [4], 9),
         ("d", [11, 30, 2, 2], 1),  # finishes at prefill
         ("e", [5, 5, 5, 5, 5, 5], 4),
@@ -192,7 +253,7 @@ def test_scheduler_mid_stream_admission():
     """A request admitted while others are mid-decode joins a recycled
     slot without disturbing their outputs."""
     model = _model()
-    eng = ServingEngine(model, n_slots=2, max_len=64, buckets=(8, 64))
+    eng = PagedServingEngine(model, n_slots=2, max_len=64, buckets=(8, 64))
     first = [("x", [1, 2], 6), ("y", [3, 4], 6)]
     sched = ContinuousBatchingScheduler(eng)
     for rid, prompt, n in first:
@@ -211,7 +272,7 @@ def test_scheduler_mid_stream_admission():
 
 def test_scheduler_eos_stops_early():
     model = _model()
-    eng = ServingEngine(model, n_slots=1, max_len=64, buckets=(8, 64))
+    eng = PagedServingEngine(model, n_slots=1, max_len=64, buckets=(8, 64))
     probe = ContinuousBatchingScheduler(eng)
     probe.submit(Request(id="p", prompt=[1, 2, 3], max_new_tokens=8))
     full = probe.run()["p"]
@@ -227,7 +288,7 @@ def test_scheduler_eos_stops_early():
 
 
 def test_scheduler_refuses_oversized_request():
-    eng = ServingEngine(_model(), n_slots=1, max_len=64)
+    eng = PagedServingEngine(_model(), n_slots=1, max_len=64)
     sched = ContinuousBatchingScheduler(eng)
     with pytest.raises(ValueError, match="cache rows"):
         sched.submit(Request(id="big", prompt=[1] * 60, max_new_tokens=10))
@@ -257,7 +318,7 @@ def test_metrics_ttft_tpot_and_recorder_events():
 
 
 def test_scheduler_feeds_metrics():
-    eng = ServingEngine(_model(), n_slots=2, max_len=64, buckets=(8, 64))
+    eng = PagedServingEngine(_model(), n_slots=2, max_len=64, buckets=(8, 64))
     rec = Recorder(verbose=False)
     metrics = ServingMetrics(recorder=rec)
     sched = ContinuousBatchingScheduler(eng, metrics=metrics)
@@ -267,8 +328,12 @@ def test_scheduler_feeds_metrics():
     s = metrics.summary()
     assert s["n_requests"] == 3
     assert s["n_tokens_out"] == 9
-    assert s["ttft_p50_s"] >= 0.0 and s["tpot_p50_s"] >= 0.0
+    for k in ("ttft_p50_s", "ttft_p99_s", "tpot_p50_s", "tpot_p99_s"):
+        assert s[k] >= 0.0
     assert sum(e["kind"] == "serve_request" for e in rec.events) == 3
+    # the run's reuse/capacity stats ride the summary
+    assert s["engine_stats"]["pool_blocks"] == eng.n_blocks - 1
+    assert 0 < s["engine_stats"]["pool_peak_used_blocks"] <= eng.n_blocks - 1
 
 
 # ---------------------------------------------------------------------------
@@ -305,7 +370,7 @@ def test_checkpoint_to_tensor_parallel_serving(tmp_path):
     src = _model()
     path = str(tmp_path / "ckpt.npz")
     checkpoint.save(path, src.checkpoint_state())
-    baseline = ServingEngine(src, n_slots=1, max_len=64).greedy([5, 3, 2], 6)
+    baseline = _recompute_greedy(src, [5, 3, 2], 6)
 
     cfg_tp = dict(CFG, tp=2)
     mesh_tp = TransformerLM.build_mesh(config=cfg_tp)  # (dp=4, tp=2)
@@ -319,7 +384,7 @@ def test_checkpoint_to_tensor_parallel_serving(tmp_path):
     np.testing.assert_array_equal(
         np.asarray(wq), np.asarray(src.params[2]["attn"]["wq"])
     )
-    eng = ServingEngine(tp_model, n_slots=1, max_len=64)
+    eng = PagedServingEngine(tp_model, n_slots=1, max_len=64)
     assert eng.greedy([5, 3, 2], 6) == baseline
 
 
@@ -331,22 +396,3 @@ def test_loader_rejects_wrong_architecture(tmp_path):
     checkpoint.save(path, model.checkpoint_state())
     with pytest.raises(ValueError, match="different params structure"):
         load_engine(path, config=dict(CFG, n_layers=3), mesh=model.mesh)
-
-
-# ---------------------------------------------------------------------------
-# cache layout
-# ---------------------------------------------------------------------------
-
-def test_cache_shards_slots_over_dp():
-    """On a multi-device dp mesh with divisible slots, the KV cache's
-    slot axis lands sharded over dp — serving reuses the training
-    mesh's memory distribution instead of replicating the cache."""
-    mesh = make_mesh()  # all 8 fake devices on dp
-    model = TransformerLM(config=CFG, mesh=mesh)
-    eng = ServingEngine(model, n_slots=8, max_len=64)
-    cache = eng.init_cache()
-    assert eng.kv_spec == P(None, DATA_AXIS, None, None, None)
-    assert cache["k"].sharding.spec == eng.kv_spec
-    # indivisible slot counts fall back to replication, never crash
-    eng2 = ServingEngine(model, n_slots=3, max_len=64)
-    assert eng2.kv_spec == P(None, None, None, None, None)

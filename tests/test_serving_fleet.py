@@ -832,7 +832,6 @@ def test_load_replica_checkpointless_spin_up(model, tmp_path):
         n_slots=2, max_len=64, block_size=8,
     )
     try:
-        assert rep.scheduler.paged
         # radix cache by default: the fleet's summaries exist
         from theanompi_tpu.serving.radix import RadixPrefixCache
 

@@ -2,10 +2,10 @@
 
 Acceptance contracts under test:
 
-- **Golden equivalence**: paged-vs-contiguous greedy decode is
-  token-identical on the same prompts (whole-prompt AND chunked
-  prefill, plain dp AND tp meshes), and the metrics summary exposes
-  identical TTFT/TPOT metric names.
+- **Golden equivalence**: greedy decode through block tables is
+  token-identical to the training forward recomputed a token at a
+  time (``test_serving._recompute_greedy``) on the same prompts
+  (whole-prompt AND chunked prefill, plain dp AND tp meshes).
 - **Prefix cache correctness**: hit vs miss produce identical outputs;
   refcounts drop to zero on finish (only the cache's own references
   survive, and evicting them empties the pool).
@@ -29,10 +29,10 @@ from theanompi_tpu.serving import (
     ContinuousBatchingScheduler,
     PagedServingEngine,
     Request,
-    ServingEngine,
-    ServingMetrics,
 )
 from theanompi_tpu.serving.paging import BlockPool, PrefixCache
+
+from test_serving import _recompute_greedy  # the oracle: tests/ is on the path
 
 CFG = dict(
     seq_len=64,
@@ -55,11 +55,6 @@ def model():
 
 
 @pytest.fixture(scope="module")
-def contiguous(model):
-    return ServingEngine(model, n_slots=2, max_len=64, buckets=(8, 16, 64))
-
-
-@pytest.fixture(scope="module")
 def paged(model):
     return PagedServingEngine(
         model, n_slots=2, max_len=64, buckets=(8, 16, 64), block_size=8
@@ -75,78 +70,30 @@ def paged_chunked(model):
 
 
 # ---------------------------------------------------------------------------
-# golden equivalence paged vs contiguous
+# golden equivalence paged vs the training forward
 # ---------------------------------------------------------------------------
 
-def test_paged_greedy_matches_contiguous(contiguous, paged):
+def test_paged_greedy_matches_recompute(model, paged):
     """The headline contract: same prompts → identical greedy tokens
-    through block-table gather/scatter as through slot-major slices."""
+    through block-table gather/scatter as from the training forward
+    recomputed a token at a time."""
     for prompt, n_new in [
         ([3, 1, 4, 1, 5], 12),          # pads into bucket 8
         ([7, 2, 9, 4, 4, 1, 0, 30, 2, 2, 11], 8),   # bucket 16
         (list(range(20)), 33),          # bucket 64, >=32 decode steps
     ]:
-        want = contiguous.greedy(list(prompt), n_new)
+        want = _recompute_greedy(model, list(prompt), n_new)
         got = paged.greedy(list(prompt), n_new)
         assert got == want, f"paged diverged on prompt {prompt[:4]}..."
 
 
-def test_chunked_prefill_matches_whole_prompt(contiguous, paged_chunked):
+def test_chunked_prefill_matches_whole_prompt(paged, paged_chunked):
     """A prompt longer than prefill_chunk is fed in block-sized chunks
     interleaved with ticks — final tokens identical to one-shot."""
     prompt = list(np.random.RandomState(0).randint(0, 32, size=37))
-    want = contiguous.greedy(list(prompt), 10)
+    want = paged.greedy(list(prompt), 10)
     got = paged_chunked.greedy(list(prompt), 10)
     assert got == want
-
-
-def test_paged_prefill_logits_close_to_recompute(model, paged):
-    """Beyond argmax: last-token prefill logits numerically match the
-    training forward (same tolerance as the contiguous test)."""
-    prompt = [7, 2, 9, 4, 4, 1, 0, 30, 2, 2, 11]
-    sched = ContinuousBatchingScheduler(paged)
-    sched.submit(Request(id="x", prompt=list(prompt), max_new_tokens=1))
-    sched._admit_paged()
-    state = sched.state
-    rows = [{"tokens": prompt, "p0": 0, "table": sched.slots[0].blocks}]
-    _, logits = paged.prefill_chunks(model.params, state, rows)
-
-    t = int(model.config.seq_len)
-    buf = np.zeros((1, t), np.int32)
-    buf[0, : len(prompt)] = prompt
-    full, _ = model.net.apply(
-        model.params, model.net_state, jnp.asarray(buf), train=False,
-        rng=None,
-    )
-    np.testing.assert_allclose(
-        np.asarray(logits[0]), np.asarray(full[0, len(prompt) - 1]),
-        rtol=1e-4, atol=1e-4,
-    )
-
-
-def test_paged_scheduler_interleaved_matches_serial(paged_chunked):
-    """The continuous-batching determinism contract holds through
-    block tables + chunked prefill: overlapped requests produce the
-    same outputs as each alone."""
-    eng = paged_chunked
-    reqs = [
-        ("a", [1, 2, 3], 7),
-        ("b", list(np.random.RandomState(7).randint(0, 32, size=30)), 5),
-        ("c", [4], 9),
-        ("d", [11, 30, 2, 2], 1),  # finishes at prefill
-        ("e", [5, 5, 5, 5, 5, 5], 4),
-    ]
-    serial = {}
-    for rid, prompt, n in reqs:
-        s = ContinuousBatchingScheduler(eng)
-        s.submit(Request(id=rid, prompt=list(prompt), max_new_tokens=n))
-        serial.update(s.run())
-    sched = ContinuousBatchingScheduler(eng)
-    for rid, prompt, n in reqs:
-        sched.submit(Request(id=rid, prompt=list(prompt), max_new_tokens=n))
-    inter = sched.run()
-    assert inter == serial
-    assert [len(inter[r]) for r, _, n in reqs] == [n for _, _, n in reqs]
 
 
 def _aligned_copy(a, align=64):
@@ -214,34 +161,17 @@ def test_dispatch_inputs_cannot_alias_scheduler_state(paged_chunked,
         assert serve(interleaved=True) == serial
 
 
-def test_paged_metric_names_identical(contiguous, paged):
-    """The serving metrics surface is engine-agnostic: a consumer of
-    BENCH_serve/ serve_summary sees the same TTFT/TPOT keys."""
-    outs = []
-    for eng in (contiguous, paged):
-        m = ServingMetrics()
-        s = ContinuousBatchingScheduler(eng, metrics=m)
-        s.submit(Request(id="r", prompt=[1, 2, 3], max_new_tokens=4))
-        s.run()
-        outs.append(m.summary())
-    contig_keys = {k for k in outs[0] if k != "engine_stats"}
-    paged_keys = {k for k in outs[1] if k != "engine_stats"}
-    assert contig_keys == paged_keys
-    for k in ("ttft_p50_s", "ttft_p99_s", "tpot_p50_s", "tpot_p99_s"):
-        assert k in paged_keys
-    # the paged run additionally reports its reuse/capacity stats
-    assert outs[1]["engine_stats"]["pool_blocks"] > 0
-
-
 def test_paged_on_tp_mesh_matches(model):
     """Tensor-parallel serving through block tables: heads shard over
     tp, decode tokens unchanged."""
     cfg_tp = dict(CFG, tp=2)
     mesh_tp = TransformerLM.build_mesh(config=cfg_tp)
     tp_model = TransformerLM(config=cfg_tp, mesh=mesh_tp)
-    want = ServingEngine(tp_model, n_slots=1, max_len=64).greedy(
-        [5, 3, 2], 6
-    )
+    # the oracle runs the same weights unsharded
+    ref = TransformerLM(config=dict(CFG),
+                        mesh=make_mesh(devices=jax.devices()[:1]))
+    ref.params = jax.device_get(tp_model.params)
+    want = _recompute_greedy(ref, [5, 3, 2], 6)
     eng = PagedServingEngine(
         tp_model, n_slots=1, max_len=64, block_size=8
     )
@@ -261,17 +191,17 @@ def test_pool_rows_shard_over_dp():
         model, n_slots=8, max_len=64, block_size=8, n_blocks=64
     )
     state = eng.init_state()
-    assert eng.pool_spec == P(DATA_AXIS, None)
+    assert eng.programs.pool_spec == P(DATA_AXIS, None)
     assert len(state["k"]) == len(state["v"]) == CFG["n_layers"]
     for leaf in state["k"] + state["v"]:
         # 4 heads of 8: 32 numbers a row, stored 128 lanes wide
         assert leaf.shape == (64 * 8, 128)
-        assert leaf.sharding.spec == eng.pool_spec
+        assert leaf.sharding.spec == eng.programs.pool_spec
     eng2 = PagedServingEngine(
         model, n_slots=8, max_len=64, block_size=8, n_blocks=9
     )
-    assert eng2.pool_spec == P(None, None)
-    assert eng2.init_state()["k"][0].sharding.spec == eng2.pool_spec
+    assert eng2.programs.pool_spec == P(None, None)
+    assert eng2.init_state()["k"][0].sharding.spec == eng2.programs.pool_spec
 
 
 @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
@@ -287,18 +217,18 @@ def test_pool_heads_shard_over_tp(kv_dtype):
                              mesh=TransformerLM.build_mesh(config=cfg_tp))
     eng = PagedServingEngine(tp_model, n_slots=2, max_len=64, block_size=8,
                              n_blocks=16, kv_dtype=kv_dtype)
-    assert eng.pool_spec == P(DATA_AXIS, TP_AXIS)
-    assert eng.row_width == CFG["d_model"]  # 32: no padding across shards
+    assert eng.programs.pool_spec == P(DATA_AXIS, TP_AXIS)
+    assert eng.programs.row_width == CFG["d_model"]  # 32: no padding across shards
     state = eng.init_state()
     for side in ("k", "v"):
         for leaf in state[side]:
             assert leaf.shape == (16 * 8, 32)
-            assert leaf.sharding.spec == eng.pool_spec
+            assert leaf.sharding.spec == eng.programs.pool_spec
             assert leaf.addressable_shards[0].data.shape == (16 * 8 // 4, 16)
     if kv_dtype == "int8":
         for leaf in state["ks"] + state["vs"]:
             assert leaf.shape == (16 * 8, CFG["n_heads"])
-            assert leaf.sharding.spec == eng.pool_spec
+            assert leaf.sharding.spec == eng.programs.pool_spec
     want = PagedServingEngine(
         TransformerLM(config=dict(CFG), mesh=make_mesh(devices=jax.devices()[:1])),
         n_slots=2, max_len=64, block_size=8, n_blocks=16, kv_dtype=kv_dtype,
@@ -332,7 +262,7 @@ def test_state_is_one_lane_aligned_array_a_layer(sized_model, kv_dtype):
     ``kv_block_bytes`` counts what is stored."""
     eng = PagedServingEngine(sized_model, n_slots=2, max_len=64,
                              block_size=8, n_blocks=9, kv_dtype=kv_dtype)
-    assert eng.row_width == 128
+    assert eng.programs.row_width == 128
     state = eng.init_state()
     assert sorted(state) == (
         ["k", "ks", "v", "vs"] if kv_dtype == "int8" else ["k", "v"])
@@ -341,7 +271,7 @@ def test_state_is_one_lane_aligned_array_a_layer(sized_model, kv_dtype):
         assert isinstance(leaves, list) and len(leaves) == eng.n_layers
         for leaf in leaves:
             assert leaf.shape == (
-                rows, eng.n_heads if side in ("ks", "vs") else 128)
+                rows, eng.programs.n_heads if side in ("ks", "vs") else 128)
     assert state["k"][0].dtype == (
         jnp.int8 if kv_dtype == "int8" else jnp.float32)
     stored = sum(leaf.nbytes for leaf in jax.tree.leaves(state))
@@ -358,7 +288,7 @@ def test_state_is_one_lane_aligned_array_a_layer(sized_model, kv_dtype):
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(old))
     # lane 0's token went to row 0 of block 3, every head side by side,
     # the padding columns left at zero; the idle lane's to the trash block
-    width = eng.n_heads * eng.head_dim
+    width = eng.programs.n_heads * eng.programs.head_dim
     k0 = np.asarray(state["k"][0])
     assert np.any(k0[3 * 8, :width] != 0) and not np.any(k0[3 * 8, width:])
     assert not np.any(k0[8:3 * 8]) and not np.any(k0[3 * 8 + 1:])
@@ -405,9 +335,14 @@ def test_kernel_gather_chunks_and_prefix_hits_serve_the_same_tokens(
 # prefix cache
 # ---------------------------------------------------------------------------
 
-def test_prefix_hit_outputs_identical_and_counted(contiguous, paged):
+def test_prefix_hit_outputs_identical_and_counted(model, paged):
     """A shared system prompt is prefilled once; later requests reuse
-    its blocks — and their outputs are identical to cold prefills."""
+    its blocks — and their outputs are identical to cold prefills (an
+    engine without a prefix cache, each request alone)."""
+    cold = PagedServingEngine(
+        model, n_slots=2, max_len=64, buckets=(8, 16, 64), block_size=8,
+        prefix_cache=False,
+    )
     shared = list(np.random.RandomState(1).randint(0, 32, size=24))
     sched = ContinuousBatchingScheduler(paged)
     sched.submit(Request(id="a", prompt=shared + [7], max_new_tokens=6))
@@ -418,9 +353,10 @@ def test_prefix_hit_outputs_identical_and_counted(contiguous, paged):
     base = {}
     for rid, p, n in (("a", shared + [7], 6), ("b", shared + [9], 6),
                       ("c", shared + [9, 3], 4)):
-        s = ContinuousBatchingScheduler(contiguous)
+        s = ContinuousBatchingScheduler(cold)
         s.submit(Request(id=rid, prompt=list(p), max_new_tokens=n))
         base.update(s.run())
+        assert s.stats["prefix_hits"] == 0
     assert out == base
     # b and c each reused the 3 full shared blocks (24 tokens)
     assert sched.stats["prefix_hits"] == 2

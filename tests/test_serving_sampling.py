@@ -24,7 +24,7 @@ from theanompi_tpu.runtime.mesh import make_mesh
 from theanompi_tpu.serving import (
     ContinuousBatchingScheduler,
     Request,
-    ServingEngine,
+    PagedServingEngine,
 )
 from theanompi_tpu.serving.sampling import Sampler, request_key
 
@@ -46,7 +46,7 @@ CFG = dict(
 def engine():
     mesh = make_mesh(devices=jax.devices()[:1])
     model = TransformerLM(config=dict(CFG), mesh=mesh)
-    return ServingEngine(model, n_slots=2, max_len=64)
+    return PagedServingEngine(model, n_slots=2, max_len=64)
 
 
 def _run(engine, requests):

@@ -186,13 +186,6 @@ def test_spec_k0_is_plain_and_refuses_dangling_draft(engine, draft_engine):
     assert sched.spec_summary() is None  # spec_k=0: no spec machinery
     with pytest.raises(ValueError, match="spec_k=0"):
         ContinuousBatchingScheduler(engine, draft_engine=draft_engine)
-    with pytest.raises(ValueError, match="paged"):
-        from theanompi_tpu.serving import ServingEngine
-
-        ContinuousBatchingScheduler(
-            ServingEngine(engine.model, n_slots=2, max_len=64),
-            spec_k=2, draft_engine=draft_engine,
-        )
 
 
 def test_spec_all_reject_degrades_to_one_token_per_round(model, engine):
@@ -246,12 +239,8 @@ def test_spec_budget_clamp_and_zero_recompile(engine, draft_engine):
 
 
 def test_spec_decoder_validates_geometry(model, engine, draft_engine):
-    from theanompi_tpu.serving import ServingEngine
-
     with pytest.raises(ValueError, match="k must be >= 1"):
         SpecDecoder(engine, draft_engine, 0)
-    with pytest.raises(ValueError, match="paged"):
-        SpecDecoder(engine, ServingEngine(model, n_slots=2, max_len=64), 2)
     mismatched = PagedServingEngine(make_draft(model, 1), n_slots=4,
                                     max_len=64, block_size=8)
     with pytest.raises(ValueError, match="n_slots"):

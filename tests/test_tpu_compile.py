@@ -238,7 +238,7 @@ def test_dense_pool_is_updated_in_place_at_25_heads_of_64(
         model, n_slots=32, max_len=1024, block_size=32, n_blocks=n_blocks,
         prefill_chunk=256, paged_attn="pallas",
     )
-    assert (eng.row_width, eng.prefill_rows) == (1664, 4)
+    assert (eng.programs.row_width, eng.prefill_rows) == (1664, 4)
     params = _described(model.params, one_chip)
     state = _described(jax.eval_shape(eng.init_state), one_chip)
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))

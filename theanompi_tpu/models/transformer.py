@@ -557,6 +557,6 @@ def make_draft(model: TransformerLM, n_layers: int = 1) -> TransformerLM:
     draft = TransformerLM(config=cfg, mesh=model.mesh)
     p = list(model.params)
     # Sequential params layout: [embedding, positions, block_0..block_{L-1},
-    # final_ln, head] — the same split serving/engine._weights makes
+    # final_ln, head] — the same split serving/dense.py _weights makes
     draft.params = p[:2] + p[2:2 + n_layers] + p[2 + L:]
     return draft

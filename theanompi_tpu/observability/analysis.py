@@ -1499,16 +1499,14 @@ REQUEST_PHASES = (
     "readmission",
 )
 
-# span names contributing to each phase.  ``prefill`` covers both the
-# paged per-lane phase span (``req_prefill``) and the contiguous
-# scheduler's rid-labeled ``prefill`` span (plus the engine dispatch
-# span, which nests inside either — the interval union makes the
-# overlap free).  ``req_spec`` counts as decode wall time; its
-# rolled-back share is carved out scalar-wise below.
+# span names contributing to each phase.  ``prefill`` is the
+# scheduler's per-lane phase span (``req_prefill``).  ``req_spec``
+# counts as decode wall time; its rolled-back share is carved out
+# scalar-wise below.
 _PHASE_SPANS = {
     "queue": ("req_queue",),
     "backpressure": ("req_backpressure",),
-    "prefill": ("req_prefill", "prefill", "prefill_dispatch"),
+    "prefill": ("req_prefill",),
     "decode": ("req_decode", "req_spec"),
     "install_wait": ("req_install_wait",),
     "readmission": ("req_readmit",),

@@ -319,7 +319,7 @@ def check_readmit_trace(record: dict) -> dict:
         journaled = int(readmit[0].get("args", {}).get("journaled", 0) or 0)
 
     decode_names = ("req_decode", "req_spec")
-    prefill_names = ("req_prefill", "prefill", "prefill_dispatch")
+    prefill_names = ("req_prefill",)
 
     def first_ts(names, after=None):
         for ev in spans:

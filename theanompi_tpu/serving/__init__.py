@@ -4,16 +4,21 @@ The training side of the train→serve gap is closed by the rest of the
 framework (BSP over a mesh, ZeRO, checkpoints); this package closes the
 serving side with the same sharded-parameter machinery:
 
-- ``engine``    — jit-compiled prefill + single-token KV-cache decode for
-  ``TransformerLM``, with a preallocated, length-bucketed cache laid out
-  on the model's own ``build_mesh()`` mesh.
-- ``paging``    — the paged KV cache: a refcounted fixed-size block
-  pool (``BlockPool``), hash-consed prefix reuse (``PrefixCache``),
-  and ``PagedServingEngine`` — block-table gather/scatter prefill +
-  decode with batched, chunked multi-slot prefill.
+- ``paging``    — the one engine, ``PagedServingEngine``, over the
+  paged KV cache: a refcounted fixed-size block pool (``BlockPool``),
+  hash-consed prefix reuse (``PrefixCache``), jit-compiled batched,
+  chunked multi-slot prefill and single-token decode through block
+  tables, on the model's own ``build_mesh()`` mesh.  It holds no
+  model's body: it picks the programs of the model's block family.
+- ``dense``, ``latent`` — the two program families (``DensePrograms``:
+  the pre-LN stack over a learned position table; ``LatentPrograms``:
+  latent attention and routed experts): a pool's layout and the bodies
+  of the prefill/verify and decode programs.
+- ``engine``    — what every program shares: ``host_input``, the trash
+  block, the prefill bucket ladder.
 - ``scheduler`` — continuous batching: an admission queue feeding a fixed
-  set of decode slots, join-on-finish slot recycling (paged engines
-  also reclaim their blocks), no recompiles as requests come and go.
+  set of decode slots, join-on-finish slot and block recycling, no
+  recompiles as requests come and go.
 - ``loader``    — restore a *training* checkpoint
   (``utils/checkpoint.restore``) and re-lay the params into inference
   sharding (reusing ``TransformerLM._build_param_specs``).
@@ -44,7 +49,6 @@ Bench entry point: ``bench_serve.py`` at the repo root (hooked from
 ``BENCH_serve`` JSON under a synthetic Poisson workload.
 """
 
-from theanompi_tpu.serving.engine import ServingEngine
 from theanompi_tpu.serving.fleet import FleetRouter, ServeReplica
 from theanompi_tpu.serving.loader import load_engine, restore_params_for_serving
 from theanompi_tpu.serving.metrics import ServingMetrics
@@ -63,7 +67,6 @@ from theanompi_tpu.serving.scheduler import (
 from theanompi_tpu.serving.spec import SpecDecoder
 
 __all__ = [
-    "ServingEngine",
     "PagedServingEngine",
     "BlockPool",
     "PrefixCache",

@@ -56,7 +56,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from theanompi_tpu.ops import platform
 from theanompi_tpu.ops.pallas_flash import _NEG_INF
-from theanompi_tpu.serving.paging import TRASH_BLOCK
+from theanompi_tpu.serving.engine import TRASH_BLOCK
 
 
 def paged_prefill_attention(attn, ap, q_nope, q_rope, pool, tables,
@@ -108,7 +108,12 @@ class LatentPrograms:
     """What ``PagedServingEngine`` runs for a ``latent_moe`` model: the
     state's layout and the bodies of its two jitted programs."""
 
+    latent = True  # the scheduler reports the latent_rows_* stats
+
     def __init__(self, engine):
+        if engine.kv_dtype != "fp32":
+            raise ValueError("a latent pool holds the compute dtype "
+                             "(kv_dtype='fp32')")
         layers = engine.model.net.layers
         self.engine = engine
         self.embed, self.blocks = layers[0], layers[1:-2]

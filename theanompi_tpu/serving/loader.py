@@ -90,7 +90,6 @@ def load_engine(
     max_len: Optional[int] = None,
     buckets=None,
     model_cls=None,
-    paged: bool = False,
     block_size: int = 16,
     n_blocks: Optional[int] = None,
     prefill_chunk: Optional[int] = None,
@@ -99,7 +98,7 @@ def load_engine(
     kv_dtype: str = "fp32",
     paged_attn: str = "xla",
 ):
-    """One-call checkpoint → ready ``ServingEngine``.
+    """One-call checkpoint → ready ``PagedServingEngine``.
 
     ``config`` must describe the architecture the checkpoint was trained
     with (d_model / n_heads / n_layers / vocab_size / seq_len); serving
@@ -107,14 +106,12 @@ def load_engine(
     ``model_cls.build_mesh(config)`` — the same mesh builder training
     rules use, so serving engages tp meshes from config alone.
 
-    ``paged=True`` returns a ``paging.PagedServingEngine`` instead —
-    same checkpoint, same decode outputs, KV memory in fixed-size
-    refcounted blocks (``block_size``/``n_blocks``) with prefix reuse
-    and chunked multi-slot prefill (``prefill_chunk``).  ``kv_dtype``
+    KV memory is fixed-size refcounted blocks (``block_size``/
+    ``n_blocks``) with prefix reuse and chunked multi-slot prefill
+    (``prefill_chunk``).  ``kv_dtype``
     ('fp32'/'int8') and ``paged_attn`` ('xla'/'pallas'/'auto') select
     the quantized-cache and fused-kernel decode tiers — a checkpoint
     loads identically into any combination."""
-    from theanompi_tpu.serving.engine import ServingEngine
     from theanompi_tpu.serving.paging import PagedServingEngine
 
     if model_cls is None:
@@ -133,16 +130,12 @@ def load_engine(
         else model_cls(config=cfg)
     )
     restore_params_for_serving(model, path)
-    if paged:
-        return PagedServingEngine(
-            model, n_slots=n_slots, max_len=max_len, buckets=buckets,
-            block_size=block_size, n_blocks=n_blocks,
-            prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
-            prefix_impl=prefix_impl, kv_dtype=kv_dtype,
-            paged_attn=paged_attn,
-        )
-    return ServingEngine(
-        model, n_slots=n_slots, max_len=max_len, buckets=buckets
+    return PagedServingEngine(
+        model, n_slots=n_slots, max_len=max_len, buckets=buckets,
+        block_size=block_size, n_blocks=n_blocks,
+        prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
+        prefix_impl=prefix_impl, kv_dtype=kv_dtype,
+        paged_attn=paged_attn,
     )
 
 
@@ -158,11 +151,10 @@ def load_replica(
     supervisor runs to replace an evicted replica (the serving analog
     of the async rules' re-admission: state is re-derived from the
     durable artifact, never copied from the dead incarnation).  The
-    engine is paged (radix prefix cache — fleet routing wants the
+    engine's prefix cache is the radix tree (fleet routing wants the
     summaries); ``engine_kwargs`` reach :func:`load_engine`."""
     from theanompi_tpu.serving.fleet import ServeReplica
 
-    engine_kwargs.setdefault("paged", True)
     engine_kwargs.setdefault("prefix_impl", "radix")
     engine = load_engine(path, config=config, **engine_kwargs)
     return ServeReplica(name, engine, port=port).start()

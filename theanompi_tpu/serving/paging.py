@@ -1,10 +1,9 @@
 """Paged KV cache: fixed-size blocks, block tables, prefix reuse.
 
-The PR 1 engine preallocates a worst-case contiguous region per slot
-(``init_cache`` reserves ``max_len`` rows for every slot), so cache
-memory scales with the *longest imaginable* sequence times the slot
-count while real traffic is long-tail: most sequences are short, a few
-are huge.  This module decouples a sequence's logical positions from
+A cache that reserves a worst-case region per slot (``max_len`` rows
+for every slot) scales with the *longest imaginable* sequence times the
+slot count, while real traffic is long-tail: most sequences are short, a
+few are huge.  This module decouples a sequence's logical positions from
 their physical placement — the same move the Theano-MPI lineage makes
 for training (preallocated exchanged buffers, arXiv:1605.08325) and
 arXiv:2112.01075 makes for redistribution: only *live* blocks occupy
@@ -13,12 +12,12 @@ memory.
 Three pieces:
 
 - **BlockPool** — host-side allocator over a device-side flat row pool:
-  ``k``/``v``, one array a layer of shape ``(n_blocks * block_size,
-  row_width)``, a row holding a token's heads side by side
-  (``heads * head_dim`` numbers, rounded up to 128 lanes — see
-  ``PagedServingEngine.init_state``).  The layers' arrays are separate
-  leaves of the state (never stacked), each donated and updated in
-  place by its program.  Block 0 is reserved as the *trash block*: masked or
+  one array a layer of shape ``(n_blocks * block_size, width)``; what a
+  row holds is the block family's business (``serving/dense.py``: a
+  token's heads side by side, for ``k`` and for ``v``;
+  ``serving/latent.py``: one latent row).  The layers' arrays are
+  separate leaves of the state (never stacked), each donated and updated
+  in place by its program.  Block 0 is reserved as the *trash block*: masked or
   inactive lanes scatter their garbage there, so a freed (reallocated)
   block can never be corrupted by a stale lane.  Refcounted — a block
   shared by N sequences (prefix reuse) frees only when the last
@@ -30,10 +29,11 @@ Three pieces:
   once, refcounted across requests.  The final prompt token is never
   served from cache (its logits must be computed), so a match is
   capped at ``(len(prompt) - 1) // block_size`` blocks.
-- **PagedServingEngine** — the contiguous engine's forward math
-  re-expressed over block tables: prefill and decode gather/scatter
-  K/V rows by ``table[block] * block_size + offset`` instead of
-  slot-major slicing.  Tables/positions enter the jitted programs as
+- **PagedServingEngine** — the one serving engine: slots, buckets,
+  block geometry, the jitted programs and their host entries.  It holds
+  no model's body: the programs of the model's block family
+  (``PROGRAMS``) gather and scatter K/V rows by ``table[block] *
+  block_size + offset``.  Tables/positions enter the jitted programs as
   *data* (device arrays), never as shapes, so admission, retirement
   and table growth cause ZERO recompiles — one decode program ever,
   one prefill program per chunk bucket.  Prefill is **batched and
@@ -72,8 +72,8 @@ Decode-speed layers on top (ISSUE 11):
   Numerics are pinned allclose between the two paths.
 
 Correctness contract (tests/test_serving_paged.py): greedy decode
-through block tables is token-identical to the contiguous engine and
-to the no-cache recompute baseline; prefix hits change which physical
+through block tables is token-identical to the no-cache recompute
+baseline (the training forward, a token at a time); prefix hits change which physical
 rows are read, never the values read from them.
 """
 
@@ -87,16 +87,20 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from theanompi_tpu import observability as obs
-from theanompi_tpu.runtime.mesh import DATA_AXIS, TP_AXIS
 from theanompi_tpu.serving import metrics as smetrics
+from theanompi_tpu.serving.dense import DensePrograms
 from theanompi_tpu.serving.engine import (
-    _NEG_INF, ServingEngine, host_input,
+    _validate_buckets, default_buckets, host_input,
 )
+from theanompi_tpu.serving.latent import LatentPrograms
 
-TRASH_BLOCK = 0  # reserved physical block: masked/inactive writes land here
+# a model's ``block`` -> the programs that serve it: the one place in
+# ``serving/`` that looks at a block's name.  A family is a class with
+# ``init_state``, ``block_bytes``, ``chunk_fn`` and ``decode_fn``
+# (docs/serving.md, "Adding a block family").
+PROGRAMS = {"dense": DensePrograms, "latent_moe": LatentPrograms}
 
 KV_DTYPES = ("fp32", "int8")
 
@@ -125,8 +129,7 @@ class BlockPool:
     The pool owns block *identities* (free list + refcounts); the
     device arrays live in the engine state and are threaded through
     the jitted programs.  One pool per scheduler — two schedulers
-    sharing an engine each run their own allocation world, exactly
-    like two schedulers each calling ``init_cache`` today.
+    sharing an engine each run their own allocation world.
     """
 
     def __init__(self, n_blocks: int, block_size: int):
@@ -297,21 +300,30 @@ class PrefixCache:
         return dropped
 
 
-class PagedServingEngine(ServingEngine):
-    """The serving engine over a paged KV cache.
+class PagedServingEngine:
+    """The serving engine: prefill + continuous decode over a paged KV
+    cache, for a ``TransformerLM`` of any block family in ``PROGRAMS``.
 
-    Shares every weight-math helper with ``ServingEngine`` (identical
-    LayerNorm/projection/softmax numerics); replaces slot-major cache
-    slicing with block-table gather/scatter.
+    ``model`` supplies the config, mesh, params and (for tp) the
+    ``param_specs`` produced by ``_build_param_specs`` — the same specs
+    training shards by.  The engine never mutates the model.  What a
+    layer computes and what a pool row holds belong to the family's
+    programs (``self.programs``); the engine owns what is common: slots,
+    buckets, block geometry, the jitted programs and their host entries.
+
+    Scope: ``pp=1`` and ``sp=1``.  ``sp`` is a long-context *training*
+    axis (ring attention over sequence shards); single-token decode has
+    no sequence dim to shard.  Tensor parallelism is served through
+    GSPMD (params stay in their Megatron layout under ``jit``; XLA
+    partitions the dense ops).
 
     Geometry:
 
     - ``block_size`` — KV rows per block (the allocation granule).
     - ``n_blocks`` — pool capacity *including* the reserved trash
-      block; defaults to contiguous parity
-      (``n_slots * blocks_per_seq + 1``) so the default engine serves
-      exactly what the contiguous one could, and operators shrink it
-      (or raise ``n_slots``) to bank the long-tail savings.
+      block; defaults to ``n_slots * blocks_per_seq + 1`` (every slot
+      can hold a ``max_len`` sequence), and operators shrink it (or
+      raise ``n_slots``) to bank the long-tail savings.
     - ``prefill_rows`` — lanes per batched prefill call (fixed shape;
       default ``min(n_slots, PREFILL_ROWS)``).  Nothing an operator
       tunes: the scheduler feeds every pending lane each tick in
@@ -331,8 +343,6 @@ class PagedServingEngine(ServingEngine):
       one) — ``paged_attn_effective`` records which runs.
     """
 
-    is_paged = True
-
     def __init__(
         self,
         model,
@@ -348,8 +358,36 @@ class PagedServingEngine(ServingEngine):
         kv_dtype: str = "fp32",
         paged_attn: str = "xla",
     ):
-        super().__init__(model, n_slots=n_slots, max_len=max_len,
-                         buckets=buckets)
+        cfg = model.config
+        block = str(cfg.block)
+        if block not in PROGRAMS:
+            raise ValueError(
+                f"no serving programs for block={block!r}: the engine "
+                f"serves {sorted(PROGRAMS)}"
+            )
+        if getattr(model, "pp_size", 1) > 1:
+            raise ValueError("serving requires pp=1 (the GPipe scan has no "
+                             "single-token decode form)")
+        if getattr(model, "sp_size", 1) > 1:
+            raise ValueError(
+                "serving requires sp=1: sequence parallelism shards the "
+                "sequence dim, which a single-token decode step does not "
+                "have — rebuild the model with sp=1 (tp is supported)"
+            )
+        self.model = model
+        self.mesh = model.mesh
+        self.d_model = int(cfg.d_model)
+        self.n_layers = int(cfg.n_layers)
+        self.vocab_size = int(cfg.vocab_size)
+        self.compute_dtype = (
+            jnp.dtype(cfg.compute_dtype) if cfg.compute_dtype else None
+        )
+        self.n_slots = int(n_slots)
+        self.max_len = int(max_len) if max_len is not None else int(cfg.seq_len)
+        self.buckets = _validate_buckets(
+            buckets if buckets is not None else default_buckets(self.max_len),
+            self.max_len,
+        )
         if int(block_size) < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.block_size = int(block_size)
@@ -420,47 +458,22 @@ class PagedServingEngine(ServingEngine):
         self.paged_attn_effective = (
             "pallas" if paged_attn != "xla" and kernel_ok else "xla"
         )
-        # pool rows shard over dp only when every per-device shard is a
-        # whole number of blocks (a split block would tear the
-        # gather/scatter row arithmetic across devices)
-        row_ax = (
-            DATA_AXIS
-            if DATA_AXIS in self.mesh.shape
-            and int(self.mesh.shape[DATA_AXIS]) > 1
-            and self.n_blocks % int(self.mesh.shape[DATA_AXIS]) == 0
-            else None
-        )
-        head_ax = (
-            TP_AXIS
-            if TP_AXIS in self.mesh.shape and int(self.mesh.shape[TP_AXIS]) > 1
-            else None
-        )
-        # one layer's pool is (rows, row_width) and its int8 scale plane
-        # (rows, heads); one spec for both: rows over dp, a row's heads
-        # over tp
-        self.pool_spec = P(row_ax, head_ax)
-        # a row holds its heads side by side, rounded up to 128 lanes
-        # (``init_state``); heads split over tp keep their exact width,
-        # so that a shard is a whole number of heads
-        width = self.n_heads * self.head_dim
-        self.row_width = width if head_ax else -(-width // 128) * 128
-        # a latent model's pool, programs and counters (serving/latent.py).
-        # `last_counters` is what the newest
-        # program call returned beside its logits (None: no such model),
+        # the family's pool layout and program bodies; its constructor
+        # refuses what the family cannot serve
+        self.programs = PROGRAMS[block](self)
+        # `last_counters` is what the newest program call returned
+        # beside its logits (None: a family without counters),
         # `last_span` the newest `prefill_chunk_dispatch` span: the
         # scheduler completes the span once the program has run.
-        self._latent = None
         self.last_counters = None
         self.last_span = None
-        if self.latent:
-            if kv_dtype != "fp32":
-                raise ValueError("a latent pool holds the compute dtype "
-                                 "(kv_dtype='fp32')")
-            from theanompi_tpu.serving.latent import LatentPrograms
-
-            self._latent = LatentPrograms(self)
-        # trace counter for the spec-decode verify program (one compile
-        # ever per chunk width — acceptance churn must retrace nothing)
+        # trace-time counters: tests pin the zero-recompile discipline
+        # (one decode program ever; one prefill program per chunk
+        # bucket; one verify program per chunk width — acceptance churn
+        # must retrace nothing) by counting how often the program
+        # functions actually retrace
+        self._n_prefill_traces = 0
+        self._n_decode_traces = 0
         self._n_verify_traces = 0
         self._paged_prefill_jit = jax.jit(
             functools.partial(self._paged_chunk_fn, all_logits=False),
@@ -477,61 +490,22 @@ class PagedServingEngine(ServingEngine):
     # ------------------------------------------------------------------
     # state + pool construction
     # ------------------------------------------------------------------
-    def _kv_compute_dtype(self):
-        return self.compute_dtype or jnp.float32
-
     def init_state(self):
-        """Device block pool: ``k``/``v``, each one array a layer,
-        ``(n_blocks · block_size, row_width)``, allocated already
-        sharded.  A resident token holds one row a layer and side: its
-        heads side by side, ``heads · head_dim`` numbers.  ``row_width``
-        is that rounded up to 128 lanes: the device would pad a narrower
-        row to as much anyway, and it lays a tall array whose rows are
-        no multiple of 128 out column-major, which every program would
-        then copy to row-major and back (a ``(…, heads, head_dim)`` pool
-        is worse still: its two minor dimensions are tiled, 2.6 times
-        the bytes at 25 heads of 64).  The layers' arrays are separate
-        leaves of the state (never stacked), each donated and updated
-        in place by its program.  ``kv_dtype='int8'`` adds the
-        per-row/per-head scale planes ``ks``/``vs``, likewise one
-        ``(rows, heads)`` array a layer.  Lengths and block tables stay
+        """Device block pool, allocated already sharded: one array a
+        layer, ``(n_blocks · block_size, width)``, the layers' arrays
+        separate leaves (never stacked), each donated and updated in
+        place by its program.  What a row holds is the family's
+        (``serving/dense.py``: ``k``/``v`` and the int8 scale planes;
+        ``serving/latent.py``: ``kv``).  Lengths and block tables stay
         host-side (tiny ints shipped per call — they are *data*, so
-        shipping them can never recompile anything).  A latent model:
-        ``kv``, one (rows, kv_rank + rope) array a layer
-        (``serving/latent.py``)."""
-        if self._latent is not None:
-            return self._latent.init_state()
-        dt = (
-            jnp.int8 if self.kv_dtype == "int8" else self._kv_compute_dtype()
-        )
-        rows = self.n_blocks * self.block_size
-
-        sh = NamedSharding(self.mesh, self.pool_spec)
-
-        def leaves(width, dtype):
-            return [jnp.zeros((rows, width), dtype, device=sh)
-                    for _ in range(self.n_layers)]
-
-        state = {side: leaves(self.row_width, dt) for side in ("k", "v")}
-        if self.kv_dtype == "int8":
-            for side in ("ks", "vs"):
-                state[side] = leaves(self.n_heads, jnp.float32)
-        return state
+        shipping them can never recompile anything)."""
+        return self.programs.init_state()
 
     def kv_block_bytes(self) -> int:
         """Device bytes ONE pool block occupies across all layers
-        (K + V payload, plus the int8 scale planes) — the equal-byte
-        currency of the ``detail.kv_quant`` capacity probe."""
-        if self._latent is not None:
-            return self._latent.block_bytes()
-        payload = (
-            1 if self.kv_dtype == "int8"
-            else jnp.dtype(self._kv_compute_dtype()).itemsize
-        )
-        row = self.row_width * payload  # the stored width, padding and all
-        if self.kv_dtype == "int8":
-            row += self.n_heads * 4  # fp32 scale per (row, head)
-        return 2 * self.n_layers * self.block_size * row
+        (payload, padding and scale planes) — the equal-byte currency
+        of the ``detail.kv_quant`` capacity probe."""
+        return self.programs.block_bytes()
 
     def blocks_at_budget(self, budget_bytes: int) -> int:
         """How many pool blocks fit in ``budget_bytes`` of cache HBM at
@@ -563,66 +537,10 @@ class PagedServingEngine(ServingEngine):
         return math.ceil(total_tokens / self.block_size)
 
     # ------------------------------------------------------------------
-    # jitted programs (tables/positions are DATA, never shapes)
+    # jitted programs (tables/positions are DATA, never shapes): thin
+    # functions that count traces and call the family's bodies, so the
+    # programs keep their names whatever the family
     # ------------------------------------------------------------------
-    def _gather_rows(self, tables):
-        """(N, blocks_per_seq) block ids → (N, t_pad) physical rows:
-        row j of a sequence's image is logical position j."""
-        bs = self.block_size
-        rows = tables[:, :, None] * bs + jnp.arange(bs)[None, None, :]
-        return rows.reshape(tables.shape[0], -1)
-
-    def _pool_leaves(self, state):
-        """The state's leaves as lists that a program replaces layer by
-        layer (``_kv_write``; a float pool has no scale planes: ``None``
-        a layer), and the dtype in which attention images are gathered."""
-        pool = {side: list(leaves) for side, leaves in state.items()}
-        for side in ("ks", "vs"):
-            pool.setdefault(side, [None] * self.n_layers)
-        img_dt = (
-            self._kv_compute_dtype() if self.kv_dtype == "int8"
-            else pool["k"][0].dtype
-        )
-        return pool, img_dt
-
-    def _kv_write(self, pool, side, i, rows, wr):
-        """Scatter freshly-computed K or V ``rows`` (N, H, hd) (``side``
-        ``'k'`` or ``'v'``) into layer ``i``'s ``(rows, row_width)`` pool
-        at row indices ``wr``: each row's heads laid side by side and
-        padded with zeros to ``row_width``, written into the layer's own
-        donated leaf (in place), which takes the old one's place in
-        ``pool``.  Returns the layer's new pool and scale plane.  fp32
-        path: a cast + scatter, the values bit-identical to PR 8.  int8
-        path: the ``quantize_blocks`` codec over head_dim
-        (per-row/per-head amax scale, into the layer's ``(rows, heads)``
-        scale plane) — quantized ONCE on write, so every later reader
-        (XLA gather, Pallas kernel, a prefix-sharing sibling) sees the
-        same bytes."""
-        pool_l, scale_l = pool[side][i], pool[side + "s"][i]
-        if self.kv_dtype == "int8":
-            from theanompi_tpu.parallel.quantize import quantize_blocks
-
-            rows, s = quantize_blocks(rows.astype(jnp.float32))
-            scale_l = scale_l.at[wr].set(s)
-        flat = rows.astype(pool_l.dtype).reshape(rows.shape[0], -1)
-        flat = jnp.pad(flat, ((0, 0), (0, self.row_width - flat.shape[1])))
-        pool[side][i], pool[side + "s"][i] = pool_l.at[wr].set(flat), scale_l
-        return pool[side][i], scale_l
-
-    def _kv_image(self, pool_l, scale_l, gr_flat, n, dtype):
-        """Gather the attention image for one layer from its ``(rows,
-        row_width)`` pool, and view it as (n, t_pad, H, hd) only after
-        the gather (the lanes' rows, not the pool) — dequantizing int8
-        payloads against their gathered scales."""
-        h, hd = self.n_heads, self.head_dim
-        img = jnp.take(pool_l, gr_flat, axis=0)[:, :h * hd]
-        img = img.reshape(n, self.t_pad, h, hd)
-        if self.kv_dtype == "int8":
-            sc = jnp.take(scale_l, gr_flat, axis=0)
-            img = img.astype(jnp.float32) * sc.reshape(
-                n, self.t_pad, h)[..., None]
-        return img.astype(dtype)
-
     def _paged_chunk_fn(
         self, params, state, tokens, tables, p0, true_len, active,
         all_logits,
@@ -641,148 +559,21 @@ class PagedServingEngine(ServingEngine):
             self._n_verify_traces += 1
         else:
             self._n_prefill_traces += 1
-        if self._latent is not None:
-            return self._latent.chunk_fn(params, state, tokens, tables, p0,
-                                         true_len, active, all_logits)
-        emb, pos, blocks, lnf, head = self._weights(params)
-        p_, c_ = tokens.shape
-        bs = self.block_size
-        h, hd = self.n_heads, self.head_dim
-        positions = p0[:, None] + jnp.arange(c_)[None, :]  # (P, C)
-        with jax.named_scope("embed"):
-            x = self._embed(
-                emb, pos, tokens, jnp.minimum(positions, self.max_len - 1)
-            )  # (P, C, D)
-        blk_idx = jnp.minimum(positions // bs, self.blocks_per_seq - 1)
-        blk = jnp.take_along_axis(tables, blk_idx, axis=1)  # (P, C)
-        valid = active[:, None] & (
-            jnp.arange(c_)[None, :] < true_len[:, None]
-        )
-        wr = jnp.where(valid, blk * bs + positions % bs, TRASH_BLOCK)
-        wr = wr.reshape(-1)  # (P·C,) — collisions only inside trash
-        gr = self._gather_rows(tables).reshape(-1)  # (P·t_pad,)
-        # causal over ABSOLUTE positions: chunk queries see the whole
-        # cached history (earlier chunks / prefix-hit blocks) plus the
-        # intra-chunk triangle, exactly like one full-prompt pass
-        mask = jnp.arange(self.t_pad)[None, None, :] <= positions[:, :, None]
-        pool, img_dt = self._pool_leaves(state)
-        # named scopes are metadata on the same operations: a profile
-        # groups by them (layer<i>/qkv, .../cast_weights inside it, ...)
-        for i, bp in enumerate(blocks):
-            with jax.named_scope(f"layer{i}"):
-                with jax.named_scope("qkv"):
-                    y = self._ln(bp["ln1"], x)
-                    q = self._proj(y, bp["attn"]["wq"]).reshape(p_, c_, h, hd)
-                    k = self._proj(y, bp["attn"]["wk"]).reshape(p_, c_, h, hd)
-                    v = self._proj(y, bp["attn"]["wv"]).reshape(p_, c_, h, hd)
-                with jax.named_scope("pool_update"):
-                    pk_l, pks_l = self._kv_write(
-                        pool, "k", i, k.reshape(p_ * c_, h, hd), wr)
-                    pv_l, pvs_l = self._kv_write(
-                        pool, "v", i, v.reshape(p_ * c_, h, hd), wr)
-                with jax.named_scope("paged_attn"):
-                    kc = self._kv_image(pk_l, pks_l, gr, p_, img_dt)
-                    vc = self._kv_image(pv_l, pvs_l, gr, p_, img_dt)
-                    s = jnp.einsum(
-                        "pchd,pthd->phct", q, kc,
-                        preferred_element_type=jnp.float32,
-                    ) * self.scale
-                    s = jnp.where(mask[:, None, :, :], s, _NEG_INF)
-                    prob = jax.nn.softmax(s, axis=-1)
-                    o = jnp.einsum(
-                        "phct,pthd->pchd", prob.astype(vc.dtype), vc,
-                        preferred_element_type=jnp.float32,
-                    ).astype(y.dtype)
-                with jax.named_scope("attn_out"):
-                    x = x + self._proj(
-                        o.reshape(p_, c_, h * hd), bp["attn"]["wo"])
-                with jax.named_scope("mlp"):
-                    x = x + self._mlp(bp, self._ln(bp["ln2"], x))
-        out = {side: pool[side] for side in state}
-        with jax.named_scope("head"):
-            if all_logits:
-                logits = self._head(lnf, head, x)  # (P, C, V)
-            else:
-                last = jnp.take_along_axis(
-                    x, jnp.maximum(true_len - 1, 0)[:, None, None], axis=1
-                )[:, 0]  # (P, D)
-                logits = self._head(lnf, head, last)
-        return out, logits
+        return self.programs.chunk_fn(params, state, tokens, tables, p0,
+                                      true_len, active, all_logits)
 
     def _paged_decode_fn(
         self, params, state, tokens, tables, lengths, active
     ):
-        """One decode tick for every lane: identical math to the
-        contiguous ``_decode_fn`` with the per-slot cache image
-        gathered through the block table.  Inactive lanes scatter to
-        the trash block — a recycled block can never be corrupted by a
-        lane that no longer owns it.  ``paged_attn='pallas'`` swaps
-        the gather+softmax for the fused kernel (same scatter, same
-        mask semantics — allclose-pinned)."""
+        """One decode tick for every lane: ``tokens`` (S,) int32 — the
+        token ENTERING each lane at position ``lengths[i]``; ``active``
+        (S,) bool.  Writes each lane's row through its block table and
+        returns logits (S, V).  Inactive lanes scatter to the trash
+        block — a recycled block can never be corrupted by a lane that
+        no longer owns it — and compute garbage that is never read."""
         self._n_decode_traces += 1  # runs at trace time only
-        if self._latent is not None:
-            return self._latent.decode_fn(params, state, tokens, tables,
-                                          lengths, active)
-        emb, pos, blocks, lnf, head = self._weights(params)
-        s_ = tokens.shape[0]
-        bs = self.block_size
-        h, hd = self.n_heads, self.head_dim
-        pos_idx = lengths  # (S,) position of the incoming token
-        with jax.named_scope("embed"):
-            x = self._embed(
-                emb, pos, tokens, jnp.minimum(pos_idx, self.max_len - 1)
-            )  # (S, D)
-        blk = jnp.take_along_axis(
-            tables,
-            jnp.minimum(pos_idx // bs, self.blocks_per_seq - 1)[:, None],
-            axis=1,
-        )[:, 0]
-        wr = jnp.where(active, blk * bs + pos_idx % bs, TRASH_BLOCK)
-        gr = self._gather_rows(tables).reshape(-1)  # (S·t_pad,)
-        att_mask = jnp.arange(self.t_pad)[None, :] <= pos_idx[:, None]
-        pool, img_dt = self._pool_leaves(state)
-        use_pallas = self.paged_attn_effective == "pallas"
-        if use_pallas:
-            from theanompi_tpu.ops import pallas_paged
-        for i, bp in enumerate(blocks):  # scopes as in _paged_chunk_fn
-            with jax.named_scope(f"layer{i}"):
-                with jax.named_scope("qkv"):
-                    y = self._ln(bp["ln1"], x)
-                    q = self._proj(y, bp["attn"]["wq"]).reshape(s_, h, hd)
-                    k = self._proj(y, bp["attn"]["wk"]).reshape(s_, h, hd)
-                    v = self._proj(y, bp["attn"]["wv"]).reshape(s_, h, hd)
-                with jax.named_scope("pool_update"):
-                    pk_l, pks_l = self._kv_write(pool, "k", i, k, wr)
-                    pv_l, pvs_l = self._kv_write(pool, "v", i, v, wr)
-                with jax.named_scope("paged_attn"):
-                    if use_pallas:
-                        o = pallas_paged.paged_decode_attention(
-                            q, pk_l, pv_l, tables, pos_idx,
-                            block_size=bs, scale=self.scale,
-                            k_scale=pks_l, v_scale=pvs_l,
-                        ).astype(y.dtype)
-                    else:
-                        kc = self._kv_image(pk_l, pks_l, gr, s_, img_dt)
-                        vc = self._kv_image(pv_l, pvs_l, gr, s_, img_dt)
-                        s = jnp.einsum(
-                            "shd,sthd->sht", q, kc,
-                            preferred_element_type=jnp.float32,
-                        ) * self.scale
-                        s = jnp.where(att_mask[:, None, :], s, _NEG_INF)
-                        prob = jax.nn.softmax(s, axis=-1)
-                        o = jnp.einsum(
-                            "sht,sthd->shd", prob.astype(vc.dtype), vc,
-                            preferred_element_type=jnp.float32,
-                        ).astype(y.dtype)
-                with jax.named_scope("attn_out"):
-                    x = x + self._proj(
-                        o.reshape(s_, h * hd), bp["attn"]["wo"])
-                with jax.named_scope("mlp"):
-                    x = x + self._mlp(bp, self._ln(bp["ln2"], x))
-        out = {side: pool[side] for side in state}
-        with jax.named_scope("head"):
-            logits = self._head(lnf, head, x)
-        return out, logits
+        return self.programs.decode_fn(params, state, tokens, tables,
+                                       lengths, active)
 
     # ------------------------------------------------------------------
     # host entries
@@ -859,9 +650,9 @@ class PagedServingEngine(ServingEngine):
         return state, logits
 
     def _call(self, program, *args):
-        """``(state, logits)`` of a jitted program; what a latent
-        model's program returns beside them (its experts' counters, a
-        device array nobody has waited for) goes to ``last_counters``."""
+        """``(state, logits)`` of a jitted program; what a family's
+        program returns beside them (a latent model's experts' counters,
+        a device array nobody has waited for) goes to ``last_counters``."""
         out = program(*args)
         self.last_counters = out[2] if len(out) > 2 else None
         return out[0], out[1]

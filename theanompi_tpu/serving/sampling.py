@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from theanompi_tpu.serving.engine import host_input
 
-_NEG_INF = -1e30  # engine's finite mask value (engine._NEG_INF)
+_NEG_INF = -1e30  # the attention masks' finite value (ops.pallas_flash)
 
 
 class Sampler:

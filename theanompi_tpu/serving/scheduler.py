@@ -10,23 +10,18 @@ prefill runs between decode ticks) and leaves the moment it finishes,
 returning the slot to the pool.  The decode step never changes shape,
 so admission/retirement cause ZERO recompilation.
 
-Two engine families drive through the same scheduler:
-
-- **contiguous** (``ServingEngine``) — each slot owns a worst-case
-  ``max_len`` cache region; admission prefills one slot at a time.
-- **paged** (``paging.PagedServingEngine``) — slots own *block
-  tables* into a shared pool.  Admission allocates exactly the blocks
-  a request can ever need (prompt + ``max_new_tokens``), reuses
-  cached prefix blocks (refcounted, prefilled once per distinct
-  prefix), and defers — clean backpressure, never a crash — when the
-  pool is exhausted (after evicting idle cached prefixes).  Prefill
-  is **chunked and batched**: every tick, every
-  admitted-but-unprefilled lane advances by up to ``prefill_chunk``
-  prompt tokens, ``prefill_rows`` lanes to a padded dispatch of the
-  engine's narrow prefill program, interleaved with decode ticks so a
-  giant prompt cannot hide the TTFT of requests queued behind it.
-  Finishing releases the slot's blocks back to the pool — the same
-  join-on-finish recycling, now also reclaiming memory.
+The engine is ``paging.PagedServingEngine``: slots own *block tables*
+into a shared pool.  Admission allocates exactly the blocks a request
+can ever need (prompt + ``max_new_tokens``), reuses cached prefix blocks
+(refcounted, prefilled once per distinct prefix), and defers — clean
+backpressure, never a crash — when the pool is exhausted (after
+evicting idle cached prefixes).  Prefill is **chunked and batched**:
+every tick, every admitted-but-unprefilled lane advances by up to
+``prefill_chunk`` prompt tokens, ``prefill_rows`` lanes to a padded
+dispatch of the engine's narrow prefill program, interleaved with
+decode ticks so a giant prompt cannot hide the TTFT of requests queued
+behind it.  Finishing releases the slot's blocks back to the pool —
+join-on-finish recycling that also reclaims memory.
 
 Determinism contract (tested): every per-slot computation in the engine
 is independent across the slot axis, so a request's output under any
@@ -123,23 +118,22 @@ class _Slot:
     def __init__(self):
         self.request: Optional[Request] = None
         self.produced = 0   # tokens generated so far for the request
-        self.blocks: List[int] = []  # paged: block ids this slot holds
-        self.n_fed = 0      # paged: prompt tokens resident (hits + fed)
-        self.decoding = False  # paged: prompt fully prefilled
+        self.blocks: List[int] = []  # block ids this slot holds
+        self.n_fed = 0      # prompt tokens resident (hits + fed)
+        self.decoding = False  # prompt fully prefilled
 
 
 class ContinuousBatchingScheduler:
     """Admission queue + slot table driving one serving engine.
 
-    ``step()`` is one serving tick: admissions, (paged) one batched
-    chunked-prefill dispatch, then one batched decode step for every
+    ``step()`` is one serving tick: admissions, the batched
+    chunked-prefill dispatches, then one batched decode step for every
     active slot.  ``run()`` loops until drained.  Completed requests
     land in ``finished`` (id → token list) and are reported to
     ``metrics`` when one is attached.
 
-    ``pool`` (paged engines only) overrides the block allocator — the
-    bench caps it below the device pool to pin equal-cache-memory
-    comparisons against the contiguous engine.
+    ``pool`` overrides the block allocator — the bench caps it below
+    the device pool to pin equal-cache-memory comparisons.
     """
 
     def __init__(self, engine, metrics=None, params=None,
@@ -155,7 +149,6 @@ class ContinuousBatchingScheduler:
         # separable in /metrics
         self.model_generation = 0
         self.clock = clock
-        self.paged = bool(getattr(engine, "is_paged", False))
         self.slots = [_Slot() for _ in range(engine.n_slots)]
         self.queue: List[Request] = []
         self.finished: Dict[str, List[int]] = {}
@@ -171,7 +164,6 @@ class ContinuousBatchingScheduler:
         # clears this and closes buffers router-side
         self.owns_request_buffers = True
         self._tokens = np.zeros((engine.n_slots,), np.int32)
-        self._active = np.zeros((engine.n_slots,), bool)
         self._sampler = None  # built lazily on the first sampling request
         # per-run reuse/capacity stats (host-side, exact — the registry
         # counters are process-global and shared across schedulers)
@@ -204,55 +196,37 @@ class ContinuousBatchingScheduler:
         # experts' counters nobody has fetched yet (a latent model's;
         # `_note_counters`)
         self._counters: List[tuple] = []
-        if self.paged:
-            if pool is not None and pool.block_size != engine.block_size:
-                raise ValueError("pool/engine block_size mismatch")
-            self.pool = pool if pool is not None else engine.make_pool()
-            impl = (
-                prefix_impl if prefix_impl is not None
-                else getattr(engine, "prefix_impl", "chain")
+        if pool is not None and pool.block_size != engine.block_size:
+            raise ValueError("pool/engine block_size mismatch")
+        self.pool = pool if pool is not None else engine.make_pool()
+        impl = prefix_impl if prefix_impl is not None else engine.prefix_impl
+        if impl not in ("chain", "radix"):
+            raise ValueError(
+                f"prefix_impl must be 'chain' or 'radix', got {impl!r}"
             )
-            if impl not in ("chain", "radix"):
-                raise ValueError(
-                    f"prefix_impl must be 'chain' or 'radix', got {impl!r}"
-                )
-            if engine.prefix_cache_enabled:
-                if impl == "radix":
-                    from theanompi_tpu.serving.radix import RadixPrefixCache
+        if engine.prefix_cache_enabled:
+            if impl == "radix":
+                from theanompi_tpu.serving.radix import RadixPrefixCache
 
-                    self.prefix = RadixPrefixCache(self.pool)
-                else:
-                    from theanompi_tpu.serving.paging import PrefixCache
-
-                    self.prefix = PrefixCache(self.pool)
+                self.prefix = RadixPrefixCache(self.pool)
             else:
-                self.prefix = None
-            self.state = engine.init_state()
-            if getattr(engine, "latent", False):
-                # the latent pool's size and fill, in rows (= tokens)
-                self.stats["latent_rows_capacity"] = (
-                    (self.pool.n_blocks - 1) * engine.block_size)
-                self.stats["latent_rows_resident"] = 0
-            self._tables = np.zeros(
-                (engine.n_slots, engine.blocks_per_seq), np.int32
-            )
-            self._lengths = np.zeros((engine.n_slots,), np.int32)
+                from theanompi_tpu.serving.paging import PrefixCache
+
+                self.prefix = PrefixCache(self.pool)
         else:
-            if pool is not None:
-                raise ValueError(
-                    "pool= applies to paged engines only"
-                )
-            self.pool = None
             self.prefix = None
-            self.cache = engine.init_cache()
+        self.state = engine.init_state()
+        if engine.programs.latent:
+            # the latent pool's size and fill, in rows (= tokens)
+            self.stats["latent_rows_capacity"] = (
+                (self.pool.n_blocks - 1) * engine.block_size)
+            self.stats["latent_rows_resident"] = 0
+        self._tables = np.zeros(
+            (engine.n_slots, engine.blocks_per_seq), np.int32
+        )
+        self._lengths = np.zeros((engine.n_slots,), np.int32)
         self._spec = None
         if int(spec_k):
-            if not self.paged:
-                raise ValueError(
-                    "speculative decoding (spec_k>0) requires a paged "
-                    "engine — the verify dispatch is the chunked-prefill "
-                    "machinery"
-                )
             if draft_engine is None:
                 raise ValueError(
                     "spec_k>0 needs a draft_engine (see "
@@ -303,14 +277,13 @@ class ContinuousBatchingScheduler:
                 f"request {request.id!r} needs {total} cache rows > "
                 f"max_len={self.engine.max_len}"
             )
-        if self.paged:
-            need = self.engine.max_seq_blocks(total)
-            if need > self.pool.n_blocks - 1:
-                raise ValueError(
-                    f"request {request.id!r} needs {need} KV blocks > "
-                    f"pool capacity {self.pool.n_blocks - 1} — it could "
-                    "never be admitted"
-                )
+        need = self.engine.max_seq_blocks(total)
+        if need > self.pool.n_blocks - 1:
+            raise ValueError(
+                f"request {request.id!r} needs {need} KV blocks > "
+                f"pool capacity {self.pool.n_blocks - 1} — it could "
+                "never be admitted"
+            )
         if self.metrics is not None:
             self.metrics.admitted(request.id, len(request.prompt),
                                   t=self.clock(),
@@ -334,9 +307,7 @@ class ContinuousBatchingScheduler:
     @property
     def n_active(self) -> int:
         """Occupied slots (prefilling or decoding)."""
-        if self.paged:
-            return sum(1 for s in self.slots if s.request is not None)
-        return int(self._active.sum())
+        return sum(1 for s in self.slots if s.request is not None)
 
     def _note_concurrency(self) -> None:
         self.stats["peak_concurrent"] = max(
@@ -348,21 +319,19 @@ class ContinuousBatchingScheduler:
         self.finished[req.id] = req.output
         if self.metrics is not None:
             self.metrics.finished(req.id, len(req.output), t=self.clock())
-        if self.paged:
-            # join-on-finish recycling now also reclaims memory: every
-            # block reference this slot holds goes back to the pool
-            # (prefix-cached blocks just drop one ref and live on)
-            self.pool.release_all(slot.blocks)
-            slot.blocks = []
-            slot.n_fed = 0
-            slot.decoding = False
-            self._tables[i, :] = 0
-            self._lengths[i] = 0
-            if self._spec is not None:
-                self._spec.release_slot(i)
+        # join-on-finish recycling also reclaims memory: every block
+        # reference this slot holds goes back to the pool
+        # (prefix-cached blocks just drop one ref and live on)
+        self.pool.release_all(slot.blocks)
+        slot.blocks = []
+        slot.n_fed = 0
+        slot.decoding = False
+        self._tables[i, :] = 0
+        self._lengths[i] = 0
+        if self._spec is not None:
+            self._spec.release_slot(i)
         slot.request = None
         slot.produced = 0
-        self._active[i] = False
         if obs.request_tracking_active():
             # close the request buffer at the END of step(), after the
             # tick's phase spans have landed in it
@@ -373,28 +342,6 @@ class ContinuousBatchingScheduler:
     # ------------------------------------------------------------------
     # token picking (batched, device-side)
     # ------------------------------------------------------------------
-    def _pick_token(self, req: Request, logits) -> int:
-        """Next token for ``req`` from its logits (V,): exact host
-        argmax for greedy requests (unchanged path), the shared jitted
-        sampler otherwise, keyed by seed + token index so interleaving
-        can never perturb a request's stream."""
-        import jax.numpy as jnp
-
-        if req.temperature == 0.0:
-            return int(jnp.argmax(logits))
-        if self._sampler is None:
-            from theanompi_tpu.serving.sampling import Sampler
-
-            self._sampler = Sampler()
-        from theanompi_tpu.serving.sampling import request_key
-
-        key = request_key(
-            req.seed, req.id, req.token_index0 + len(req.output)
-        )
-        return self._sampler.sample(
-            logits, key, req.temperature, req.top_k
-        )
-
     def _pick_batch(self, reqs: List[Optional[Request]], logits):
         """Next token for every row of ``logits`` (N, V) in ONE device
         dispatch + ONE host transfer.  ``reqs[i] is None`` marks a row
@@ -517,72 +464,7 @@ class ContinuousBatchingScheduler:
         self._req_done.clear()
 
     # ------------------------------------------------------------------
-    # contiguous tick
-    # ------------------------------------------------------------------
-    def _step_contiguous(self) -> int:
-        produced = 0
-        # 1) join-on-finish admission: every free slot takes the oldest
-        # queued request; its prefill yields the request's FIRST token
-        for i, slot in enumerate(self.slots):
-            if slot.request is not None or not self.queue:
-                continue
-            req = self.queue.pop(0)
-            self._note_admitted(req.id)
-            slot.request = req
-            with obs.span("prefill", slot=i, rid=req.id,
-                          n_prompt=len(req.prompt)):
-                self.cache, logits = self.engine.prefill(
-                    self.params, self.cache, i, req.prompt, rid=req.id
-                )
-            self._active[i] = True
-            self._note_concurrency()
-            _SLOTS.set(self.n_active)
-            _QUEUE.set(len(self.queue))
-            produced += 1
-            if self._emit(i, self._pick_token(req, logits)):
-                self._finish(i)
-        # 2) one fixed-shape decode tick over the active slots
-        if self._active.any():
-            track = obs.request_tracking_active()
-            if track:
-                t0 = self.clock()
-                rids = [
-                    s.request.id if self._active[i] else None
-                    for i, s in enumerate(self.slots)
-                ]
-            for i, slot in enumerate(self.slots):
-                # the token entering each active slot = its last output
-                self._tokens[i] = (
-                    slot.request.output[-1] if self._active[i] else 0
-                )
-            was_active = self._active.copy()
-            with obs.span("decode_step", boundary=True,
-                          active=int(was_active.sum())):
-                self.cache, logits = self.engine.decode_step(
-                    self.params, self.cache, self._tokens, self._active
-                )
-            toks = self._pick_batch(
-                [s.request if was_active[i] else None
-                 for i, s in enumerate(self.slots)],
-                logits,
-            )
-            for i in range(len(self.slots)):
-                if not was_active[i]:
-                    continue
-                produced += 1
-                if self._emit(i, int(toks[i])):
-                    self._finish(i)
-            if track:
-                t1 = self.clock()
-                for i in range(len(self.slots)):
-                    if rids[i] is not None:
-                        obs.add_span(
-                            "req_decode", t0, t1, {"rid": rids[i]}
-                        )
-        return produced
-
-    # ------------------------------------------------------------------
-    # paged tick
+    # the tick's three parts
     # ------------------------------------------------------------------
     def _admit_paged(self) -> None:
         """Free slots take queued requests FIFO; each admission reuses
@@ -728,7 +610,6 @@ class ContinuousBatchingScheduler:
                 if self.prefix is not None:
                     self.prefix.insert(req.prompt, s.blocks)
                 s.decoding = True
-                self._active[i] = True
                 produced += 1
                 if self._emit(i, int(token)):
                     self._finish(i)
@@ -920,8 +801,8 @@ class ContinuousBatchingScheduler:
 
     # ------------------------------------------------------------------
     def step(self) -> int:
-        """One tick: admissions, (paged) chunked prefill, then one
-        decode step.  Returns the number of tokens generated."""
+        """One tick: admissions, chunked prefill, then one decode
+        step.  Returns the number of tokens generated."""
         self._n_ticks += 1
         # boundary span over the whole tick; its children are `admit`,
         # `prefill`, `decode_step`/`spec_verify` and `pick`, so its self
@@ -947,26 +828,18 @@ class ContinuousBatchingScheduler:
             phase_of: Dict[str, str] = {}
             for s in self.slots:
                 if s.request is not None:
-                    feeding = (
-                        self.paged
-                        and s.n_fed < len(s.request.prompt)
-                    )
+                    feeding = s.n_fed < len(s.request.prompt)
                     phase_of[s.request.id] = (
                         "req_prefill" if feeding else "req_decode"
                     )
-        produced = (
-            self._step_paged() if self.paged else self._step_contiguous()
-        )
+        produced = self._step_paged()
         if track:
             t1 = self.clock()
             for s in self.slots:
                 if s.request is not None:
                     rid = s.request.id
                     if rid not in phase_of:
-                        feeding = (
-                            self.paged
-                            and s.n_fed < len(s.request.prompt)
-                        )
+                        feeding = s.n_fed < len(s.request.prompt)
                         phase_of[rid] = (
                             "req_prefill" if feeding else "req_decode"
                         )
@@ -1011,12 +884,11 @@ class ContinuousBatchingScheduler:
                 telemetry.stop()
         if self.metrics is not None:
             stats = dict(self.stats)
-            if self.paged:
-                stats["pool_peak_used_blocks"] = self.pool.peak_used
-                stats["pool_blocks"] = self.pool.n_blocks - 1
-                if self.prefix is not None:
-                    stats["prefix_entries"] = len(self.prefix)
-                if self._spec is not None:
-                    stats["spec"] = self._spec.summary()
+            stats["pool_peak_used_blocks"] = self.pool.peak_used
+            stats["pool_blocks"] = self.pool.n_blocks - 1
+            if self.prefix is not None:
+                stats["prefix_entries"] = len(self.prefix)
+            if self._spec is not None:
+                stats["spec"] = self._spec.summary()
             self.metrics.set_engine_stats(stats)
         return self.finished

@@ -72,9 +72,6 @@ class SpecDecoder:
                 f"spec k must be >= 1 (got {k}); spec_k=0 on the "
                 "scheduler disables speculation instead"
             )
-        if not getattr(draft_engine, "is_paged", False):
-            raise ValueError("the draft engine must be paged "
-                             "(PagedServingEngine)")
         if draft_engine.vocab_size != engine.vocab_size:
             raise ValueError(
                 f"draft vocab {draft_engine.vocab_size} != target vocab "
