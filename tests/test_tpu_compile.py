@@ -25,6 +25,7 @@ Rules this file keeps (``on-chip-measurement`` guide, section 2):
   written for a described chip cannot be read back without one.
 """
 
+import math
 import re
 
 import jax
@@ -217,12 +218,16 @@ def test_dense_pool_is_updated_in_place_at_25_heads_of_64(
     """The decode program and the four-row prefill program of the
     benchmark's ``gpt2-xl`` engine, at full depth and at the width that
     pads (25 heads of 64: 1,600 numbers a row, stored 1,664 wide),
-    bfloat16 pool, Pallas decode kernel, float32 weights: the pool's
-    96 arrays are donated and written in place, so a program holds no
-    temporary of the pool's size (the stacked ``(48, rows, 25, 64)``
-    pool of before: 6.93 GB of temporaries at 257 blocks, and 513 did
-    not fit).  At 513 blocks, what the benchmark's issue first asked
-    for, weights, pool and temporaries stay under 16 GiB."""
+    bfloat16 pool, Pallas decode kernel, the weights as the scheduler
+    hands them over (the serving tree: ``jax.eval_shape`` of the engine's
+    hook): the pool's 96 arrays are donated and written in place, so a
+    program holds no temporary of the pool's size (the stacked ``(48,
+    rows, 25, 64)`` pool of before: 6.93 GB of temporaries at 257
+    blocks, and 513 did not fit), and no program reads a float32 matrix
+    or table to cast it (9.26 GB of arguments at 257 blocks before, the
+    whole embedding table converted for 32 rows of it).  At 513 blocks,
+    what the benchmark's issue first asked for, weights, pool and
+    temporaries stay under 16 GiB."""
     from theanompi_tpu.models.transformer import TransformerLM
     from theanompi_tpu.serving import PagedServingEngine
 
@@ -239,7 +244,11 @@ def test_dense_pool_is_updated_in_place_at_25_heads_of_64(
         prefill_chunk=256, paged_attn="pallas",
     )
     assert (eng.programs.row_width, eng.prefill_rows) == (1664, 4)
-    params = _described(model.params, one_chip)
+    params = _described(jax.eval_shape(eng.serving_params, model.params),
+                        one_chip)
+    matrices = {",".join(map(str, a.shape)) for a in jax.tree.leaves(params)
+                if a.dtype == jnp.bfloat16}
+    assert {"1600,1600", "1600,6400", "50257,1600", "1600,50257"} <= matrices
     state = _described(jax.eval_shape(eng.init_state), one_chip)
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
     assert pool_bytes == 2 * 48 * n_blocks * 32 * 1664 * 2
@@ -267,6 +276,27 @@ def test_dense_pool_is_updated_in_place_at_25_heads_of_64(
         held = (m.argument_size_in_bytes + m.temp_size_in_bytes
                 + m.output_size_in_bytes - m.alias_size_in_bytes)
         assert held < HBM_BYTES, held
+        # the 48 layers share one body of code a fusion (49 and 245 MB
+        # with every call inlined, which is what the compiler chooses for
+        # a program of this size: paging.PagedServingEngine)
+        assert m.generated_code_size_in_bytes < 16 * 1024 ** 2
+        # 3.28 GB of weights beside the pool (6.63 GB in float32)
+        assert m.argument_size_in_bytes - pool_bytes < 3.7e9
+        if n_blocks == 257:
+            assert m.argument_size_in_bytes < 6.3e9
+        # every matrix and table arrives in bfloat16 and none is converted
+        # (the float32 leaves left are scales and biases: 50,257 at most)
+        text = program.as_text()
+        op = r"= (\w+)\[([\d,]+)\]\S* %s\("
+        arguments = re.findall(op % "parameter", text[text.index("\nENTRY "):])
+        assert len(arguments) > 96 + 48 * 12
+        bad = [(dtype, shape) for dtype, shape in arguments
+               if dtype == "f32"
+               and math.prod(map(int, shape.split(","))) >= 1600 * 1600]
+        assert not bad, bad[:4]
+        bad = [shape for dtype, shape in re.findall(op % "convert", text)
+               if dtype == "bf16" and shape in matrices]
+        assert not bad, bad[:4]
     assert decode.as_text().count("tpu_custom_call") >= 48
 
 

@@ -104,6 +104,8 @@ BOUNDARY_SPANS = (
     # serving: ContinuousBatchingScheduler.step and what it calls
     "tick", "admit", "prefill", "prefill_chunk_dispatch", "decode_step",
     "spec_verify", "pick",
+    # serving: a params tree becomes a scheduler's (paging.serving_params)
+    "weights_relayout",
     # training: TpuModel.train_iter, the Recorder's phases, the print
     "train_iter", "wait", "calc", "print",
 )

@@ -39,6 +39,12 @@ from theanompi_tpu.ops.pallas_flash import _NEG_INF
 from theanompi_tpu.runtime.mesh import DATA_AXIS, TP_AXIS
 from theanompi_tpu.serving.engine import TRASH_BLOCK
 
+# The leaves ``_run`` multiplies by or gathers from, by their own names:
+# the projections, the feed-forward's and the head's ``w``, the embedding
+# and position tables.  LayerNorm scales and every bias enter float32
+# arithmetic and stay as they are.
+COMPUTE_LEAVES = frozenset(("wq", "wk", "wv", "wo", "w", "table", "pos"))
+
 
 class DensePrograms:
     """What ``PagedServingEngine`` runs for a ``dense`` model: the
@@ -88,6 +94,26 @@ class DensePrograms:
         # so that a shard is a whole number of heads
         width = self.n_heads * self.head_dim
         self.row_width = width if head_ax else -(-width // 128) * 128
+
+    # ---- weights ---------------------------------------------------------
+    @staticmethod
+    def serving_params(params, compute_dtype):
+        """``params`` with its ``COMPUTE_LEAVES`` in ``compute_dtype``
+        (module docstring).  A leaf already there comes back as the same
+        array, so a serving tree comes back as the arrays it is made of,
+        and where ``compute_dtype`` is ``None`` every tree does; a cast
+        leaf keeps its sharding (an elementwise operation on a placed
+        array)."""
+        if compute_dtype is None:
+            return params
+
+        def leaf(path, a):
+            name = getattr(path[-1], "key", None)
+            if name not in COMPUTE_LEAVES or a.dtype == compute_dtype:
+                return a
+            return jnp.asarray(a).astype(compute_dtype)
+
+        return jax.tree_util.tree_map_with_path(leaf, params)
 
     # ---- state -----------------------------------------------------------
     def init_state(self):

@@ -321,7 +321,10 @@ class ServeReplica:
             prefix = getattr(self.scheduler, "prefix", None)
             if prefix is not None:
                 prefix.evict_unused(None)
-            self.scheduler.params = params
+            # in the engine's serving layout (a tree that came through
+            # `relayout_for_serving`, or a rollback's prior tree, is
+            # already there and is bound as it is)
+            self.scheduler.install_params(params)
             self.installs += 1
             # install epoch: the membership roster's rejoin bump IS the
             # monotone epoch counter (generation machinery reused, not

@@ -145,6 +145,14 @@ class LatentPrograms:
             return "xla"
         return e.paged_attn_effective
 
+    # ---- weights ---------------------------------------------------------
+    @staticmethod
+    def serving_params(params, compute_dtype):
+        """The tree as it is: a latent model's weights are held in the
+        dtype it computes in (``serving/dense.py`` has the family whose
+        are not)."""
+        return params
+
     # ---- state -----------------------------------------------------------
     def init_state(self):
         e = self.engine

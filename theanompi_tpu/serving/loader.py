@@ -36,7 +36,13 @@ def relayout_for_serving(model, params):
     ``ServeReplica.install_params``.  Replication covers plain-dp
     serving; tp leaves move replicated → Megatron-sharded per the same
     ``_build_param_specs`` tree training shards by (a no-op when the
-    mesh has no ``tp`` axis or the model declares no specs)."""
+    mesh has no ``tp`` axis or the model declares no specs).  The placed
+    tree is handed back as the **serving tree** its programs read
+    (``paging.serving_params``: a ``dense`` model's matrices and tables
+    in the compute dtype), which is what a scheduler holds, so that
+    ``publish.validate_swap`` compares like with like."""
+    from theanompi_tpu.serving.paging import serving_params
+
     if jax.tree.structure(params) != jax.tree.structure(model.params):
         raise ValueError(
             "published snapshot has a different params structure than "
@@ -55,7 +61,7 @@ def relayout_for_serving(model, params):
             placed,
             specs,
         )
-    return placed
+    return serving_params(model, placed)
 
 
 def restore_params_for_serving(model, path: str):
@@ -130,13 +136,18 @@ def load_engine(
         else model_cls(config=cfg)
     )
     restore_params_for_serving(model, path)
-    return PagedServingEngine(
+    engine = PagedServingEngine(
         model, n_slots=n_slots, max_len=max_len, buckets=buckets,
         block_size=block_size, n_blocks=n_blocks,
         prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
         prefix_impl=prefix_impl, kv_dtype=kv_dtype,
         paged_attn=paged_attn,
     )
+    # this model exists to be served: it holds the serving tree, and the
+    # restored float32 leaves the tree replaces are freed (a scheduler
+    # built on the engine then binds the same arrays)
+    model.params = engine.serving_params(model.params)
+    return engine
 
 
 def load_replica(

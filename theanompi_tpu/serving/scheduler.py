@@ -46,6 +46,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from theanompi_tpu import observability as obs
@@ -142,7 +143,6 @@ class ContinuousBatchingScheduler:
                  prefix_impl: Optional[str] = None):
         self.engine = engine
         self.metrics = metrics
-        self.params = params if params is not None else engine.model.params
         # the model generation these params came from (publish/ live
         # installs set it alongside the whole-tree params rebind); it
         # labels admissions and the token counter so A/B cohorts stay
@@ -168,6 +168,7 @@ class ContinuousBatchingScheduler:
         # per-run reuse/capacity stats (host-side, exact — the registry
         # counters are process-global and shared across schedulers)
         self.stats = {
+            "weight_bytes": 0,  # of the tree the programs read
             "peak_concurrent": 0,
             "prefill_tokens": 0,
             "prefill_chunks": 0,
@@ -177,6 +178,8 @@ class ContinuousBatchingScheduler:
             "backpressure_events": 0,
             "drain_refusals": 0,
         }
+        self.install_params(
+            params if params is not None else engine.model.params)
         # request forensics (observability request tracking, all gated
         # on obs.request_tracking_active()): enqueue timestamps for
         # queue-wait spans, when the head of the queue started stalling
@@ -243,6 +246,18 @@ class ContinuousBatchingScheduler:
                              "spec_k>=1 to enable speculation")
 
     # ------------------------------------------------------------------
+    def install_params(self, params) -> None:
+        """Make ``params`` the tree every later program call reads: the
+        engine's serving tree of it (``paging.serving_params``: a
+        ``dense`` model's matrices and tables in the compute dtype, cast
+        here once and not by every call), bound whole.  The tree is this
+        scheduler's own and dies with it; the caller's arrays and the
+        model are left as they were.  Between ticks only (a replica
+        calls it from its idle gap)."""
+        self.params = self.engine.serving_params(params)
+        self.stats["weight_bytes"] = sum(
+            int(a.nbytes) for a in jax.tree.leaves(self.params))
+
     def begin_drain(self) -> None:
         """Stop admitting: queued + in-flight requests run to
         completion (their blocks release through the ordinary finish

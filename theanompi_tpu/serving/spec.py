@@ -91,7 +91,9 @@ class SpecDecoder:
         self.engine = engine
         self.draft = draft_engine
         self.k = int(k)
-        self.draft_params = (
+        # the draft's serving tree (paging.serving_params), this
+        # decoder's own
+        self.draft_params = draft_engine.serving_params(
             draft_params if draft_params is not None
             else draft_engine.model.params
         )
