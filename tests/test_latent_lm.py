@@ -195,8 +195,9 @@ def test_paged_prefill_and_decode_match_the_reference_everywhere(
             np.testing.assert_allclose(
                 np.asarray(logits[i]), want[i][lengths[i]], atol=TOL, rtol=0)
         lengths += 1
-    hit, load = np.asarray(eng.last_counters)
+    hit, load, pairs = np.asarray(eng.last_counters)
     assert 2 <= hit <= 2 * 4 and 1 <= load <= 2  # two expert layers, two tokens
+    assert pairs == 2 * 2 * 2  # every pick of both tokens: all experts held
 
 
 def test_served_tokens_are_the_references_and_the_spans_carry_the_counters(

@@ -154,6 +154,141 @@ def _alexnet_step(topo, n_devices, batch_size=512):
     return step, args
 
 
+# The two serving stages come first among the whole-program compiles and
+# the four-chip step last: xdist hands out the files with the most tests
+# first, so this file and tests/test_benchmarks_harness.py start together
+# on two workers, and the stage compiles (the heaviest on the CPU) then
+# fall well before the harness's one-second traced windows, which count
+# finished requests and run some three minutes in.
+
+def test_latent_stage_programs_fit_one_v5e_chip(one_chip, as_tpu):
+    """The decode program and the widest prefill program of the
+    benchmark's ``xing4.0-29b-a4b`` stage, at the sizes of its
+    configuration file (published widths, 4.79 G bfloat16 weights, the
+    12,289-block latent pool), compile for a v5e with their four kernels
+    inside and fit the chip: arguments, temporaries and the outputs that
+    are not the donated pool's stay under 16 GiB."""
+    import json
+    import os
+
+    from theanompi_tpu.models.transformer import TransformerLM
+    from theanompi_tpu.serving import PagedServingEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "xing4.0-29b-a4b.json")) as f:
+        cfg = json.load(f)
+    pc = dict(cfg["program_config"], seed=0)
+    model = TransformerLM(
+        config=pc,
+        mesh=TransformerLM.build_mesh(devices=jax.devices()[:1], config=pc),
+    )
+    assert 4.79e9 < model.n_params < 4.80e9 and model.opt_state is None
+    eng = PagedServingEngine(model, **cfg["engine"])
+    assert eng.paged_attn_effective == "pallas" and eng.prefill_rows == 1
+    # 393,216 usable rows, stored 640 wide: 3.02 GB over the six layers
+    assert eng.kv_block_bytes() * eng.n_blocks == 12289 * 32 * 6 * 640 * 2
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one_chip),
+        model.params)
+    state = _described(jax.eval_shape(eng.init_state), one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s, nb, c = eng.n_slots, eng.blocks_per_seq, eng.chunk_buckets[-1]
+    r = eng.prefill_rows
+    assert (s, nb, c) == (32, 544, 2048)
+    prefill = eng._paged_prefill_jit.lower(
+        params, state, arg(jnp.int32, r, c), arg(jnp.int32, r, nb),
+        arg(jnp.int32, r), arg(jnp.int32, r), arg(jnp.bool_, r),
+    ).compile()
+    decode = eng._paged_decode_jit.lower(
+        params, state, arg(jnp.int32, s), arg(jnp.int32, s, nb),
+        arg(jnp.int32, s), arg(jnp.bool_, s),
+    ).compile()
+    for program in (prefill, decode):
+        m = program.memory_analysis()
+        held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+        assert held < HBM_BYTES, held
+        # the pool is updated in place: no copy of it among the temporaries
+        assert m.temp_size_in_bytes < 2 * 1024 ** 3, m.temp_size_in_bytes
+    text = decode.as_text()
+    for kernel in ("mla_paged_decode", "moe_grouped_mm_gate", "mhc_pre",
+                   "mhc_post"):
+        assert kernel in text, kernel
+    assert "moe_grouped_mm_down" in prefill.as_text()
+
+
+def test_kimi_linear_stage_programs_fit_one_v5e_chip(one_chip, as_tpu):
+    """The decode program and the widest prefill program of the
+    benchmark's ``kimi-linear-48b-a3b`` stage, at the sizes of its
+    configuration file (published widths, 4.27 G bfloat16 weights with 64
+    of 256 experts held, the 35,841-block latent pool over two layers,
+    64 lanes of recurrent state over seven), compile for a v5e with
+    their kernels inside and fit the chip; pool and state are updated in
+    place."""
+    import json
+    import os
+
+    from theanompi_tpu.models.transformer import TransformerLM
+    from theanompi_tpu.serving import PagedServingEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        cfg = json.load(f)
+    pc = dict(cfg["program_config"], seed=0)
+    model = TransformerLM(
+        config=pc,
+        mesh=TransformerLM.build_mesh(devices=jax.devices()[:1], config=pc),
+    )
+    assert 4.26e9 < model.n_params < 4.28e9 and model.opt_state is None
+    eng = PagedServingEngine(model, **cfg["engine"])
+    assert eng.paged_attn_effective == "pallas" and eng.prefill_rows == 1
+    assert eng.programs.recurrent and not eng.prefix_cache_enabled
+    # 1,146,880 usable rows, stored 640 wide over the two latent layers
+    assert eng.kv_block_bytes() * eng.n_blocks == 35841 * 32 * 2 * 640 * 2
+    # 64 lanes x 7 layers x (32 x 128 x 128 x 4 B + 3 x 12,288 x 2 B)
+    assert eng.programs.recurrent_state_bytes() == 64 * 7 * (2 ** 21 + 73728)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one_chip),
+        model.params)
+    state = _described(jax.eval_shape(eng.init_state), one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s, nb, c = eng.n_slots, eng.blocks_per_seq, eng.chunk_buckets[-1]
+    r = eng.prefill_rows
+    assert (s, nb, c) == (64, 560, 2048)
+    prefill = eng._paged_prefill_jit.lower(
+        params, state, arg(jnp.int32, r, c), arg(jnp.int32, r, nb),
+        arg(jnp.int32, r), arg(jnp.int32, r), arg(jnp.bool_, r),
+        arg(jnp.int32, r),
+    ).compile()
+    decode = eng._paged_decode_jit.lower(
+        params, state, arg(jnp.int32, s), arg(jnp.int32, s, nb),
+        arg(jnp.int32, s), arg(jnp.bool_, s),
+    ).compile()
+    for name, program in (("prefill", prefill), ("decode", decode)):
+        m = program.memory_analysis()
+        held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+        print(name, "arguments", m.argument_size_in_bytes, "temporaries",
+              m.temp_size_in_bytes, "held", held)
+        assert held < HBM_BYTES, held
+        # pool and state are updated in place: no copy among the temporaries
+        assert m.temp_size_in_bytes < 2 * 1024 ** 3, m.temp_size_in_bytes
+    text = decode.as_text()
+    for kernel in ("kda_decode", "mla_paged_decode", "moe_grouped_mm_gate"):
+        assert kernel in text, kernel
+    assert "mhc_pre" not in text  # one stream: no hyper-connections
+    text = prefill.as_text()
+    assert "kda_chunk_prefill" in text and "moe_grouped_mm_down" in text
+
+
 def test_alexnet_step_fits_one_v5e_chip(topo, as_tpu):
     """Batch 512, bf16, every layer at its width: the step the *train*
     phase of chip_smoke.py runs."""
@@ -162,14 +297,6 @@ def test_alexnet_step_fits_one_v5e_chip(topo, as_tpu):
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < need < HBM_BYTES, f"step needs {need / 2**30:.2f} GiB"
-
-
-def test_bsp_step_over_four_chips_holds_an_all_reduce(topo, as_tpu):
-    step, args = _alexnet_step(topo, 4)
-    text = step.lower(*args).compile().as_text()
-    assert re.search(r"all-reduce(-start)?\(", text), (
-        "dp=4 BSP step compiled without an all-reduce"
-    )
 
 
 @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
@@ -300,61 +427,9 @@ def test_dense_pool_is_updated_in_place_at_25_heads_of_64(
     assert decode.as_text().count("tpu_custom_call") >= 48
 
 
-def test_latent_stage_programs_fit_one_v5e_chip(one_chip, as_tpu):
-    """The decode program and the widest prefill program of the
-    benchmark's ``xing4.0-29b-a4b`` stage, at the sizes of its
-    configuration file (published widths, 4.79 G bfloat16 weights, the
-    12,289-block latent pool), compile for a v5e with their four kernels
-    inside and fit the chip: arguments, temporaries and the outputs that
-    are not the donated pool's stay under 16 GiB."""
-    import json
-    import os
-
-    from theanompi_tpu.models.transformer import TransformerLM
-    from theanompi_tpu.serving import PagedServingEngine
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "configs",
-                           "xing4.0-29b-a4b.json")) as f:
-        cfg = json.load(f)
-    pc = dict(cfg["program_config"], seed=0)
-    model = TransformerLM(
-        config=pc,
-        mesh=TransformerLM.build_mesh(devices=jax.devices()[:1], config=pc),
+def test_bsp_step_over_four_chips_holds_an_all_reduce(topo, as_tpu):
+    step, args = _alexnet_step(topo, 4)
+    text = step.lower(*args).compile().as_text()
+    assert re.search(r"all-reduce(-start)?\(", text), (
+        "dp=4 BSP step compiled without an all-reduce"
     )
-    assert 4.79e9 < model.n_params < 4.80e9 and model.opt_state is None
-    eng = PagedServingEngine(model, **cfg["engine"])
-    assert eng.paged_attn_effective == "pallas" and eng.prefill_rows == 1
-    # 393,216 usable rows, stored 640 wide: 3.02 GB over the six layers
-    assert eng.kv_block_bytes() * eng.n_blocks == 12289 * 32 * 6 * 640 * 2
-    params = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one_chip),
-        model.params)
-    state = _described(jax.eval_shape(eng.init_state), one_chip)
-
-    def arg(dtype, *shape):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    s, nb, c = eng.n_slots, eng.blocks_per_seq, eng.chunk_buckets[-1]
-    r = eng.prefill_rows
-    assert (s, nb, c) == (32, 544, 2048)
-    prefill = eng._paged_prefill_jit.lower(
-        params, state, arg(jnp.int32, r, c), arg(jnp.int32, r, nb),
-        arg(jnp.int32, r), arg(jnp.int32, r), arg(jnp.bool_, r),
-    ).compile()
-    decode = eng._paged_decode_jit.lower(
-        params, state, arg(jnp.int32, s), arg(jnp.int32, s, nb),
-        arg(jnp.int32, s), arg(jnp.bool_, s),
-    ).compile()
-    for program in (prefill, decode):
-        m = program.memory_analysis()
-        held = (m.argument_size_in_bytes + m.temp_size_in_bytes
-                + m.output_size_in_bytes - m.alias_size_in_bytes)
-        assert held < HBM_BYTES, held
-        # the pool is updated in place: no copy of it among the temporaries
-        assert m.temp_size_in_bytes < 2 * 1024 ** 3, m.temp_size_in_bytes
-    text = decode.as_text()
-    for kernel in ("mla_paged_decode", "moe_grouped_mm_gate", "mhc_pre",
-                   "mhc_post"):
-        assert kernel in text, kernel
-    assert "moe_grouped_mm_down" in prefill.as_text()

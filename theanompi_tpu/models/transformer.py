@@ -82,12 +82,20 @@ class TransformerLM(TpuModel):
         # latent attention, a gated feed-forward in the first
         # `first_k_dense` blocks and `moe_experts` sigmoid-routed experts
         # (+ `n_shared_experts`) after them, a residual of `hc_mult`
-        # streams; its sizes are the keys below
+        # streams (`hc_mult` 1: the plain residual); its sizes are the
+        # keys below.  `q_lora_rank` None: queries from one matrix;
+        # `mla_nope`: no rotary positions; `kda_layers`: the layers
+        # (counted from 1) whose mixer is Kimi delta attention
+        # (ops.kda: `kda_head_dim`, `kda_conv`) and not latent
+        # attention; `moe_experts_held`: how many of the `moe_experts`
+        # (the first ones) this model holds and computes (parallel.moe)
         q_lora_rank=None, kv_lora_rank=None, qk_nope_head_dim=None,
         qk_rope_head_dim=None, v_head_dim=None,
         ffn_hidden=None, first_k_dense=1, n_shared_experts=0,
         route_scale=1.0, rms_norm_eps=1e-6,
         hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6, hc_clamp=30.0,
+        mla_nope=False, kda_layers=(), kda_head_dim=128, kda_conv=4,
+        moe_experts_held=None,
         rope=None,  # dict: theta, factor, original_max_position,
         # beta_fast, beta_slow, mscale, mscale_all_dim (ops.attention)
         param_dtype="float32",  # the dtype the weights are HELD in
@@ -301,10 +309,22 @@ class TransformerLM(TpuModel):
         dt = jnp.dtype(cfg.compute_dtype) if cfg.compute_dtype else None
         pdt = jnp.dtype(cfg.param_dtype)
         d, n = int(cfg.d_model), int(cfg.hc_mult)
+        q_rank = None if cfg.q_lora_rank is None else int(cfg.q_lora_rank)
         attn = LB.LatentAttention(
-            d, int(cfg.n_heads), int(cfg.q_lora_rank), int(cfg.kv_lora_rank),
+            d, int(cfg.n_heads), q_rank, int(cfg.kv_lora_rank),
             int(cfg.qk_nope_head_dim), int(cfg.qk_rope_head_dim),
-            int(cfg.v_head_dim), float(cfg.rms_norm_eps), dict(cfg.rope or {}))
+            int(cfg.v_head_dim), float(cfg.rms_norm_eps), dict(cfg.rope or {}),
+            rotary=not bool(cfg.mla_nope))
+        kda_layers = {int(i) for i in (cfg.kda_layers or ())}
+        kda = None
+        if kda_layers:
+            from theanompi_tpu.ops.kda import KdaMixer
+
+            kda = KdaMixer(d, int(cfg.n_heads), int(cfg.kda_head_dim),
+                           int(cfg.kda_conv), float(cfg.rms_norm_eps))
+        held = cfg.moe_experts_held
+        held = None if held is None else (0, int(held))
+
         def make_block(i):
             moe = None
             if i >= int(cfg.first_k_dense) and int(cfg.moe_experts):
@@ -313,11 +333,13 @@ class TransformerLM(TpuModel):
                     top_k=int(cfg.moe_top_k), ep_axis=None, compute_dtype=dt,
                     emit_aux=False, scoring="sigmoid",
                     route_scale=float(cfg.route_scale), gated=True,
-                    n_shared=int(cfg.n_shared_experts), param_dtype=pdt, w_init=normal_init(0.02))
+                    n_shared=int(cfg.n_shared_experts), experts_held=held,
+                    param_dtype=pdt, w_init=normal_init(0.02))
             return LB.LatentMoeBlock(
                 attn, ffn_hidden=int(cfg.ffn_hidden), moe=moe, n_streams=n,
                 hc_iters=int(cfg.hc_sinkhorn_iters), hc_eps=float(cfg.hc_eps),
-                hc_clamp=float(cfg.hc_clamp), param_dtype=pdt)
+                hc_clamp=float(cfg.hc_clamp), param_dtype=pdt,
+                kda=kda if i + 1 in kda_layers else None)
 
         net = L.Sequential([
             LB.StreamEmbedding(int(cfg.vocab_size), d, n, compute_dtype=dt,

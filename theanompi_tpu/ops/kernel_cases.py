@@ -454,8 +454,55 @@ def _mhc_cases(size):
     ]
 
 
+# ---------------------------------------------------------------------------
+# Kimi delta attention: the recurrent step and the chunked scan
+# ---------------------------------------------------------------------------
+
+def _kda_cases(size):
+    from theanompi_tpu.ops import kda
+
+    # real: the benchmark's stage (64 lanes of 32 heads; one lane's
+    # 2,048-token chunk)
+    n, h, t = (64, 32, 2048) if size == "real" else (3, 2, 128)
+    d = 128
+
+    def draw(key, *lead):
+        """Activated and normed as ``KdaMixer.convolve`` leaves them, the
+        decay over the configuration's range."""
+        ks = jax.random.split(key, 5)
+
+        def l2(z):
+            return z / jnp.sqrt(jnp.sum(z * z, axis=-1, keepdims=True) + 1e-6)
+
+        q, k, v = (jax.nn.silu(jax.random.normal(kk, (*lead, h, d)))
+                   for kk in ks[:3])
+        g = -jax.nn.softplus(
+            jax.random.uniform(ks[3], (*lead, h, d), minval=-7.0, maxval=-2.0))
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], (*lead, h)))
+        return l2(q) * d ** -0.5, l2(k), v, g, beta
+
+    def decode_args(key):
+        k0, k1 = jax.random.split(key)
+        return (0.1 * jax.random.normal(k0, (n, h, d, d)), *draw(k1, n))
+
+    def chunk_args(key):
+        k0, k1 = jax.random.split(key)
+        return (0.1 * jax.random.normal(k0, (1, h, d, d)), *draw(k1, 1, t))
+
+    return [
+        KernelCase("kda_decode", decode_args, kda.kda_decode,
+                   kda.kda_step_xla, atol=1e-5, rtol=1e-5,
+                   oracle_precision="highest"),
+        # the inverse and the triangles are shared (XLA, at `highest` by
+        # their own argument); the kernel's four products ask for it too
+        KernelCase("kda_chunk_prefill", chunk_args, kda.kda_chunk_prefill,
+                   kda.kda_chunk_xla, atol=1e-4, rtol=1e-4,
+                   oracle_precision="highest"),
+    ]
+
+
 _FAMILIES = (_flash_cases, _paged_cases, _wire_cases, _lrn_cases, _pool_cases,
-             _mla_cases, _gmm_cases, _mhc_cases)
+             _mla_cases, _gmm_cases, _mhc_cases, _kda_cases)
 
 
 def cases(size: str = "real"):

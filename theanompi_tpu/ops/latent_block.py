@@ -13,7 +13,15 @@ them to an ``attend`` function of the caller's —
   write the rows into the block pool and attend through a block table,
   expanded over a blocked context for a chunk, absorbed for one token.
 
-**Latent attention** (as DeepSeek-V3, ``q_lora_rank`` set).  A token's
+**The mixer** is one of two kinds, a block's ``kind``: latent attention
+(``'mla'``) or Kimi delta attention (``'kda'``, ``ops.kda``: no rows in
+a cache but a matrix a head and the convolution's last inputs; its
+``attend`` takes the convolution's inputs, the decay and the step and
+returns the heads' outputs, and owns that state).
+
+**Latent attention** (as DeepSeek-V3; with ``q_rank`` ``None`` the
+queries come from one matrix, with ``rotary=False`` no position enters
+and the ``rope`` dimensions are plain ones).  A token's
 cache row is ``[c_kv | k_rope]``: the normed compression of its keys and
 values and one rotary key shared by every head.  Two orders of the same
 products: *expanded*, ``[k_nope | v] = c_kv W_kvb`` per head and
@@ -24,7 +32,8 @@ result carried out (``· W_kvb[v]``) — ``LatentAttention.absorb`` /
 
 **The residual** is ``n_streams`` streams mixed by manifold-constrained
 hyper-connections (``ops.pallas_mhc``); the state is ``(tokens,
-n_streams · d)``.
+n_streams · d)``.  One stream is the plain residual ``x + F(norm(x))``
+and has no hyper-connection parameters.
 
 **The feed-forward** is a gated dense one or ``parallel.moe.MoeMlp``.
 """
@@ -55,8 +64,9 @@ class LatentAttention:
     """Sizes and products of the latent attention; no state."""
 
     def __init__(self, d_model, n_heads, q_rank, kv_rank, nope, rope, v_dim,
-                 norm_eps, rope_cfg):
+                 norm_eps, rope_cfg, rotary: bool = True):
         self.d_model, self.n_heads = d_model, n_heads
+        self.rotary = rotary
         self.q_rank, self.kv_rank = q_rank, kv_rank
         self.nope, self.rope, self.v_dim = nope, rope, v_dim
         self.norm_eps = norm_eps
@@ -79,11 +89,18 @@ class LatentAttention:
         d, h = self.d_model, self.n_heads
         ks = jax.random.split(key, 5)
         w = normal_init(0.02)
+        if self.q_rank is None:
+            queries = {"wq": w(ks[0], (d, h * (self.nope + self.rope)), d,
+                               dtype)}
+        else:
+            queries = {
+                "wq_a": w(ks[0], (d, self.q_rank), d, dtype),
+                "q_norm": jnp.ones((self.q_rank,), dtype),
+                "wq_b": w(ks[1], (self.q_rank, h * (self.nope + self.rope)),
+                          self.q_rank, dtype),
+            }
         return {
-            "wq_a": w(ks[0], (d, self.q_rank), d, dtype),
-            "q_norm": jnp.ones((self.q_rank,), dtype),
-            "wq_b": w(ks[1], (self.q_rank, h * (self.nope + self.rope)),
-                      self.q_rank, dtype),
+            **queries,
             "wkv_a": w(ks[2], (d, self.row_dim), d, dtype),
             "kv_norm": jnp.ones((self.kv_rank,), dtype),
             "wkv_b": w(ks[3], (self.kv_rank, h * (self.nope + self.v_dim)),
@@ -95,14 +112,20 @@ class LatentAttention:
         """``(q_nope (N, H, nope), q_rope (N, H, rope), row (N, kv_rank
         + rope))`` of the tokens ``hid`` (N, d) at ``positions`` (N,)."""
         n, h = hid.shape[0], self.n_heads
-        cq = rms_norm(_mm(hid, ap["wq_a"]), ap["q_norm"], self.norm_eps)
-        q = _mm(cq, ap["wq_b"]).reshape(n, h, self.nope + self.rope)
-        q_rope = rope_interleaved(q[..., self.nope:], positions,
-                                  self.inv_freq, self.rope_scale)
+        if self.q_rank is None:
+            q = _mm(hid, ap["wq"])
+        else:
+            cq = rms_norm(_mm(hid, ap["wq_a"]), ap["q_norm"], self.norm_eps)
+            q = _mm(cq, ap["wq_b"])
+        q = q.reshape(n, h, self.nope + self.rope)
         kv = _mm(hid, ap["wkv_a"])
         c_kv = rms_norm(kv[:, :self.kv_rank], ap["kv_norm"], self.norm_eps)
-        k_rope = rope_interleaved(kv[:, self.kv_rank:], positions,
-                                  self.inv_freq, self.rope_scale)
+        q_rope, k_rope = q[..., self.nope:], kv[:, self.kv_rank:]
+        if self.rotary:
+            q_rope = rope_interleaved(q_rope, positions, self.inv_freq,
+                                      self.rope_scale)
+            k_rope = rope_interleaved(k_rope, positions, self.inv_freq,
+                                      self.rope_scale)
         return q[..., :self.nope], q_rope, jnp.concatenate(
             [c_kv, k_rope], axis=-1)
 
@@ -162,8 +185,9 @@ def causal_attend(attn: LatentAttention, batch: int):
 
 
 class LatentMoeBlock(Layer):
-    """One block: hyper-connected latent attention, then a
-    hyper-connected feed-forward (dense gated, or ``moe``).
+    """One block: a hyper-connected mixer (latent attention, or ``kda``
+    where one is given), then a hyper-connected feed-forward (dense
+    gated, or ``moe``).
 
     ``forward``'s ``impl``: ``'pallas'`` runs the hyper-connection halves
     and the experts as the named kernels (the server's choice on one
@@ -172,8 +196,9 @@ class LatentMoeBlock(Layer):
     def __init__(self, attn: LatentAttention, *, ffn_hidden: Optional[int],
                  moe=None, n_streams: int = 4, hc_iters: int = 20,
                  hc_eps: float = 1e-6, hc_clamp: float = 30.0,
-                 param_dtype=jnp.float32):
-        self.attn = attn
+                 param_dtype=jnp.float32, kda=None):
+        self.attn, self.kda = attn, kda
+        self.kind = "mla" if kda is None else "kda"
         self.ffn_hidden, self.moe = ffn_hidden, moe
         self.n_streams = n_streams
         self.hc = dict(n=n_streams, eps=hc_eps, iters=hc_iters, clamp=hc_clamp)
@@ -197,12 +222,16 @@ class LatentMoeBlock(Layer):
         d, dt = self.attn.d_model, self.param_dtype
         ks = jax.random.split(key, 7)
         params = {
-            "attn": self.attn.init(ks[0], dt),
             "attn_norm": jnp.ones((d,), dt),
             "ffn_norm": jnp.ones((d,), dt),
-            "hc_attn": self._init_hc(ks[1], d),
-            "hc_ffn": self._init_hc(ks[2], d),
         }
+        if self.kda is None:
+            params["attn"] = self.attn.init(ks[0], dt)
+        else:
+            params["kda"] = self.kda.init(ks[0], dt)
+        if self.n_streams > 1:
+            params["hc_attn"] = self._init_hc(ks[1], d)
+            params["hc_ffn"] = self._init_hc(ks[2], d)
         if self.moe is not None:
             params["moe"], _, _ = self.moe.init(ks[3], (d,))
             if "route_bias" in params["moe"]:
@@ -219,7 +248,10 @@ class LatentMoeBlock(Layer):
 
     # ---- the forward pass, once ----------------------------------------
     def _mix(self, x, hp, norm_scale, f, impl):
-        """One hyper-connected sublayer around ``f`` (d → d)."""
+        """One hyper-connected sublayer around ``f`` (d → d); with one
+        stream the plain residual."""
+        if self.n_streams == 1:
+            return x + f(rms_norm(x, norm_scale, self.attn.norm_eps))
         kernel = impl == "pallas"
         with jax.named_scope("mhc"):
             phi_t, ab = pallas_mhc.pack_coefficients(
@@ -236,12 +268,18 @@ class LatentMoeBlock(Layer):
         """``(x' (N, n·d), counts)``: the state after the block, and the
         tokens each held expert received (``None`` for a dense block).
         ``attend(attention params, q_nope, q_rope, row) -> (N, H,
-        v_dim)`` supplies the keys and values (module docstring);
-        ``valid`` (N,) bool marks the rows that are tokens, not
+        v_dim)`` supplies the keys and values (module docstring); in a
+        ``kda`` block it is ``attend(mixer params, u, g, beta) -> (N, H,
+        K)``, the convolution and the recurrence over the caller's
+        state.  ``valid`` (N,) bool marks the rows that are tokens, not
         padding."""
         counts = []
 
         def attention(hid):
+            if self.kda is not None:
+                mp = params["kda"]
+                u, g, beta = self.kda.project(mp, hid)
+                return self.kda.out(mp, attend(mp, u, g, beta), hid)
             with jax.named_scope("mla_attn"):
                 ap = params["attn"]
                 q_nope, q_rope, row = self.attn.project(ap, hid, positions)
@@ -257,17 +295,20 @@ class LatentMoeBlock(Layer):
             counts.append(c)
             return y
 
-        x = self._mix(x, params["hc_attn"], params["attn_norm"], attention,
-                      impl)
-        x = self._mix(x, params["hc_ffn"], params["ffn_norm"], feed_forward,
-                      impl)
+        x = self._mix(x, params.get("hc_attn"), params["attn_norm"],
+                      attention, impl)
+        x = self._mix(x, params.get("hc_ffn"), params["ffn_norm"],
+                      feed_forward, impl)
         return x, (counts[0] if counts else None)
 
     def apply(self, params, state, x, train=False, rng=None):
         b, t, nd = x.shape
         positions = jnp.tile(jnp.arange(t), b)
-        y, _ = self.forward(params, x.reshape(b * t, nd), positions,
-                            causal_attend(self.attn, b))
+        from theanompi_tpu.ops.kda import causal_mix
+
+        attend = (causal_attend(self.attn, b) if self.kda is None
+                  else causal_mix(self.kda, b))
+        y, _ = self.forward(params, x.reshape(b * t, nd), positions, attend)
         return y.reshape(b, t, nd), state
 
 

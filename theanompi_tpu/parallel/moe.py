@@ -243,6 +243,9 @@ class MoeMlp(Layer):
         if experts_held is not None and self.ep_axis is not None:
             raise ValueError("experts_held and ep_axis both say which "
                              "experts a layer holds: give one")
+        # told so, the expert leaves hold `count` experts and not all
+        self._leaf_experts = n_experts if experts_held is None else int(
+            experts_held[1])
         first, count = experts_held or (0, n_experts // self.ep_size)
         if not (0 <= first and count >= 1 and first + count <= n_experts):
             raise ValueError(
@@ -274,10 +277,11 @@ class MoeMlp(Layer):
         params = {"wg": he_normal(kg, (d, E), d, dt)}
         if self.scoring == "sigmoid":
             params["route_bias"] = jnp.zeros((E,), dt)
+        held = self._leaf_experts
         if self.gated:
-            params["w_gate"] = he_normal(ki, (E, d, h), d, dt)
-            params["w_up"] = he_normal(ku, (E, d, h), d, dt)
-            params["w_down"] = he_normal(ko, (E, h, d), h, dt)
+            params["w_gate"] = he_normal(ki, (held, d, h), d, dt)
+            params["w_up"] = he_normal(ku, (held, d, h), d, dt)
+            params["w_down"] = he_normal(ko, (held, h, d), h, dt)
             if self.n_shared:
                 hs = h * self.n_shared
                 k1, k2, k3 = jax.random.split(ks, 3)
@@ -288,10 +292,10 @@ class MoeMlp(Layer):
                 }
         else:
             params.update(
-                w_in=he_normal(ki, (E, d, h), d, dt),
-                b_in=jnp.zeros((E, h), dt),
-                w_out=he_normal(ko, (E, h, d), h, dt),
-                b_out=jnp.zeros((E, d), dt),
+                w_in=he_normal(ki, (held, d, h), d, dt),
+                b_in=jnp.zeros((held, h), dt),
+                w_out=he_normal(ko, (held, h, d), h, dt),
+                b_out=jnp.zeros((held, d), dt),
             )
         # aux_loss rides the STATE tree: apply emits the differentiable
         # Switch load-balance scalar there, and the owning model adds

@@ -51,6 +51,7 @@ class DensePrograms:
     state's layout and the bodies of its two jitted programs."""
 
     latent = False  # the scheduler reports no latent_rows_* stats
+    recurrent = False  # every layer's past is rows in the pool
 
     def __init__(self, engine):
         cfg = engine.model.config
@@ -304,7 +305,7 @@ class DensePrograms:
         return out, logits
 
     def chunk_fn(self, params, state, tokens, tables, p0, true_len, active,
-                 all_logits):
+                 all_logits, lanes=None):
         e = self.engine
         p_, c_ = tokens.shape
         bs = e.block_size

@@ -40,9 +40,25 @@ equals whole-prompt prefill):
 The block's forward pass is ``LatentMoeBlock.forward``, the one the
 model's own ``apply`` runs; these programs only supply its ``attend``.
 
-Each program also returns two counters of its expert layers, summed or
-maximised over them: ``experts_hit`` (distinct experts that received a
-token) and ``expert_load_max`` (the most tokens one expert received).
+**Recurrent layers.**  A block whose mixer is Kimi delta attention
+(``ops.kda``) holds no rows: the pool has an array for each *latent*
+layer only, and beside it the state holds, for each recurrent layer,
+``kda`` (``n_slots``, heads, K, K) float32 — a lane's matrices — and
+``conv`` (``n_slots``, 3, 3 · heads · K) — the convolution's last
+inputs.  They are addressed by lane and not by block table: a prefill
+row says which lane it is (``lanes``), a lane's state is cleared where a
+request's first chunk enters (``p0 == 0``), carried from chunk to chunk
+and from the last chunk into decode; padding positions (``g = 0, β =
+0``), padding rows (written nowhere) and inactive lanes leave it as it
+was.  A prefix cannot be rebuilt from blocks alone for such a model:
+the engine serves it with prefix reuse off and refuses to verify drafts
+(``recurrent``).
+
+Each program also returns three counters of its expert layers, summed
+or maximised over them: ``experts_hit`` (distinct held experts that
+received a token), ``expert_load_max`` (the most tokens one expert
+received) and ``pairs_routed`` (the token-expert pairs computed here:
+the picks of useful tokens that fell on a held expert).
 """
 
 from __future__ import annotations
@@ -54,7 +70,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from theanompi_tpu.ops import platform
+from theanompi_tpu.ops import kda, platform
 from theanompi_tpu.ops.pallas_flash import _NEG_INF
 from theanompi_tpu.serving.engine import TRASH_BLOCK
 
@@ -119,6 +135,16 @@ class LatentPrograms:
         self.embed, self.blocks = layers[0], layers[1:-2]
         self.norm, self.head = layers[-2], layers[-1]
         self.attn = self.blocks[0].attn
+        # a block's place among the blocks of its kind: the index of its
+        # pool array, or of its recurrent state
+        seen = {"mla": 0, "kda": 0}
+        self.place = []
+        for b in self.blocks:
+            self.place.append(seen[b.kind])
+            seen[b.kind] += 1
+        self.n_latent, self.n_recurrent = seen["mla"], seen["kda"]
+        self.kda = next((b.kda for b in self.blocks if b.kda is not None),
+                        None)
         bs = engine.block_size
         # the span of positions the prefill attention folds at a time:
         # 512 (its float32 scores, heads x chunk x span, are what a span
@@ -134,12 +160,19 @@ class LatentPrograms:
             or jax.tree.leaves(engine.model.params)[0].dtype)
 
     @property
+    def recurrent(self) -> bool:
+        """Some layer's past is per-lane state, not rows: no prefix
+        reuse, no rolled-back drafts."""
+        return self.n_recurrent > 0
+
+    @property
     def impl(self) -> str:
         """``'pallas'``: the named kernels (latent decode, grouped
-        experts, hyper-connections); ``'xla'``: their plain forms.  The
-        engine's one selection rule, except that ``paged_attn='auto'``
-        takes the kernels on a TPU only: interpreted on the CPU they are
-        for the tests that ask for them (``paged_attn='pallas'``)."""
+        experts, hyper-connections, the recurrent step and scan);
+        ``'xla'``: their plain forms.  The engine's one selection rule,
+        except that ``paged_attn='auto'`` takes the kernels on a TPU
+        only: interpreted on the CPU they are for the tests that ask for
+        them (``paged_attn='pallas'``)."""
         e = self.engine
         if e.paged_attn == "auto" and not platform.on_tpu():
             return "xla"
@@ -158,46 +191,81 @@ class LatentPrograms:
         e = self.engine
         sh = NamedSharding(e.mesh, P())
         shape = (e.n_blocks * e.block_size, self.row_width)
-        return {"kv": [jnp.zeros(shape, self.dtype, device=sh)
-                       for _ in self.blocks]}
+        state = {"kv": [jnp.zeros(shape, self.dtype, device=sh)
+                        for _ in range(self.n_latent)]}
+        if self.recurrent:
+            m = self.kda
+            state["kda"] = [
+                jnp.zeros((e.n_slots, m.n_heads, m.head_dim, m.head_dim),
+                          jnp.float32, device=sh)
+                for _ in range(self.n_recurrent)]
+            state["conv"] = [
+                jnp.zeros((e.n_slots, m.conv - 1, 3 * m.width), self.dtype,
+                          device=sh)
+                for _ in range(self.n_recurrent)]
+        return state
 
     def block_bytes(self) -> int:
         e = self.engine
-        return (len(self.blocks) * e.block_size * self.row_width
+        return (self.n_latent * e.block_size * self.row_width
                 * self.dtype.itemsize)
+
+    def recurrent_state_bytes(self) -> int:
+        """Device bytes of the per-lane state over all recurrent layers
+        and lanes (0 for a model without such layers)."""
+        if not self.recurrent:
+            return 0
+        m, e = self.kda, self.engine
+        lane = (m.n_heads * m.head_dim * m.head_dim * 4
+                + (m.conv - 1) * 3 * m.width * self.dtype.itemsize)
+        return self.n_recurrent * e.n_slots * lane
 
     # ---- the two programs --------------------------------------------------
     def _run(self, params, state, tokens, positions, valid, wr, attention,
-             pick_rows):
+             recur, pick_rows):
         """Embed, every block with ``attention(ap, q…, pool) -> o`` over
-        the pool it has just written, then norm and head over the rows
-        ``pick_rows`` chooses."""
+        the pool it has just written or ``recur(mp, u, g, beta, s, conv)
+        -> (o, s, conv)`` over the lanes' state, then norm and head over
+        the rows ``pick_rows`` chooses."""
         x, _ = self.embed.apply(params[0], {}, tokens)
-        kv, hit, load = list(state["kv"]), 0, 0
+        kv = list(state["kv"])
+        mats, conv = list(state.get("kda", ())), list(state.get("conv", ()))
+        hit, load, pairs = 0, 0, 0
         for i, block in enumerate(self.blocks):
-            def attend(ap, q_nope, q_rope, row, i=i):
+            j = self.place[i]
+
+            def attend(ap, q_nope, q_rope, row, j=j):
                 with jax.named_scope("pool_update"):
-                    row = jnp.pad(row.astype(kv[i].dtype), (
+                    row = jnp.pad(row.astype(kv[j].dtype), (
                         (0, 0), (0, self.row_width - row.shape[1])))
-                    kv[i] = kv[i].at[wr].set(row)
-                return attention(ap, q_nope, q_rope, kv[i])
+                    kv[j] = kv[j].at[wr].set(row)
+                return attention(ap, q_nope, q_rope, kv[j])
+
+            def mix(mp, u, g, beta, j=j):
+                o, mats[j], conv[j] = recur(mp, u, g, beta, mats[j], conv[j])
+                return o
 
             with jax.named_scope(f"layer{i}"):
                 x, counts = block.forward(
-                    params[1 + i], x, positions, attend, valid=valid,
+                    params[1 + i], x, positions,
+                    attend if block.kda is None else mix, valid=valid,
                     impl=self.impl)
             if counts is not None:
                 hit = hit + jnp.sum(counts > 0)
                 load = jnp.maximum(load, jnp.max(counts))
+                pairs = pairs + jnp.sum(counts)
         with jax.named_scope("head"):
             x, _ = self.norm.apply(params[-2], {}, pick_rows(x))
             logits, _ = self.head.apply(params[-1], {}, x)
-        counters = jnp.stack([jnp.asarray(hit, jnp.int32),
-                              jnp.asarray(load, jnp.int32)])
-        return {"kv": kv}, logits, counters
+        counters = jnp.stack([jnp.asarray(c, jnp.int32)
+                              for c in (hit, load, pairs)])
+        new = {"kv": kv}
+        if self.recurrent:
+            new.update(kda=mats, conv=conv)
+        return new, logits, counters
 
     def chunk_fn(self, params, state, tokens, tables, p0, true_len, active,
-                 all_logits):
+                 all_logits, lanes=None):
         e = self.engine
         p_, c_ = tokens.shape
         bs = e.block_size
@@ -212,6 +280,34 @@ class LatentPrograms:
                 self.attn, ap, q_nope, q_rope, pool, tables, positions,
                 block_size=bs, ctx_block=self.ctx_block)
 
+        def recur(mp, u, g, beta, mats, conv):
+            """The rows' chunks through the recurrence, from their lanes'
+            state (an empty one where a request's first chunk enters) and
+            back into it."""
+            m = self.kda
+            h, k = m.n_heads, m.head_dim
+            src = jnp.minimum(lanes, e.n_slots - 1)
+            fresh = active & (p0 == 0)
+            s0 = jnp.where(fresh[:, None, None, None], 0.0, mats[src])
+            past = jnp.where(fresh[:, None, None], 0, conv[src])
+            u = u.reshape(p_, c_, -1)
+            g = jnp.where(valid[..., None, None], g.reshape(p_, c_, h, k), 0.0)
+            beta = jnp.where(valid[..., None], beta.reshape(p_, c_, h), 0.0)
+            q, kk, v = m.convolve(mp, past, u)
+            scan = (kda.kda_chunk_prefill if self.impl == "pallas"
+                    else kda.kda_chunk_xla)
+            o, s1 = scan(s0, q, kk, v, g, beta)
+            with jax.named_scope("state_update"):
+                # the last inputs behind the row's real tokens; a padding
+                # row (lane n_slots) is written nowhere
+                seq = jnp.concatenate([past, u], axis=1)
+                last = jax.vmap(lambda a, n: lax.dynamic_slice_in_dim(
+                    a, n, m.conv - 1, axis=0))(seq, true_len)
+                dst = jnp.where(active, lanes, e.n_slots)
+                mats = mats.at[dst].set(s1, mode="drop")
+                conv = conv.at[dst].set(last, mode="drop")
+            return o.reshape(p_ * c_, h, k), mats, conv
+
         def pick_rows(x):
             x = x.reshape(p_, c_, -1)
             if all_logits:
@@ -221,7 +317,7 @@ class LatentPrograms:
 
         return self._run(params, state, tokens.reshape(-1),
                          positions.reshape(-1), valid.reshape(-1),
-                         wr.reshape(-1), attention, pick_rows)
+                         wr.reshape(-1), attention, recur, pick_rows)
 
     def decode_fn(self, params, state, tokens, tables, lengths, active):
         from theanompi_tpu.ops import pallas_paged
@@ -240,5 +336,21 @@ class LatentPrograms:
                            lengths, block_size=bs, scale=self.attn.scale)
             return self.attn.unabsorb(ap, o_lat, q_nope.dtype)
 
+        def recur(mp, u, g, beta, mats, conv):
+            """One token a lane; an inactive lane (``g = 0, β = 0``, its
+            inputs not shifted in) keeps its state."""
+            g = jnp.where(active[:, None, None], g, 0.0)
+            beta = jnp.where(active[:, None], beta, 0.0)
+            q, kk, v = (a[:, 0] for a in
+                        self.kda.convolve(mp, conv, u[:, None, :]))
+            step = (kda.kda_decode if self.impl == "pallas"
+                    else kda.kda_step_xla)
+            mats, o = step(mats, q, kk, v, g, beta)
+            with jax.named_scope("state_update"):
+                shifted = jnp.concatenate(
+                    [conv[:, 1:], u[:, None, :].astype(conv.dtype)], axis=1)
+                conv = jnp.where(active[:, None, None], shifted, conv)
+            return o, mats, conv
+
         return self._run(params, state, tokens, lengths, active, wr,
-                         attention, lambda x: x)
+                         attention, recur, lambda x: x)

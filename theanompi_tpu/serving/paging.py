@@ -99,8 +99,9 @@ from theanompi_tpu.serving.latent import LatentPrograms
 
 # a model's ``block`` -> the programs that serve it: the one place in
 # ``serving/`` that looks at a block's name.  A family is a class with
-# ``serving_params``, ``init_state``, ``block_bytes``, ``chunk_fn`` and
-# ``decode_fn`` (docs/serving.md, "Adding a block family").
+# ``serving_params``, ``init_state``, ``block_bytes``, ``chunk_fn``,
+# ``decode_fn`` and the flags ``latent`` and ``recurrent``
+# (docs/serving.md, "Adding a block family").
 PROGRAMS = {"dense": DensePrograms, "latent_moe": LatentPrograms}
 
 
@@ -495,6 +496,11 @@ class PagedServingEngine:
         # the family's pool layout and program bodies; its constructor
         # refuses what the family cannot serve
         self.programs = PROGRAMS[block](self)
+        if self.programs.recurrent:
+            # the blocks of a prefix stand for the layers that keep rows
+            # and not for the lanes' recurrent state (module docstring of
+            # serving/latent.py): no request is handed another's blocks
+            self.prefix_cache_enabled = False
         # `last_counters` is what the newest program call returned
         # beside its logits (None: a family without counters),
         # `last_span` the newest `prefill_chunk_dispatch` span: the
@@ -593,7 +599,7 @@ class PagedServingEngine:
     # ------------------------------------------------------------------
     def _paged_chunk_fn(
         self, params, state, tokens, tables, p0, true_len, active,
-        all_logits,
+        lanes=None, *, all_logits,
     ):
         """One batched, chunked multi-token pass: ``tokens`` (P, C)
         int32 — chunk c of each lane, entering logical positions
@@ -604,13 +610,15 @@ class PagedServingEngine:
         ``all_logits=False``) or at EVERY chunk position (the
         speculative-decoding verify dispatch, ``all_logits=True`` —
         (P, C, V), so a draft's k proposals and the bonus token are
-        scored in this ONE call)."""
+        scored in this ONE call).  ``lanes`` (P,) int32, for a family
+        with per-lane state alone: the lane each row belongs to."""
         if all_logits:  # runs at trace time only
             self._n_verify_traces += 1
         else:
             self._n_prefill_traces += 1
         return self.programs.chunk_fn(params, state, tokens, tables, p0,
-                                      true_len, active, all_logits)
+                                      true_len, active, all_logits,
+                                      lanes=lanes)
 
     def _paged_decode_fn(
         self, params, state, tokens, tables, lengths, active
@@ -633,8 +641,10 @@ class PagedServingEngine:
 
         ``rows`` is a list of up to ``prefill_rows`` dicts with keys
         ``tokens`` (this lane's chunk, 1..prefill_chunk ints), ``p0``
-        (its absolute start position) and ``table`` (the lane's block
-        ids).  Returns ``(state, logits)`` — logits row i belongs to
+        (its absolute start position), ``table`` (the lane's block
+        ids) and, for a model with per-lane recurrent state, ``lane``
+        (whose state the chunk continues; cleared at ``p0 == 0``).
+        Returns ``(state, logits)`` — logits row i belongs to
         rows[i] (meaningful only for the lane's FINAL chunk)."""
         if not rows or len(rows) > self.prefill_rows:
             raise ValueError(
@@ -653,7 +663,16 @@ class PagedServingEngine:
             p0 = np.zeros((p_,), np.int32)
             true_len = np.zeros((p_,), np.int32)
             active = np.zeros((p_,), bool)
+            recurrent = self.programs.recurrent
+            # a padding row's lane is none (n_slots): written nowhere
+            lanes = np.full((p_,), self.n_slots, np.int32)
             for i, r in enumerate(rows):
+                if recurrent:
+                    if not 0 <= int(r["lane"]) < self.n_slots:
+                        raise ValueError(
+                            f"row {i}: lane {r['lane']} outside "
+                            f"0..{self.n_slots - 1}")
+                    lanes[i] = int(r["lane"])
                 n = len(r["tokens"])
                 tokens[i, :n] = r["tokens"]
                 tables[i, :len(r["table"])] = r["table"]
@@ -669,6 +688,7 @@ class PagedServingEngine:
                 host_input(tokens), host_input(tables),
                 host_input(p0), host_input(true_len),
                 host_input(active),
+                *((host_input(lanes),) if recurrent else ()),
             )
             self.last_span = span
         return state, logits
@@ -686,6 +706,10 @@ class PagedServingEngine:
         sampled acceptance draws with the request's own per-index keys.
         C is pinned by the caller (spec_k + 1) — ONE compiled program
         across every acceptance/rollback outcome."""
+        if self.programs.recurrent:
+            raise ValueError(
+                "a model with recurrent layers cannot verify drafts: a "
+                "rejected token cannot be rolled back out of a lane's state")
         smetrics.SPEC_VERIFY_DISPATCHES.inc()
         with obs.span("spec_verify_dispatch", rows=int(np.sum(active)),
                       width=int(np.asarray(tokens).shape[1])):

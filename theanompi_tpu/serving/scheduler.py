@@ -218,6 +218,19 @@ class ContinuousBatchingScheduler:
                 self.prefix = PrefixCache(self.pool)
         else:
             self.prefix = None
+        if engine.programs.recurrent:
+            if int(spec_k):
+                raise ValueError(
+                    "spec_k>0 needs a model whose past is rows alone: a "
+                    "rejected draft cannot be rolled back out of a "
+                    "recurrent state")
+            # per-lane state beside the pool (serving/latent.py): its
+            # size, the first chunks that cleared a lane, and why no
+            # request is handed a cached prefix
+            self.stats["recurrent_state_bytes"] = (
+                engine.programs.recurrent_state_bytes())
+            self.stats["recurrent_lanes_reset"] = 0
+            self.stats["prefix_reuse"] = "off: recurrent state"
         self.state = engine.init_state()
         if engine.programs.latent:
             # the latent pool's size and fill, in rows (= tokens)
@@ -419,15 +432,16 @@ class ContinuousBatchingScheduler:
 
     def _fetch_counters(self) -> None:
         """Complete the spans of the calls noted so far with
-        ``experts_hit``, ``expert_load_max`` and ``tokens_routed``.
+        ``experts_hit``, ``expert_load_max``, ``pairs_routed`` and
+        ``tokens_routed``.
         Called behind a pick, so every program noted has run and the
         fetch waits for nothing; a boundary span's arguments are the
         recorded event's own, so a span that has closed still takes
         them."""
         for span, tokens_routed, counters in self._counters:
-            hit, load = np.asarray(counters).tolist()
+            hit, load, pairs = np.asarray(counters).tolist()
             span.set(experts_hit=hit, expert_load_max=load,
-                     tokens_routed=tokens_routed)
+                     pairs_routed=pairs, tokens_routed=tokens_routed)
         self._counters.clear()
 
     def _emit(self, i: int, token: int) -> bool:
@@ -589,8 +603,11 @@ class ContinuousBatchingScheduler:
             self.state, logits = self.engine.prefill_chunks(
                 self.params, self.state,
                 [{"tokens": chunks[i], "p0": self.slots[i].n_fed,
-                  "table": self.slots[i].blocks} for i in lanes],
+                  "table": self.slots[i].blocks, "lane": i} for i in lanes],
             )
+            if "recurrent_lanes_reset" in self.stats:
+                self.stats["recurrent_lanes_reset"] += sum(
+                    self.slots[i].n_fed == 0 for i in lanes)
             self._note_counters(self.engine.last_span,
                                 sum(len(chunks[i]) for i in lanes))
             reqs = []  # per lane: its request if the prompt completes
