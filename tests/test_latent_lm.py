@@ -229,6 +229,10 @@ def test_served_tokens_are_the_references_and_the_spans_carry_the_counters(
         a = s["args"]
         assert 1 <= a["expert_load_max"] <= a["tokens_routed"]
         assert a["expert_load_max"] <= a["experts_hit"] <= 2 * 8
+    # the decode kernel's grid: 48 positions in blocks of 4 are one
+    # group of 12 a lane, so the lists are as long as the table is wide
+    decode = [s["args"] for s in spans if s["name"] == "decode_step"]
+    assert all(a["attn_steps"] == a["attn_steps_table"] == 3 for a in decode)
     prefill = [s["args"] for s in spans if s["name"] == "prefill_chunk_dispatch"]
     assert sum(a["tokens_routed"] for a in prefill) == 19 + 5 + 11
     assert all(a["tokens_routed"] == a["useful_tokens"] for a in prefill)
@@ -255,6 +259,31 @@ def test_absorbed_decode_equals_expanded_attention():
     assert float(jnp.abs(expanded).max()) > 0.05
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded),
                                atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("kind", ["edges", "full", "one_long"])
+def test_latent_decode_kernel_walks_the_lanes_resident_groups(kind):
+    """``mla_paged_decode``'s grid is the list of resident (lane, block
+    group) pairs; at its edges (an idle lane, lanes a row short of, at
+    and past a group's boundary, the table's last column, every lane
+    full, one long lane among short ones) it equals the XLA form.  The
+    table's 7 columns make a ragged last group of 3."""
+    from theanompi_tpu.ops.kernel_cases import ragged_lengths
+
+    s, h, c, r, bs, nt, nb, g = 6, 4, 16, 8, 4, 7, 20, 3
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q_lat = jax.random.normal(keys[0], (s, h, c))
+    q_rope = jax.random.normal(keys[1], (s, h, r))
+    pool = jax.random.normal(keys[2], (nb * bs, 128))
+    tables = jax.random.randint(keys[3], (s, nt), 1, nb, jnp.int32)
+    lengths = jnp.asarray(ragged_lengths(kind, s, g * bs, nt * bs))
+    kw = dict(block_size=bs, scale=(c + r) ** -0.5)
+    got = pallas_paged.mla_paged_decode(
+        q_lat, q_rope, pool, tables, lengths, group=g, **kw)
+    want = pallas_paged.mla_decode_xla(
+        q_lat, q_rope, pool, tables, lengths, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
 
 
 # ---- (f) the write-back matrix is doubly stochastic --------------------------

@@ -368,7 +368,7 @@ def test_pallas_paged_kernel_matches_xla_fp32_and_int8(h, hd):
     """The kernel-level allclose pin, exercised in interpret mode:
     fused in-kernel gather over the flat ``(rows, width)`` pool ==
     materialized XLA gather, fp32 and int8 pools, including short
-    lengths (masked-block elision)."""
+    lengths (a lane's grid steps stop at its length)."""
     from theanompi_tpu.ops.pallas_paged import paged_decode_attention
     from theanompi_tpu.parallel.quantize import (
         dequantize_blocks, quantize_blocks,
@@ -409,6 +409,110 @@ def test_pallas_paged_kernel_matches_xla_fp32_and_int8(h, hd):
         paged_decode_attention(q, kq, vq, tables, lengths, block_size=bs)
     with pytest.raises(ValueError, match="rows, width"):  # the old layout
         paged_decode_attention(q, kp, vp, tables, lengths, block_size=bs)
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+@pytest.mark.parametrize("kind", ["edges", "full", "one_long"])
+def test_pallas_paged_kernel_walks_the_lanes_resident_blocks(kind, kv_dtype):
+    """The grid is the list of resident (lane, block) pairs: an idle
+    lane, lanes a row short of, at and past a block's boundary, a lane
+    in the table's last column, every lane full, one long lane among
+    short ones: each equals the XLA gather."""
+    from theanompi_tpu.ops.kernel_cases import ragged_lengths
+    from theanompi_tpu.ops.pallas_paged import paged_decode_attention
+    from theanompi_tpu.parallel.quantize import (
+        dequantize_blocks, quantize_blocks,
+    )
+
+    rng = np.random.RandomState(1)
+    s, h, hd, bs, nb, nt = 6, 5, 8, 4, 30, 6
+    q = rng.randn(s, h, hd).astype(np.float32)
+    kp = rng.randn(nb * bs, h, hd).astype(np.float32)
+    vp = rng.randn(nb * bs, h, hd).astype(np.float32)
+    tables = rng.randint(1, nb, (s, nt)).astype(np.int32)
+    lengths = ragged_lengths(kind, s, bs, nt * bs)
+    kw = {}
+    if kv_dtype == "int8":
+        (kq, ks), (vq, vs) = (quantize_blocks(jnp.asarray(a))
+                              for a in (kp, vp))
+        kw = dict(k_scale=np.asarray(ks), v_scale=np.asarray(vs))
+        kp = np.asarray(dequantize_blocks(kq, ks))
+        vp = np.asarray(dequantize_blocks(vq, vs))
+        pools = _flat_pool(kq, 128), _flat_pool(vq, 128)
+    else:
+        pools = _flat_pool(kp, 128), _flat_pool(vp, 128)
+    got = np.asarray(paged_decode_attention(
+        q, *pools, tables, lengths, block_size=bs, **kw))
+    want = _xla_paged_reference(q, kp, vp, tables, lengths, bs, hd ** -0.5)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kind,s,span,per_lane", [
+    ("edges", 5, 4, 6), ("edges", 7, 512, 35), ("full", 3, 32, 32),
+    ("one_long", 64, 512, 35), ("past_the_table", 4, 8, 3),
+])
+def test_lane_steps_lists_each_lanes_resident_groups(kind, s, span, per_lane):
+    """``total`` is the sum of ``length // span + 1``, ``lane`` does not
+    decrease and each lane's ``j`` runs ``0 .. n - 1``; a length past
+    the table is held to the table's width."""
+    from theanompi_tpu.ops.kernel_cases import ragged_lengths
+    from theanompi_tpu.ops.pallas_paged import lane_steps
+
+    if kind == "past_the_table":
+        lengths = np.array([0, 10 ** 6, 23, 24], np.int32)
+    else:
+        lengths = ragged_lengths(kind, s, span, per_lane * span)
+    lane, j, total = jax.jit(
+        lambda ln: lane_steps(ln, span, per_lane))(lengths)
+    assert lane.shape == j.shape == (s * per_lane,)
+    assert lane.dtype == j.dtype == jnp.int32
+    n = np.minimum(lengths // span + 1, per_lane)
+    assert int(total) == n.sum()
+    lane, j = np.asarray(lane)[:n.sum()], np.asarray(j)[:n.sum()]
+    np.testing.assert_array_equal(lane, np.repeat(np.arange(s), n))
+    np.testing.assert_array_equal(
+        j, np.concatenate([np.arange(k) for k in n]))
+
+
+def test_paged_kernels_refuse_a_list_of_another_shape():
+    from theanompi_tpu.ops.pallas_paged import (
+        lane_steps, mla_paged_decode, paged_decode_attention,
+    )
+
+    lengths = np.array([3, 9], np.int32)
+    tables = np.ones((2, 4), np.int32)
+    steps = lane_steps(lengths, 4, 3)  # a table of three columns
+    with pytest.raises(ValueError, match=r"lane_steps\(lengths, 4, 4\)"):
+        paged_decode_attention(
+            np.zeros((2, 2, 8), np.float32), np.zeros((20, 128), np.float32),
+            np.zeros((20, 128), np.float32), tables, lengths, block_size=4,
+            steps=steps)
+    with pytest.raises(ValueError, match=r"lane_steps\(lengths, 8, 2\)"):
+        mla_paged_decode(
+            np.zeros((2, 2, 16), np.float32), np.zeros((2, 2, 8), np.float32),
+            np.zeros((20, 128), np.float32), tables, lengths, block_size=4,
+            scale=1.0, group=2, steps=steps)
+
+
+def test_decode_step_span_says_how_far_the_kernels_grid_engages(model):
+    """``attn_steps`` is what the lanes hold (every lane one step at
+    least), ``attn_steps_table`` what the table's width would walk."""
+    from theanompi_tpu import observability as obs
+
+    eng = PagedServingEngine(model, n_slots=3, max_len=64, block_size=8,
+                             buckets=(8, 16, 64), paged_attn="pallas")
+    sched = ContinuousBatchingScheduler(eng)
+    t0 = sched.clock()
+    sched.submit(Request(id="a", prompt=list(range(1, 20)), max_new_tokens=3))
+    sched.submit(Request(id="b", prompt=[4, 5, 6], max_new_tokens=3))
+    sched.run()
+    spans = [s["args"] for s in obs.get_tracer().boundary_spans(t0)
+             if s["name"] == "decode_step"]
+    assert spans and all(a["attn_steps_table"] == 3 * 8 for a in spans)
+    # the first decode tick: lengths 19 and 3 and an idle lane, in
+    # blocks of 8: 3 + 1 + 1 steps
+    assert spans[0]["attn_steps"] == 5
+    assert all(3 <= a["attn_steps"] <= 6 for a in spans)
 
 
 @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])

@@ -34,6 +34,7 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from theanompi_tpu.ops import platform
 
@@ -131,6 +132,27 @@ def _paged_oracle(q, kp, vp, tables, lengths, *, bs, ks=None, vs=None):
     return jnp.einsum("sht,sthd->shd", prob, vc)
 
 
+def ragged_lengths(kind: str, s: int, span: int, positions: int):
+    """Lengths (S,) that put the paged decode kernels' grid at its
+    edges (``pallas_paged.lane_steps``; ``span`` rows a grid step,
+    ``positions`` the table's): ``edges`` an idle lane, lanes a row
+    short of, at and past a step's boundary and one in the table's last
+    column; ``full`` every lane full; ``one_long`` a full lane among
+    short ones."""
+    last = positions - 1
+    if kind == "edges":
+        edges = [0, span - 1, span, span + 1, last]
+        out = (edges * -(-s // len(edges)))[:s]
+    elif kind == "full":
+        out = [last] * s
+    elif kind == "one_long":
+        out = [span // 2] * s
+        out[s // 2] = last
+    else:
+        raise ValueError(f"unknown kind of lengths {kind!r}")
+    return np.asarray(out, np.int32)
+
+
 def _paged_cases(size):
     from theanompi_tpu.ops.pallas_paged import paged_decode_attention
     from theanompi_tpu.parallel.quantize import quantize_blocks
@@ -152,7 +174,8 @@ def _paged_cases(size):
             kp = jax.random.normal(kk, (nb * bs, h, hd), jnp.float32)
             vp = jax.random.normal(kv, (nb * bs, h, hd), jnp.float32)
             # block 0 is the trash block; lane 0 sits at length 0 and the
-            # last lane fills its table (masked-block elision both ways)
+            # last lane fills its table (a lane's shortest and longest
+            # run of grid steps)
             tables = jax.random.randint(kt, (s, nt), 1, nb, jnp.int32)
             lengths = jax.random.randint(kl, (s,), 0, nt * bs, jnp.int32)
             lengths = lengths.at[0].set(0).at[-1].set(nt * bs - 1)
@@ -344,41 +367,49 @@ def _pool_cases(size):
 def _mla_cases(size):
     from theanompi_tpu.ops.pallas_paged import mla_decode_xla, mla_paged_decode
 
-    # real: the serving widths of the latent model (32 lanes, 32 heads,
-    # rows of 512 + 64 stored 640 wide, blocks of 32) over 2,048 positions
-    s, h, c, r, w, bs, nt, nb = (
-        (32, 32, 512, 64, 640, 32, 64, 2049) if size == "real"
-        else (3, 4, 16, 8, 128, 4, 7, 12)
+    # real: the serving widths of the latent models (32 heads, rows of
+    # 512 + 64 stored 640 wide, blocks of 32, 16 a grid step): 32 lanes
+    # over 2,048 positions, and the Kimi cell's 64 lanes under a
+    # 560-column table, lengths of 100-9,000 (the grid's dynamic bound at
+    # that width: 35 steps a lane, three or four of them resident)
+    h, c, r, w, bs, g = (
+        (32, 512, 64, 640, 32, 16) if size == "real" else (4, 16, 8, 128, 4, 3)
     )
+    kw = dict(block_size=bs, scale=(c + r) ** -0.5)
 
-    def make(dtype):
+    def make(dtype, s, nt, nb, lo, hi):
         def make_args(key):
             kq, kr, kp, kt, kl = jax.random.split(key, 5)
             q_lat = jax.random.normal(kq, (s, h, c), dtype)
             q_rope = jax.random.normal(kr, (s, h, r), dtype)
             pool = jax.random.normal(kp, (nb * bs, w), dtype)
             tables = jax.random.randint(kt, (s, nt), 1, nb, jnp.int32)
-            lengths = jax.random.randint(kl, (s,), 0, nt * bs, jnp.int32)
+            lengths = jax.random.randint(kl, (s,), lo, hi, jnp.int32)
             lengths = lengths.at[0].set(0).at[-1].set(nt * bs - 1)
             return q_lat, q_rope, pool, tables, lengths
         return make_args
 
-    kw = dict(block_size=bs, scale=(c + r) ** -0.5)
-
     def kernel(*args):  # several groups a lane, and a ragged last one
-        return mla_paged_decode(*args, group=16 if size == "real" else 3, **kw)
+        return mla_paged_decode(*args, group=g, **kw)
 
     def oracle(q_lat, q_rope, pool, tables, lengths):
         f32 = jnp.float32
         return mla_decode_xla(q_lat.astype(f32), q_rope.astype(f32),
                               pool.astype(f32), tables, lengths, **kw)
 
+    s, nt, nb = (32, 64, 2049) if size == "real" else (3, 7, 12)
+    wide = ((64, 560, 8193, 100, 9000) if size == "real"
+            else (5, 16, 12, 1, 9))
     return [
-        KernelCase("mla_decode_f32", make(jnp.float32), kernel, oracle,
-                   atol=1e-4, rtol=1e-4, kernel_precision="highest",
-                   oracle_precision="highest"),
-        KernelCase("mla_decode_bf16", make(jnp.bfloat16), kernel, oracle,
+        KernelCase("mla_decode_f32", make(jnp.float32, s, nt, nb, 0, nt * bs),
+                   kernel, oracle, atol=1e-4, rtol=1e-4,
+                   kernel_precision="highest", oracle_precision="highest"),
+        KernelCase("mla_decode_bf16",
+                   make(jnp.bfloat16, s, nt, nb, 0, nt * bs), kernel, oracle,
                    atol=3e-2, rtol=3e-2, oracle_precision="highest"),
+        KernelCase("mla_decode_bf16_wide_table", make(jnp.bfloat16, *wide),
+                   kernel, oracle, atol=3e-2, rtol=3e-2,
+                   oracle_precision="highest"),
     ]
 
 

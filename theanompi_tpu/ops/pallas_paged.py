@@ -4,13 +4,24 @@ One decode tick's attention for every serving lane: a single query
 token per sequence against K/V gathered **block by block from the
 paged pool inside the kernel**.  The lane's block table is a
 scalar-prefetch argument, so the BlockSpec ``index_map`` reads
-``table[s, j]`` and each grid step DMAs exactly ONE pool block into
-VMEM — the XLA path instead materializes the whole gathered
-``(S, t_pad, H, hd)`` image in HBM first (and, on a dp-sharded pool,
-pays a GSPMD cross-shard gather for it).  Softmax runs as the online
-recurrence over the block stream (same max/denominator carry as
-``pallas_flash``), so nothing quadratic in the table length ever
-leaves VMEM.
+``table[lane, j]`` and a grid step DMAs one pool block into VMEM
+(``mla_paged_decode``: a group of 16) — the XLA path instead
+materializes the whole gathered ``(S, t_pad, H, hd)`` image in HBM
+first (and, on a dp-sharded pool, pays a GSPMD cross-shard gather for
+it).  Softmax runs as the online recurrence over the block stream
+(same max/denominator carry as ``pallas_flash``), so nothing quadratic
+in the table length ever leaves VMEM.
+
+**The grid is the lanes' resident blocks, not the table's width**
+(``lane_steps``): one flat list of (lane, block group) pairs, lanes in
+order and each lane's groups in order, ``length // span + 1`` of them
+a lane, and a one-dimensional grid whose bound is the list's length
+(a traced scalar: the same compiled program whatever the lanes hold).
+A step past a lane's length does not exist, so a table 35 groups wide
+over contexts of three costs three steps a lane, and a full table
+walks what a rectangular grid would.  Every lane has one step at
+least (an idle lane's, on the trash block), so every output row is
+written.
 
 int8 pool payloads (``serving.paging`` ``kv_dtype='int8'``)
 dequantize **in-kernel**: the per-row/per-head fp32 scales ride a
@@ -18,10 +29,8 @@ parallel scale pool gathered through the same table, and the int8
 rows never round-trip through an fp32 HBM image — the capacity win of
 the quantized cache is also a bandwidth win on the decode hot path.
 
-Blocks whose first row is already past the lane's resident length are
-skipped entirely (``pl.when``), mirroring the flash kernels' masked-
-block elision; within the boundary block, rows past the length mask
-to ``-inf`` exactly like the XLA path's ``att_mask``.
+Within a lane's boundary block, rows past the length mask to ``-inf``
+exactly like the XLA path's ``att_mask``.
 
 ``interpret=True`` on the CPU (the ``ops.platform.on_tpu`` gate)
 so CPU CI exercises the same kernel code — the tier-1 contract is
@@ -71,6 +80,63 @@ def supported(mesh=None) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# the grid: one step for each (lane, block group) that holds rows
+# ---------------------------------------------------------------------------
+
+def lane_steps(lengths, span: int, steps_per_lane: int):
+    """The decode kernels' grid as a list: ``(lane, j, total)``.
+
+    A lane attends to positions ``<= length``, so it takes ``n =
+    length // span + 1`` steps of ``span`` rows (at most
+    ``steps_per_lane``, the table's width; an idle lane at length 0
+    takes one, on the trash block).  ``lane`` and ``j`` are flat int32
+    arrays of the static length ``S * steps_per_lane``: step ``t`` of
+    the grid is group ``j[t]`` of lane ``lane[t]``, lanes in order and
+    each lane's groups in order; ``total = sum(n)`` is the grid's bound
+    and the tail past it is never visited.  Plain XLA on S integers: a
+    decode program builds it once and hands it to every layer's call.
+    """
+    lengths = jnp.asarray(lengths, jnp.int32)
+    s = lengths.shape[0]
+    n = jnp.minimum(lengths // span + 1, steps_per_lane)
+    t = jnp.arange(s * steps_per_lane, dtype=jnp.int32)
+    # (T, S): lane i lies wholly before step t.  One compare and two
+    # sums, no search: a search is a loop on the device
+    before = t[:, None] >= jnp.cumsum(n)[None, :]
+    lane = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), s - 1)
+    j = t - jnp.sum(jnp.where(before, n[None, :], 0), axis=1)
+    return lane, j, jnp.sum(n)
+
+
+MLA_GROUP = 16  # blocks a grid step of ``mla_paged_decode``
+
+
+def mla_grid(block_size: int, table_blocks: int, group: int = MLA_GROUP):
+    """``(span, steps_per_lane)`` of ``mla_paged_decode`` over a table
+    of ``table_blocks`` columns: rows a grid step, and a full lane's
+    steps (what ``lane_steps`` takes)."""
+    g = max(1, min(int(group), int(table_blocks)))
+    return g * int(block_size), -(-int(table_blocks) // g)
+
+
+def _grid(tables, lengths, span, steps_per_lane, steps):
+    """``(grid, scalar-prefetch operands)`` of a decode kernel: the
+    list's length, and ``lane, j, tables, lengths``.  ``steps`` is the
+    caller's ``lane_steps`` (a decode program's one list for all its
+    layers) or None to build it here."""
+    lengths = jnp.minimum(
+        jnp.asarray(lengths, jnp.int32), steps_per_lane * span - 1)
+    lane, j, total = (lane_steps(lengths, span, steps_per_lane)
+                      if steps is None else steps)
+    if lane.shape != (tables.shape[0] * steps_per_lane,) \
+            or j.shape != lane.shape:
+        raise ValueError(
+            f"steps are lane_steps(lengths, {span}, {steps_per_lane}) of "
+            f"{tables.shape[0]} lanes, got arrays of {lane.shape}, {j.shape}")
+    return (total,), (lane, j, jnp.asarray(tables, jnp.int32), lengths)
+
+
+# ---------------------------------------------------------------------------
 # kernel body
 # ---------------------------------------------------------------------------
 
@@ -82,8 +148,8 @@ def _head_columns(h, hd, w):
     return (col >= lo) & (col < lo + hd)
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *refs,
-                  bs, nt, scale, hd, quant):
+def _paged_kernel(lane_ref, j_ref, tbl_ref, len_ref, q_ref, k_ref, v_ref,
+                  *refs, bs, scale, hd, quant):
     """One lane's online softmax over its block stream, on flat rows.
 
     A pool row holds the heads side by side, so per-head scores come
@@ -100,8 +166,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *refs,
         ks_ref, vs_ref, o_ref, qh_ref, m_ref, d_ref, acc_ref = refs
     else:
         o_ref, qh_ref, m_ref, d_ref, acc_ref = refs
-    s_idx = pl.program_id(0)
-    j = pl.program_id(1)
+    t = pl.program_id(0)
+    s_idx, j = lane_ref[t], j_ref[t]
     h, w = acc_ref.shape
 
     @pl.when(j == 0)
@@ -116,29 +182,27 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *refs,
     # XLA att_mask
     length = len_ref[s_idx]
 
-    @pl.when(j * bs <= length)  # fully-masked blocks are elided
-    def _work():
-        s = lax.dot_general(
-            qh_ref[...], k_ref[...].astype(jnp.float32),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        ) * scale  # (H, bs)
-        if quant:
-            s = s * ks_ref[...].T
-        pos = j * bs + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos <= length, s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        d_ref[...] = d_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        if quant:
-            p = p * vs_ref[...].T
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p, v_ref[...].astype(jnp.float32),
-            preferred_element_type=jnp.float32)  # (H, W)
-        m_ref[...] = m_new
+    s = lax.dot_general(
+        qh_ref[...], k_ref[...].astype(jnp.float32),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+    ) * scale  # (H, bs)
+    if quant:
+        s = s * ks_ref[...].T
+    pos = j * bs + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(pos <= length, s, _NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    d_ref[...] = d_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    if quant:
+        p = p * vs_ref[...].T
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+        p, v_ref[...].astype(jnp.float32),
+        preferred_element_type=jnp.float32)  # (H, W)
+    m_ref[...] = m_new
 
-    @pl.when(j == nt - 1)
+    @pl.when(j == length // bs)  # the lane's last step
     def _fin():
         own = jnp.where(
             _head_columns(h, hd, w), acc_ref[...] / d_ref[...], 0.0)
@@ -160,6 +224,7 @@ def paged_decode_attention(
     scale: Optional[float] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    steps=None,
     interpret: Optional[bool] = None,
 ):
     """softmax(q·Kᵀ·scale)·V over each lane's paged K/V, one layer.
@@ -179,9 +244,12 @@ def paged_decode_attention(
       call, exactly like the XLA path).
     - ``k_scale``/``v_scale`` (R, H) fp32: required when the pools are
       int8 — per-row/per-head dequant scales.
+    - ``steps``: ``lane_steps(lengths, block_size, NT)`` where the
+      caller has built it for several calls; built here when None.
 
     A grid step fetches one ``(block_size, W)`` block of each pool
-    through the table-driven index map.  Returns fp32 (S, H, hd).
+    through the table-driven index map, and the grid holds a lane's
+    resident blocks alone (``lane_steps``).  Returns fp32 (S, H, hd).
     Numerics contract (tier-1 pinned): allclose to the XLA gather path
     on both pool dtypes.
     """
@@ -199,11 +267,11 @@ def paged_decode_attention(
         )
     sc = resolve_scale(scale, hd)
 
-    def _pool_map(si, j, tbl, ln):
-        return (tbl[si, j], 0)
+    def _pool_map(t, lane, j, tbl, ln):
+        return (tbl[lane[t], j[t]], 0)
 
-    def _row_map(si, j, tbl, ln):
-        return (si, 0, 0)
+    def _row_map(t, lane, j, tbl, ln):
+        return (lane[t], 0, 0)
 
     # the lane's query as one flat row, like the pool's
     q_flat = jnp.pad(q.reshape(s, 1, h * hd), ((0, 0), (0, 0), (0, w - h * hd)))
@@ -219,9 +287,10 @@ def paged_decode_attention(
             pl.BlockSpec((bs, h), _pool_map),        # v scales
         ]
         args += [k_scale, v_scale]
+    grid, scalars = _grid(tables, lengths, bs, nt, steps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # tables, lengths
-        grid=(s, nt),
+        num_scalar_prefetch=4,  # lane, j, tables, lengths
+        grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, w), _row_map),
         scratch_shapes=[
@@ -232,17 +301,14 @@ def paged_decode_attention(
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, nt=nt, scale=sc, hd=hd,
+        functools.partial(_paged_kernel, bs=bs, scale=sc, hd=hd,
                           quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, 1, w), jnp.float32),
         interpret=(not platform.on_tpu()) if interpret is None else interpret,
         # the device operation's name in a profile starts with this
         name="paged_decode_attn_i8" if quant else "paged_decode_attn",
-    )(
-        jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-        *args,
-    )
+    )(*scalars, *args)
     return out[:, 0, :h * hd].reshape(s, h, hd)
 
 
@@ -250,11 +316,12 @@ def paged_decode_attention(
 # latent cache: absorbed decode over rows shared by every head
 # ---------------------------------------------------------------------------
 
-def _mla_kernel(tbl_ref, len_ref, ql_ref, qr_ref, *refs, bs, group, nj,
-                scale, c_dim, r_dim):
+def _mla_kernel(lane_ref, j_ref, tbl_ref, len_ref, ql_ref, qr_ref, *refs,
+                bs, group, scale, c_dim, r_dim):
     pool_refs, o_ref = refs[:group], refs[group]
     m_ref, d_ref, acc_ref = refs[group + 1:]
-    s_idx, j = pl.program_id(0), pl.program_id(1)
+    t = pl.program_id(0)
+    s_idx, j = lane_ref[t], j_ref[t]
 
     @pl.when(j == 0)
     def _init():
@@ -265,29 +332,27 @@ def _mla_kernel(tbl_ref, len_ref, ql_ref, qr_ref, *refs, bs, group, nj,
     length = len_ref[s_idx]
     span = group * bs
 
-    @pl.when(j * span <= length)  # fully-masked groups are elided
-    def _work():
-        rows = jnp.concatenate([r[...] for r in pool_refs], axis=0)
-        c, kr = rows[:, :c_dim], rows[:, c_dim:c_dim + r_dim]
-        nt = (((1,), (1,)), ((), ()))
-        sc = (
-            lax.dot_general(ql_ref[0], c, nt,
-                            preferred_element_type=jnp.float32)
-            + lax.dot_general(qr_ref[0], kr, nt,
-                              preferred_element_type=jnp.float32)
-        ) * scale  # (H, span)
-        pos = j * span + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(pos <= length, sc, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        d_ref[...] = d_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(c.dtype), c, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+    rows = jnp.concatenate([r[...] for r in pool_refs], axis=0)
+    c, kr = rows[:, :c_dim], rows[:, c_dim:c_dim + r_dim]
+    nt = (((1,), (1,)), ((), ()))
+    sc = (
+        lax.dot_general(ql_ref[0], c, nt,
+                        preferred_element_type=jnp.float32)
+        + lax.dot_general(qr_ref[0], kr, nt,
+                          preferred_element_type=jnp.float32)
+    ) * scale  # (H, span)
+    pos = j * span + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    sc = jnp.where(pos <= length, sc, _NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+    p = jnp.exp(sc - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    d_ref[...] = d_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+        p.astype(c.dtype), c, preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
 
-    @pl.when(j == nj - 1)
+    @pl.when(j == length // span)  # the lane's last step
     def _fin():
         o_ref[0] = (acc_ref[...] / d_ref[...]).astype(o_ref.dtype)
 
@@ -301,7 +366,8 @@ def mla_paged_decode(
     *,
     block_size: int,
     scale: float,
-    group: int = 16,
+    group: int = MLA_GROUP,
+    steps=None,
     interpret: Optional[bool] = None,
 ):
     """Absorbed latent attention for one decode tick, one layer:
@@ -316,34 +382,37 @@ def mla_paged_decode(
       tall array out column-major, and every call would then copy the
       whole pool to row-major and back.)
     - ``tables`` (S, NT), ``lengths`` (S,) as ``paged_decode_attention``.
+    - ``steps``: ``lane_steps(lengths, *mla_grid(block_size, NT,
+      group))`` where the caller has built it for several calls.
 
     A grid step attends to ``group`` blocks: the pool is handed to the
     call ``group`` times, each with its own table-driven BlockSpec, so
     the blocks arrive by the pipeline's own double-buffered copies; a
     block past the lane's last one maps onto the last (no new copy) and
-    its positions are masked.  Returns fp32 (S, H, C): the caller
-    carries it out of the latent space (``· W_kvb[v]``)."""
+    its positions are masked.  The grid holds a lane's resident groups
+    alone (``lane_steps``).  Returns fp32 (S, H, C): the caller carries
+    it out of the latent space (``· W_kvb[v]``)."""
     s, h, c_dim = q_lat.shape
     r_dim = q_rope.shape[-1]
     bs = int(block_size)
-    g = max(1, min(int(group), int(tables.shape[1])))
+    span, nj = mla_grid(bs, int(tables.shape[1]), group)
+    g = span // bs
     tables = jnp.asarray(tables, jnp.int32)
-    pad = -tables.shape[1] % g
-    if pad:
-        tables = jnp.pad(tables, ((0, 0), (0, pad)))
-    nj = tables.shape[1] // g
+    tables = jnp.pad(tables, ((0, 0), (0, nj * g - tables.shape[1])))
 
     def pool_map(k):
-        def index(si, j, tbl, ln):
-            return (tbl[si, jnp.minimum(j * g + k, ln[si] // bs)], 0)
+        def index(t, lane, j, tbl, ln):
+            si = lane[t]
+            return (tbl[si, jnp.minimum(j[t] * g + k, ln[si] // bs)], 0)
         return index
 
-    def row_map(si, j, tbl, ln):
-        return (si, 0, 0)
+    def row_map(t, lane, j, tbl, ln):
+        return (lane[t], 0, 0)
 
+    grid, scalars = _grid(tables, lengths, span, nj, steps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # tables, lengths
-        grid=(s, nj),
+        num_scalar_prefetch=4,  # lane, j, tables, lengths
+        grid=grid,
         in_specs=[pl.BlockSpec((1, h, c_dim), row_map),
                   pl.BlockSpec((1, h, r_dim), row_map)]
         + [pl.BlockSpec((bs, pool.shape[1]), pool_map(k)) for k in range(g)],
@@ -355,14 +424,14 @@ def mla_paged_decode(
         ],
     )
     return pl.pallas_call(
-        functools.partial(_mla_kernel, bs=bs, group=g, nj=nj,
+        functools.partial(_mla_kernel, bs=bs, group=g,
                           scale=float(scale), c_dim=c_dim, r_dim=r_dim),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, c_dim), jnp.float32),
         interpret=(not platform.on_tpu()) if interpret is None else interpret,
         name="mla_paged_decode",
-    )(tables, jnp.asarray(lengths, jnp.int32),
-      q_lat.astype(pool.dtype), q_rope.astype(pool.dtype), *([pool] * g))
+    )(*scalars, q_lat.astype(pool.dtype), q_rope.astype(pool.dtype),
+      *([pool] * g))
 
 
 def mla_decode_xla(q_lat, q_rope, pool, tables, lengths, *, block_size,

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from theanompi_tpu.ops import pallas_paged
 from theanompi_tpu.ops.pallas_flash import _NEG_INF
 from theanompi_tpu.runtime.mesh import DATA_AXIS, TP_AXIS
 from theanompi_tpu.serving.engine import TRASH_BLOCK
@@ -70,6 +71,10 @@ class DensePrograms:
         self.n_heads = int(cfg.n_heads)
         self.head_dim = engine.d_model // self.n_heads
         self.scale = self.head_dim ** -0.5
+        # rows a grid step of the decode kernel attends to, and a full
+        # lane's steps
+        self.attn_span, self.attn_steps = (
+            engine.block_size, engine.blocks_per_seq)
         mesh = engine.mesh
         # pool rows shard over dp only when every per-device shard is a
         # whole number of blocks (a split block would tear the
@@ -368,13 +373,15 @@ class DensePrograms:
             att_mask = jnp.arange(e.t_pad)[None, :] <= pos_idx[:, None]
 
             if e.paged_attn_effective == "pallas":
-                from theanompi_tpu.ops import pallas_paged
+                # the kernel's grid, one list for every layer's call
+                steps = pallas_paged.lane_steps(
+                    pos_idx, self.attn_span, self.attn_steps)
 
                 def attention(q, k_pool, k_scale, v_pool, v_scale):
                     return pallas_paged.paged_decode_attention(
                         q, k_pool, v_pool, tables, pos_idx,
                         block_size=bs, scale=self.scale,
-                        k_scale=k_scale, v_scale=v_scale,
+                        k_scale=k_scale, v_scale=v_scale, steps=steps,
                     )
             else:
                 def attention(q, k_pool, k_scale, v_pool, v_scale):

@@ -63,6 +63,7 @@ the picks of useful tokens that fell on a held expert).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -70,7 +71,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from theanompi_tpu.ops import kda, platform
+from theanompi_tpu.ops import kda, pallas_paged, platform
 from theanompi_tpu.ops.pallas_flash import _NEG_INF
 from theanompi_tpu.serving.engine import TRASH_BLOCK
 
@@ -151,6 +152,10 @@ class LatentPrograms:
         # costs in memory), and a quarter of a short context so that the
         # tests' small engines loop too
         self.ctx_block = max(bs, min(512, engine.t_pad // 4) // bs * bs)
+        # rows a grid step of the decode kernel attends to, and a full
+        # lane's steps
+        self.attn_span, self.attn_steps = pallas_paged.mla_grid(
+            bs, engine.blocks_per_seq)
         self.row_width = -(-self.attn.row_dim // 128) * 128
         # the rows' dtype: the compute dtype where the model names one,
         # else the dtype its weights are held in (activations follow
@@ -320,16 +325,19 @@ class LatentPrograms:
                          wr.reshape(-1), attention, recur, pick_rows)
 
     def decode_fn(self, params, state, tokens, tables, lengths, active):
-        from theanompi_tpu.ops import pallas_paged
-
         e = self.engine
         bs = e.block_size
         blk = jnp.take_along_axis(
             tables, jnp.minimum(lengths // bs, e.blocks_per_seq - 1)[:, None],
             axis=1)[:, 0]
         wr = jnp.where(active, blk * bs + lengths % bs, TRASH_BLOCK)
-        decode = (pallas_paged.mla_paged_decode if self.impl == "pallas"
-                  else pallas_paged.mla_decode_xla)
+        if self.impl == "pallas":
+            # the kernel's grid, one list for every latent layer's call
+            decode = functools.partial(
+                pallas_paged.mla_paged_decode, steps=pallas_paged.lane_steps(
+                    lengths, self.attn_span, self.attn_steps))
+        else:
+            decode = pallas_paged.mla_decode_xla
 
         def attention(ap, q_nope, q_rope, pool):
             o_lat = decode(self.attn.absorb(ap, q_nope), q_rope, pool, tables,

@@ -675,8 +675,15 @@ class ContinuousBatchingScheduler:
             self._tokens[i] = (
                 slot.request.output[-1] if decoding[i] else 0
             )
-        with obs.span("decode_step", boundary=True,
-                      active=int(decoding.sum())) as span:
+        programs = self.engine.programs
+        with obs.span(
+            "decode_step", boundary=True, active=int(decoding.sum()),
+            # the decode kernel's grid: what the lanes hold, and what
+            # the table's width would walk
+            attn_steps=int(
+                (self._lengths // programs.attn_span + 1).sum()),
+            attn_steps_table=len(self.slots) * programs.attn_steps,
+        ) as span:
             self.state, logits = self.engine.decode_step_paged(
                 self.params, self.state, self._tokens,
                 self._tables, self._lengths, decoding,
