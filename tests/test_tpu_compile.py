@@ -289,6 +289,73 @@ def test_kimi_linear_stage_programs_fit_one_v5e_chip(one_chip, as_tpu):
     assert "kda_chunk_prefill" in text and "moe_grouped_mm_down" in text
 
 
+def test_smallthinker_stage_programs_fit_one_v5e_chip(one_chip, as_tpu):
+    """The decode program and the widest prefill program of the
+    benchmark's ``smallthinker-21b-a3b`` stage, at the sizes of its
+    configuration file (published widths, 3.97 G bfloat16 weights, two
+    global layers' keys and values over 16,385 blocks, six window
+    layers' rings of 192 blocks for 32 lanes), compile for a v5e with
+    their kernels inside and fit the chip; pool and rings are updated
+    in place."""
+    import json
+    import os
+
+    from theanompi_tpu.models.transformer import TransformerLM
+    from theanompi_tpu.serving import PagedServingEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "smallthinker-21b-a3b.json")) as f:
+        cfg = json.load(f)
+    pc = dict(cfg["program_config"], seed=0)
+    model = TransformerLM(
+        config=pc,
+        mesh=TransformerLM.build_mesh(devices=jax.devices()[:1], config=pc),
+    )
+    assert 3.96e9 < model.n_params < 3.98e9 and model.opt_state is None
+    eng = PagedServingEngine(model, **cfg["engine"])
+    p = eng.programs
+    assert eng.paged_attn_effective == "pallas" and eng.prefill_rows == 1
+    assert p.lane_state == "window rings" and not eng.prefix_cache_enabled
+    assert (p.n_full, p.n_window, p.ring_blocks) == (2, 6, 192)
+    # keys and values, 512 wide, in the two global layers
+    assert eng.kv_block_bytes() == 2 * 2 * 32 * 512 * 2
+    assert p.ring_bytes() == 6 * 2 * 32 * 6144 * 512 * 2
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one_chip),
+        model.params)
+    state = _described(jax.eval_shape(eng.init_state), one_chip)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s, nb, c = eng.n_slots, eng.blocks_per_seq, eng.chunk_buckets[-1]
+    r = eng.prefill_rows
+    assert (s, nb, c) == (32, 512, 2048)
+    prefill = eng._paged_prefill_jit.lower(
+        params, state, arg(jnp.int32, r, c), arg(jnp.int32, r, nb),
+        arg(jnp.int32, r), arg(jnp.int32, r), arg(jnp.bool_, r),
+        arg(jnp.int32, r),
+    ).compile()
+    decode = eng._paged_decode_jit.lower(
+        params, state, arg(jnp.int32, s), arg(jnp.int32, s, nb),
+        arg(jnp.int32, s), arg(jnp.bool_, s),
+    ).compile()
+    for name, program in (("prefill", prefill), ("decode", decode)):
+        m = program.memory_analysis()
+        held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+                + m.output_size_in_bytes - m.alias_size_in_bytes)
+        print(name, "arguments", m.argument_size_in_bytes, "temporaries",
+              m.temp_size_in_bytes, "held", held)
+        assert held < HBM_BYTES, held
+        # pool and rings are updated in place: no copy among the temporaries
+        assert m.temp_size_in_bytes < 2 * 1024 ** 3, m.temp_size_in_bytes
+    text = decode.as_text()
+    for kernel in ("paged_decode_attn", "moe_grouped_mm_gate"):
+        assert kernel in text, kernel
+    assert "moe_grouped_mm_down" in prefill.as_text()
+
+
 def test_alexnet_step_fits_one_v5e_chip(topo, as_tpu):
     """Batch 512, bf16, every layer at its width: the step the *train*
     phase of chip_smoke.py runs."""

@@ -80,21 +80,35 @@ class TransformerLM(TpuModel):
         # table (above).  'latent_moe': ops.latent_block — RMSNorm, YaRN
         # rotary positions (no table: seq_len only sizes the data),
         # latent attention, a gated feed-forward in the first
-        # `first_k_dense` blocks and `moe_experts` sigmoid-routed experts
+        # `first_k_dense` blocks and `moe_experts` routed experts
         # (+ `n_shared_experts`) after them, a residual of `hc_mult`
         # streams (`hc_mult` 1: the plain residual); its sizes are the
-        # keys below.  `q_lora_rank` None: queries from one matrix;
-        # `mla_nope`: no rotary positions; `kda_layers`: the layers
-        # (counted from 1) whose mixer is Kimi delta attention
-        # (ops.kda: `kda_head_dim`, `kda_conv`) and not latent
-        # attention; `moe_experts_held`: how many of the `moe_experts`
-        # (the first ones) this model holds and computes (parallel.moe)
+        # keys below.  `mixer`: the attention of the layers that are
+        # not `kda_layers`, 'mla' (latent) or 'gqa' (grouped-query:
+        # `n_kv_heads` key/value heads of `head_dim`; the layers in
+        # `window_layers` see the last `window` positions, the others
+        # the whole context; those in `rope_layers` take rotary
+        # positions, the others none).  `q_lora_rank` None: queries
+        # from one matrix; `mla_nope`: no rotary positions in latent
+        # attention; `rope_layout`: 'interleaved' (DeepSeek's pairs) or
+        # 'half' (rotate-half); `kda_layers`: the layers whose mixer is
+        # Kimi delta attention (ops.kda: `kda_head_dim`, `kda_conv`);
+        # layer lists count from 1.  `moe_scoring` 'sigmoid' (with a
+        # selection bias) or 'softmax'; `moe_activation` 'silu' or
+        # 'relu' (ReGLU experts); `moe_route_from` 'ffn' (the router
+        # reads the feed-forward's normed input) or 'attn' (the
+        # attention's); `moe_experts_held`: how many of the
+        # `moe_experts` (the first ones) this model holds and computes
+        # (parallel.moe)
+        mixer="mla", n_kv_heads=None, head_dim=None, window=None,
+        window_layers=(), rope_layers=(), rope_layout="interleaved",
         q_lora_rank=None, kv_lora_rank=None, qk_nope_head_dim=None,
         qk_rope_head_dim=None, v_head_dim=None,
         ffn_hidden=None, first_k_dense=1, n_shared_experts=0,
         route_scale=1.0, rms_norm_eps=1e-6,
         hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6, hc_clamp=30.0,
         mla_nope=False, kda_layers=(), kda_head_dim=128, kda_conv=4,
+        moe_scoring="sigmoid", moe_activation="silu", moe_route_from="ffn",
         moe_experts_held=None,
         rope=None,  # dict: theta, factor, original_max_position,
         # beta_fast, beta_slow, mscale, mscale_all_dim (ops.attention)
@@ -309,19 +323,44 @@ class TransformerLM(TpuModel):
         dt = jnp.dtype(cfg.compute_dtype) if cfg.compute_dtype else None
         pdt = jnp.dtype(cfg.param_dtype)
         d, n = int(cfg.d_model), int(cfg.hc_mult)
-        q_rank = None if cfg.q_lora_rank is None else int(cfg.q_lora_rank)
-        attn = LB.LatentAttention(
-            d, int(cfg.n_heads), q_rank, int(cfg.kv_lora_rank),
-            int(cfg.qk_nope_head_dim), int(cfg.qk_rope_head_dim),
-            int(cfg.v_head_dim), float(cfg.rms_norm_eps), dict(cfg.rope or {}),
-            rotary=not bool(cfg.mla_nope))
+        eps, rope = float(cfg.rms_norm_eps), dict(cfg.rope or {})
+        if str(cfg.mixer) == "gqa":
+            windowed = {int(i) for i in (cfg.window_layers or ())}
+            rotary = {int(i) for i in (cfg.rope_layers or ())}
+            kinds = {}  # one GroupedAttention for each (window, rotary)
+
+            def attention(i):
+                key = (i + 1 in windowed, i + 1 in rotary)
+                if key not in kinds:
+                    kinds[key] = LB.GroupedAttention(
+                        d, int(cfg.n_heads), int(cfg.n_kv_heads),
+                        int(cfg.head_dim), eps,
+                        window=int(cfg.window) if key[0] else None,
+                        rotary=key[1], theta=float(rope.get("theta", 1e4)),
+                        rope_layout=str(cfg.rope_layout))
+                return kinds[key]
+        elif str(cfg.mixer) == "mla":
+            q_rank = None if cfg.q_lora_rank is None else int(cfg.q_lora_rank)
+            latent = LB.LatentAttention(
+                d, int(cfg.n_heads), q_rank, int(cfg.kv_lora_rank),
+                int(cfg.qk_nope_head_dim), int(cfg.qk_rope_head_dim),
+                int(cfg.v_head_dim), eps, rope, rotary=not bool(cfg.mla_nope),
+                rope_layout=str(cfg.rope_layout))
+
+            def attention(i):
+                return latent
+        else:
+            raise ValueError(f"mixer must be 'mla' or 'gqa', got {cfg.mixer!r}")
+        if str(cfg.moe_route_from) not in ("ffn", "attn"):
+            raise ValueError("moe_route_from must be 'ffn' or 'attn', got "
+                             f"{cfg.moe_route_from!r}")
         kda_layers = {int(i) for i in (cfg.kda_layers or ())}
         kda = None
         if kda_layers:
             from theanompi_tpu.ops.kda import KdaMixer
 
             kda = KdaMixer(d, int(cfg.n_heads), int(cfg.kda_head_dim),
-                           int(cfg.kda_conv), float(cfg.rms_norm_eps))
+                           int(cfg.kda_conv), eps)
         held = cfg.moe_experts_held
         held = None if held is None else (0, int(held))
 
@@ -331,21 +370,23 @@ class TransformerLM(TpuModel):
                 moe = MoeMlp(
                     int(cfg.moe_experts), int(cfg.moe_hidden),
                     top_k=int(cfg.moe_top_k), ep_axis=None, compute_dtype=dt,
-                    emit_aux=False, scoring="sigmoid",
+                    emit_aux=False, scoring=str(cfg.moe_scoring),
                     route_scale=float(cfg.route_scale), gated=True,
                     n_shared=int(cfg.n_shared_experts), experts_held=held,
-                    param_dtype=pdt, w_init=normal_init(0.02))
+                    param_dtype=pdt, w_init=normal_init(0.02),
+                    activation=str(cfg.moe_activation))
             return LB.LatentMoeBlock(
-                attn, ffn_hidden=int(cfg.ffn_hidden), moe=moe, n_streams=n,
-                hc_iters=int(cfg.hc_sinkhorn_iters), hc_eps=float(cfg.hc_eps),
-                hc_clamp=float(cfg.hc_clamp), param_dtype=pdt,
-                kda=kda if i + 1 in kda_layers else None)
+                attention(i), ffn_hidden=int(cfg.ffn_hidden or 0), moe=moe,
+                n_streams=n, hc_iters=int(cfg.hc_sinkhorn_iters),
+                hc_eps=float(cfg.hc_eps), hc_clamp=float(cfg.hc_clamp),
+                param_dtype=pdt, kda=kda if i + 1 in kda_layers else None,
+                route_from_attn=str(cfg.moe_route_from) == "attn")
 
         net = L.Sequential([
             LB.StreamEmbedding(int(cfg.vocab_size), d, n, compute_dtype=dt,
                                param_dtype=pdt),
             *[make_block(i) for i in range(int(cfg.n_layers))],
-            LB.StreamSumNorm(n, float(cfg.rms_norm_eps), param_dtype=pdt),
+            LB.StreamSumNorm(n, eps, param_dtype=pdt),
             L.Dense(int(cfg.vocab_size), use_bias=False,
                     w_init=normal_init(0.02), compute_dtype=dt,
                     output_dtype=jnp.float32),

@@ -397,12 +397,32 @@ def rope_interleaved(x, positions, inv_freq, scale: float = 1.0):
         [xe * cos - xo * sin, xe * sin + xo * cos], axis=-1).astype(x.dtype)
 
 
-def gated_ffn(x, w_gate, w_up, w_down):
-    """``(silu(x W_gate) ⊙ (x W_up)) W_down``: operands as they come,
-    fp32 accumulation, the activation in fp32, result in ``x``'s
-    dtype."""
+def rope_half(x, positions, inv_freq, scale: float = 1.0):
+    """Rotate the pairs ``(x[i], x[i + dim/2])`` of the last dim by
+    ``positions · inv_freq[i]``: the rotate-half layout of Llama and
+    Qwen (``x·cos + rotate_half(x)·sin``).  Shapes as
+    ``rope_interleaved``; the result keeps the layout."""
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    if x.ndim == ang.ndim + 1:
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    xf = x.astype(jnp.float32)
+    half = xf.shape[-1] // 2
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def gated_ffn(x, w_gate, w_up, w_down, activation: str = "silu"):
+    """``(act(x W_gate) ⊙ (x W_up)) W_down`` (``act`` SiLU, or ReLU:
+    ReGLU): operands as they come, fp32 accumulation, the activation in
+    fp32, result in ``x``'s dtype."""
     g = jnp.dot(x, w_gate.astype(x.dtype), preferred_element_type=jnp.float32)
     u = jnp.dot(x, w_up.astype(x.dtype), preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    h = (GATES[activation](g) * u).astype(x.dtype)
     return jnp.dot(h, w_down.astype(x.dtype),
                    preferred_element_type=jnp.float32).astype(x.dtype)

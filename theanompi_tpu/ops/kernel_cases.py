@@ -208,6 +208,51 @@ def _paged_cases(size):
             ("paged_decode_f32_h25", False, h_odd),
             ("paged_decode_int8_h25", True, h_odd),
         )
+    ] + _grouped_cases(size)
+
+
+def _grouped_cases(size):
+    """The same kernel with key/value heads shared by groups of query
+    heads (SmallThinker's 28 over 4, heads of 128, in bfloat16), over
+    the shared table, and over per-lane rings under a window."""
+    from theanompi_tpu.ops.pallas_paged import (
+        paged_decode_attention, paged_decode_xla,
+    )
+
+    s, h, kv, hd, bs, nt, ring, window = (
+        (32, 28, 4, 128, 32, 32, 8, 200) if size == "real"
+        else (3, 6, 2, 8, 4, 5, 3, 9))
+    nb = s * ring + 1  # the rings of every lane and the trash block
+
+    def make(rings):
+        def make_args(key):
+            kq, kk, kv_, kt, kl = jax.random.split(key, 5)
+            bf = jnp.bfloat16
+            q = jax.random.normal(kq, (s, h, hd), jnp.float32).astype(bf)
+            width = -(-kv * hd // 128) * 128
+            kp = jax.random.normal(kk, (nb * bs, width), jnp.float32).astype(bf)
+            vp = jax.random.normal(kv_, (nb * bs, width), jnp.float32).astype(bf)
+            if rings:  # column c of lane l: its ring block c mod R
+                tables = (1 + jnp.arange(s)[:, None] * ring
+                          + jnp.arange(nt)[None, :] % ring).astype(jnp.int32)
+            else:
+                tables = jax.random.randint(kt, (s, nt), 1, nb, jnp.int32)
+            lengths = jax.random.randint(kl, (s,), 0, nt * bs, jnp.int32)
+            lengths = lengths.at[0].set(0).at[-1].set(nt * bs - 1)
+            return q, kp, vp, tables, lengths
+        return make_args
+
+    def run(fn, win):
+        return lambda q, kp, vp, tables, lengths: fn(
+            q, kp, vp, tables, lengths, block_size=bs, kv_heads=kv,
+            window=win)
+
+    return [
+        KernelCase(name, make(rings), run(paged_decode_attention, win),
+                   run(paged_decode_xla, win), atol=2e-2, rtol=2e-2)
+        for name, rings, win in (("paged_decode_gqa", False, None),
+                                 ("paged_decode_gqa_window_ring", True,
+                                  window))
     ]
 
 

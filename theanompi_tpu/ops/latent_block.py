@@ -13,11 +13,15 @@ them to an ``attend`` function of the caller's —
   write the rows into the block pool and attend through a block table,
   expanded over a blocked context for a chunk, absorbed for one token.
 
-**The mixer** is one of two kinds, a block's ``kind``: latent attention
-(``'mla'``) or Kimi delta attention (``'kda'``, ``ops.kda``: no rows in
-a cache but a matrix a head and the convolution's last inputs; its
-``attend`` takes the convolution's inputs, the decay and the step and
-returns the heads' outputs, and owns that state).
+**The mixer** is one of three kinds, a block's ``kind``: latent
+attention (``'mla'``), grouped-query attention (``'gqa'``,
+``GroupedAttention``: keys and values of ``kv_heads`` heads, each shared
+by a group of query heads; windowed or global, rotary or not, layer by
+layer; its ``attend`` takes the queries, keys and values) or Kimi delta
+attention (``'kda'``, ``ops.kda``: no rows in a cache but a matrix a
+head and the convolution's last inputs; its ``attend`` takes the
+convolution's inputs, the decay and the step and returns the heads'
+outputs, and owns that state).
 
 **Latent attention** (as DeepSeek-V3; with ``q_rank`` ``None`` the
 queries come from one matrix, with ``rotary=False`` no position enters
@@ -35,7 +39,10 @@ hyper-connections (``ops.pallas_mhc``); the state is ``(tokens,
 n_streams · d)``.  One stream is the plain residual ``x + F(norm(x))``
 and has no hyper-connection parameters.
 
-**The feed-forward** is a gated dense one or ``parallel.moe.MoeMlp``.
+**The feed-forward** is a gated dense one or ``parallel.moe.MoeMlp``,
+whose router reads the feed-forward's normed input or, with
+``route_from_attn``, the attention's (SmallThinker's router placed
+before attention).
 """
 
 from __future__ import annotations
@@ -47,10 +54,16 @@ import jax.numpy as jnp
 
 from theanompi_tpu.ops import pallas_mhc
 from theanompi_tpu.ops.attention import (
-    gated_ffn, rms_norm, rope_interleaved, yarn_inv_freq, yarn_mscale,
+    gated_ffn, rms_norm, rope_half, rope_interleaved, yarn_inv_freq,
+    yarn_mscale,
 )
 from theanompi_tpu.ops.layers import Layer, normal_init
 from theanompi_tpu.ops.pallas_flash import _NEG_INF
+
+
+def _rotate(layout: str):
+    """The rotary function of a layout, looked up when it is called."""
+    return {"interleaved": rope_interleaved, "half": rope_half}[layout]
 
 
 def _mm(x, w):
@@ -63,10 +76,13 @@ def _mm(x, w):
 class LatentAttention:
     """Sizes and products of the latent attention; no state."""
 
+    kind = "mla"
+
     def __init__(self, d_model, n_heads, q_rank, kv_rank, nope, rope, v_dim,
-                 norm_eps, rope_cfg, rotary: bool = True):
+                 norm_eps, rope_cfg, rotary: bool = True,
+                 rope_layout: str = "interleaved"):
         self.d_model, self.n_heads = d_model, n_heads
-        self.rotary = rotary
+        self.rotary, self.rope_layout = rotary, rope_layout
         self.q_rank, self.kv_rank = q_rank, kv_rank
         self.nope, self.rope, self.v_dim = nope, rope, v_dim
         self.norm_eps = norm_eps
@@ -122,10 +138,9 @@ class LatentAttention:
         c_kv = rms_norm(kv[:, :self.kv_rank], ap["kv_norm"], self.norm_eps)
         q_rope, k_rope = q[..., self.nope:], kv[:, self.kv_rank:]
         if self.rotary:
-            q_rope = rope_interleaved(q_rope, positions, self.inv_freq,
-                                      self.rope_scale)
-            k_rope = rope_interleaved(k_rope, positions, self.inv_freq,
-                                      self.rope_scale)
+            rotate = _rotate(self.rope_layout)
+            q_rope = rotate(q_rope, positions, self.inv_freq, self.rope_scale)
+            k_rope = rotate(k_rope, positions, self.inv_freq, self.rope_scale)
         return q[..., :self.nope], q_rope, jnp.concatenate(
             [c_kv, k_rope], axis=-1)
 
@@ -184,10 +199,95 @@ def causal_attend(attn: LatentAttention, batch: int):
     return attend
 
 
+class GroupedAttention:
+    """Sizes and products of grouped-query attention; no state.
+    ``n_heads`` query heads and ``kv_heads`` key/value heads of
+    ``head_dim``, query head ``h`` reading key/value head ``h //
+    group``; no biases; scale ``head_dim^-1/2``.  ``window``: a query
+    at ``p`` sees the keys at ``p - window < j <= p`` (None: every key
+    before it); ``rotary``: positions enter by ``rope_layout`` at
+    ``theta``, or not at all (NoPE).  A token's keys and its values are
+    one row each, ``kv_heads · head_dim`` wide, heads side by side."""
+
+    kind = "gqa"
+
+    def __init__(self, d_model, n_heads, kv_heads, head_dim, norm_eps, *,
+                 window=None, rotary=True, theta=10000.0,
+                 rope_layout="half"):
+        if n_heads % kv_heads:
+            raise ValueError(f"{n_heads} query heads do not divide into "
+                             f"{kv_heads} key/value heads")
+        self.d_model, self.n_heads = d_model, n_heads
+        self.kv_heads, self.head_dim = kv_heads, head_dim
+        self.group = n_heads // kv_heads
+        self.norm_eps = norm_eps
+        self.window = None if window is None else int(window)
+        self.rotary, self.rope_layout = rotary, rope_layout
+        self.inv_freq = yarn_inv_freq(head_dim, float(theta))
+        self.scale = head_dim ** -0.5
+        self.width = kv_heads * head_dim
+
+    def init(self, key, dtype):
+        d, hd = self.d_model, self.head_dim
+        ks = jax.random.split(key, 4)
+        w = normal_init(0.02)
+        return {
+            "wq": w(ks[0], (d, self.n_heads * hd), d, dtype),
+            "wk": w(ks[1], (d, self.width), d, dtype),
+            "wv": w(ks[2], (d, self.width), d, dtype),
+            "wo": w(ks[3], (self.n_heads * hd, d), self.n_heads * hd, dtype),
+        }
+
+    def project(self, ap, hid, positions):
+        """``(q (N, H, hd), k (N, kv·hd), v (N, kv·hd))`` of the tokens
+        ``hid`` (N, d) at ``positions`` (N,)."""
+        n, hd = hid.shape[0], self.head_dim
+        q = _mm(hid, ap["wq"]).reshape(n, self.n_heads, hd)
+        k = _mm(hid, ap["wk"])
+        if self.rotary:
+            rotate = _rotate(self.rope_layout)
+            q = rotate(q, positions, self.inv_freq)
+            k = rotate(k.reshape(n, self.kv_heads, hd), positions,
+                       self.inv_freq).reshape(n, self.width)
+        return q, k, _mm(hid, ap["wv"])
+
+    def mask(self, q_at, k_at):
+        """Which keys at ``k_at`` the queries at ``q_at`` see
+        (broadcast): causal, and inside the window where there is one."""
+        seen = k_at <= q_at
+        if self.window is not None:
+            seen &= k_at > q_at - self.window
+        return seen
+
+    def out(self, ap, o):
+        return _mm(o.reshape(o.shape[0], -1), ap["wo"])
+
+
+def causal_gqa_attend(attn: GroupedAttention, batch: int):
+    """``attend`` for whole sequences with no cache, as
+    ``causal_attend``: causal, and windowed where the layer is."""
+
+    def attend(ap, q, k, v):
+        n, hd, kv = q.shape[0], attn.head_dim, attn.kv_heads
+        t = n // batch
+        f32 = dict(preferred_element_type=jnp.float32)
+        s = jnp.einsum("bqkgd,btkd->bkgqt",
+                       q.reshape(batch, t, kv, attn.group, hd),
+                       k.reshape(batch, t, kv, hd), **f32) * attn.scale
+        at = jnp.arange(t)
+        seen = attn.mask(at[:, None], at[None, :])
+        p = jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1)
+        o = jnp.einsum("bkgqt,btkd->bqkgd", p.astype(v.dtype),
+                       v.reshape(batch, t, kv, hd), **f32)
+        return o.reshape(n, attn.n_heads, hd).astype(q.dtype)
+
+    return attend
+
+
 class LatentMoeBlock(Layer):
-    """One block: a hyper-connected mixer (latent attention, or ``kda``
-    where one is given), then a hyper-connected feed-forward (dense
-    gated, or ``moe``).
+    """One block: a hyper-connected mixer (``attn``, latent or grouped,
+    or ``kda`` where one is given), then a hyper-connected feed-forward
+    (dense gated, or ``moe``).
 
     ``forward``'s ``impl``: ``'pallas'`` runs the hyper-connection halves
     and the experts as the named kernels (the server's choice on one
@@ -196,9 +296,11 @@ class LatentMoeBlock(Layer):
     def __init__(self, attn: LatentAttention, *, ffn_hidden: Optional[int],
                  moe=None, n_streams: int = 4, hc_iters: int = 20,
                  hc_eps: float = 1e-6, hc_clamp: float = 30.0,
-                 param_dtype=jnp.float32, kda=None):
+                 param_dtype=jnp.float32, kda=None,
+                 route_from_attn: bool = False):
         self.attn, self.kda = attn, kda
-        self.kind = "mla" if kda is None else "kda"
+        self.kind = attn.kind if kda is None else "kda"
+        self.route_from_attn = route_from_attn
         self.ffn_hidden, self.moe = ffn_hidden, moe
         self.n_streams = n_streams
         self.hc = dict(n=n_streams, eps=hc_eps, iters=hc_iters, clamp=hc_clamp)
@@ -269,17 +371,24 @@ class LatentMoeBlock(Layer):
         tokens each held expert received (``None`` for a dense block).
         ``attend(attention params, q_nope, q_rope, row) -> (N, H,
         v_dim)`` supplies the keys and values (module docstring); in a
-        ``kda`` block it is ``attend(mixer params, u, g, beta) -> (N, H,
-        K)``, the convolution and the recurrence over the caller's
-        state.  ``valid`` (N,) bool marks the rows that are tokens, not
-        padding."""
-        counts = []
+        ``gqa`` block it is ``attend(attention params, q, k, v) -> (N,
+        H, hd)``; in a ``kda`` block ``attend(mixer params, u, g, beta)
+        -> (N, H, K)``, the convolution and the recurrence over the
+        caller's state.  ``valid`` (N,) bool marks the rows that are
+        tokens, not padding."""
+        counts, normed = [], []
 
         def attention(hid):
+            normed.append(hid)
             if self.kda is not None:
                 mp = params["kda"]
                 u, g, beta = self.kda.project(mp, hid)
                 return self.kda.out(mp, attend(mp, u, g, beta), hid)
+            if self.kind == "gqa":
+                with jax.named_scope("gqa_attn"):
+                    ap = params["attn"]
+                    q, k, v = self.attn.project(ap, hid, positions)
+                    return self.attn.out(ap, attend(ap, q, k, v))
             with jax.named_scope("mla_attn"):
                 ap = params["attn"]
                 q_nope, q_rope, row = self.attn.project(ap, hid, positions)
@@ -290,8 +399,9 @@ class LatentMoeBlock(Layer):
                 mp = params["mlp"]
                 with jax.named_scope("mlp"):
                     return gated_ffn(hid, mp["w_gate"], mp["w_up"], mp["w_down"])
-            y, c, _ = self.moe.forward(params["moe"], hid, valid=valid,
-                                       impl=impl)
+            y, c, _ = self.moe.forward(
+                params["moe"], hid, valid=valid, impl=impl,
+                route_x=normed[0] if self.route_from_attn else None)
             counts.append(c)
             return y
 
@@ -306,8 +416,12 @@ class LatentMoeBlock(Layer):
         positions = jnp.tile(jnp.arange(t), b)
         from theanompi_tpu.ops.kda import causal_mix
 
-        attend = (causal_attend(self.attn, b) if self.kda is None
-                  else causal_mix(self.kda, b))
+        if self.kda is not None:
+            attend = causal_mix(self.kda, b)
+        elif self.kind == "gqa":
+            attend = causal_gqa_attend(self.attn, b)
+        else:
+            attend = causal_attend(self.attn, b)
         y, _ = self.forward(params, x.reshape(b * t, nd), positions, attend)
         return y.reshape(b, t, nd), state
 

@@ -42,7 +42,11 @@ heads · head_dim rounded up to 128 lanes), so a block is ``(block_size,
 width)`` dense — no ``(heads, head_dim)`` minor dimensions for the
 device to tile and pad, and no reshape of the pool in front of the
 kernel (which would be a copy of it).  Per-head scores come from the
-flat rows by spreading the query block-diagonally (``_paged_kernel``).
+flat rows by spreading the query block-diagonally (``_paged_kernel``),
+or, where key/value heads are shared by a group of query heads or a
+window bounds a lane's reach, by a product of the group's queries and
+each key/value head's columns (``_grouped_kernel``; ``kv_heads=``,
+``window=``): one kernel, one name, ``paged_decode_attn``.
 
 Scope: the kernel is a SINGLE-SHARD program.  ``supported()`` gates on
 one device — a dp-sharded pool or tp-sharded heads would need a
@@ -83,29 +87,45 @@ def supported(mesh=None) -> bool:
 # the grid: one step for each (lane, block group) that holds rows
 # ---------------------------------------------------------------------------
 
-def lane_steps(lengths, span: int, steps_per_lane: int):
+def lane_steps(lengths, span: int, steps_per_lane: int, starts=None):
     """The decode kernels' grid as a list: ``(lane, j, total)``.
 
     A lane attends to positions ``<= length``, so it takes ``n =
     length // span + 1`` steps of ``span`` rows (at most
     ``steps_per_lane``, the table's width; an idle lane at length 0
-    takes one, on the trash block).  ``lane`` and ``j`` are flat int32
-    arrays of the static length ``S * steps_per_lane``: step ``t`` of
-    the grid is group ``j[t]`` of lane ``lane[t]``, lanes in order and
-    each lane's groups in order; ``total = sum(n)`` is the grid's bound
-    and the tail past it is never visited.  Plain XLA on S integers: a
-    decode program builds it once and hands it to every layer's call.
+    takes one, on the trash block); with ``starts`` (S,), the first
+    position each lane attends to (a window's), its steps begin at
+    ``start // span`` and ``n`` is that many fewer.  ``lane`` and ``j``
+    are flat int32 arrays of the static length ``S * steps_per_lane``:
+    step ``t`` of the grid is group ``j[t]`` of lane ``lane[t]``, lanes
+    in order and each lane's groups in order; ``total = sum(n)`` is the
+    grid's bound and the tail past it is never visited.  Plain XLA on S
+    integers: a decode program builds it once and hands it to every
+    layer's call.
     """
     lengths = jnp.asarray(lengths, jnp.int32)
     s = lengths.shape[0]
     n = jnp.minimum(lengths // span + 1, steps_per_lane)
+    if starts is not None:
+        first = jnp.asarray(starts, jnp.int32) // span
+        n = n - first
     t = jnp.arange(s * steps_per_lane, dtype=jnp.int32)
     # (T, S): lane i lies wholly before step t.  One compare and two
     # sums, no search: a search is a loop on the device
     before = t[:, None] >= jnp.cumsum(n)[None, :]
     lane = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), s - 1)
     j = t - jnp.sum(jnp.where(before, n[None, :], 0), axis=1)
+    if starts is not None:
+        j = j + jnp.take(first, lane)
     return lane, j, jnp.sum(n)
+
+
+def window_starts(lengths, window: Optional[int]):
+    """The first position each lane attends to: ``length - window + 1``
+    (not below 0) under a window, None without one."""
+    if window is None:
+        return None
+    return jnp.maximum(jnp.asarray(lengths, jnp.int32) - window + 1, 0)
 
 
 MLA_GROUP = 16  # blocks a grid step of ``mla_paged_decode``
@@ -119,15 +139,17 @@ def mla_grid(block_size: int, table_blocks: int, group: int = MLA_GROUP):
     return g * int(block_size), -(-int(table_blocks) // g)
 
 
-def _grid(tables, lengths, span, steps_per_lane, steps):
+def _grid(tables, lengths, span, steps_per_lane, steps, window=None):
     """``(grid, scalar-prefetch operands)`` of a decode kernel: the
     list's length, and ``lane, j, tables, lengths``.  ``steps`` is the
     caller's ``lane_steps`` (a decode program's one list for all its
-    layers) or None to build it here."""
+    layers of a kind) or None to build it here."""
     lengths = jnp.minimum(
         jnp.asarray(lengths, jnp.int32), steps_per_lane * span - 1)
-    lane, j, total = (lane_steps(lengths, span, steps_per_lane)
-                      if steps is None else steps)
+    lane, j, total = (
+        lane_steps(lengths, span, steps_per_lane,
+                   window_starts(lengths, window))
+        if steps is None else steps)
     if lane.shape != (tables.shape[0] * steps_per_lane,) \
             or j.shape != lane.shape:
         raise ValueError(
@@ -148,19 +170,71 @@ def _head_columns(h, hd, w):
     return (col >= lo) & (col < lo + hd)
 
 
+def _grouped_kernel(lane_ref, j_ref, tbl_ref, len_ref, q_ref, k_ref, v_ref,
+                    o_ref, m_ref, d_ref, acc_ref, *, bs, scale, hd, window):
+    """One lane's online softmax over its block stream, a group of
+    query heads to each key/value head: for key/value head ``k`` the
+    group's queries (G, hd) against the block's ``hd`` columns of that
+    head, ``(G, hd) × (hd, bs)``, and the probabilities against the
+    same columns of the values, ``(G, bs) × (bs, hd)``; operands in the
+    pool's dtype, float32 accumulation.  ``window``: the lane's steps
+    begin at the block of its first position (``lane_steps``' start)
+    and positions before it are masked."""
+    t = pl.program_id(0)
+    s_idx, j = lane_ref[t], j_ref[t]
+    length = len_ref[s_idx]
+    first = (0 if window is None
+             else jnp.maximum(length - window + 1, 0))
+
+    @pl.when(j == first // bs)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        d_ref[...] = jnp.zeros_like(d_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    kv, g = m_ref.shape[0], m_ref.shape[1]
+    pos = j * bs + lax.broadcasted_iota(jnp.int32, (g, bs), 1)
+    seen = pos <= length
+    if window is not None:
+        seen &= pos >= first
+    for k in range(kv):
+        cols = slice(k * hd, (k + 1) * hd)
+        kb, vb = k_ref[:, cols], v_ref[:, cols]
+        s = lax.dot_general(
+            q_ref[0, k].astype(kb.dtype), kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (G, bs)
+        s = jnp.where(seen, s, _NEG_INF)
+        m_prev = m_ref[k]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        d_ref[k] = d_ref[k] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[k] = acc_ref[k] * corr + jnp.dot(
+            p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
+        m_ref[k] = m_new
+
+    @pl.when(j == length // bs)  # the lane's last step
+    def _fin():
+        o_ref[0] = (acc_ref[...] / d_ref[...]).astype(o_ref.dtype)
+
+
 def _paged_kernel(lane_ref, j_ref, tbl_ref, len_ref, q_ref, k_ref, v_ref,
                   *refs, bs, scale, hd, quant):
-    """One lane's online softmax over its block stream, on flat rows.
+    """One lane's online softmax over its block stream, on flat rows,
+    every head its own key/value head.
 
     A pool row holds the heads side by side, so per-head scores come
     from two plain products a block and no reshape that splits lanes:
     the lane's query is spread block-diagonally (``qh`` (H, W): ``q_h``
     in head ``h``'s ``hd`` columns, zeros elsewhere), ``qh · Kᵀ`` is the
     (H, bs) scores, and ``p · V`` is (H, W), of which head ``h``'s own
-    columns are kept at the end.  int8 payloads: the per-row/per-head
-    scales multiply the scores (K) and the probabilities (V) — the
-    same numbers as dequantizing the block first, since row ``h`` of
-    either product only keeps head ``h``'s columns.
+    columns are kept at the end: one product of H rows, where a product
+    a head would be H products of one row over ``hd``-column slices
+    (64 at GPT-2 XL's heads, half a lane tile).  int8 payloads: the
+    per-row/per-head scales multiply the scores (K) and the
+    probabilities (V) — the same numbers as dequantizing the block
+    first, since row ``h`` of either product only keeps head ``h``'s
+    columns.
     """
     if quant:
         ks_ref, vs_ref, o_ref, qh_ref, m_ref, d_ref, acc_ref = refs
@@ -225,11 +299,16 @@ def paged_decode_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     steps=None,
+    kv_heads: Optional[int] = None,
+    window: Optional[int] = None,
     interpret: Optional[bool] = None,
 ):
     """softmax(q·Kᵀ·scale)·V over each lane's paged K/V, one layer.
 
     - ``q`` (S, H, hd): the decode tick's single query per lane.
+    - ``kv_heads``: the pool's key/value heads, each read by a group of
+      ``H // kv_heads`` query heads (query head ``h`` by head ``h //
+      group``); None: ``H``, a head each.
     - ``k_pool``/``v_pool`` (R, W >= H·hd): the flat row pool for this
       layer (R = n_blocks · block_size), one row a token, ``[head 0 |
       head 1 | … | padding]``, fp32/compute dtype or int8.  (``W`` a
@@ -244,27 +323,42 @@ def paged_decode_attention(
       call, exactly like the XLA path).
     - ``k_scale``/``v_scale`` (R, H) fp32: required when the pools are
       int8 — per-row/per-head dequant scales.
-    - ``steps``: ``lane_steps(lengths, block_size, NT)`` where the
-      caller has built it for several calls; built here when None.
+    - ``window``: a lane at ``length`` attends to the positions
+      ``length - window < p <= length`` alone, and its steps begin at
+      the block that holds the first of them; None: every position.
+      The table says where the positions live, so a per-lane ring of
+      ``R`` blocks is read through a table whose column ``c`` holds
+      ring block ``c mod R`` (``serving/latent.py``).
+    - ``steps``: ``lane_steps(lengths, block_size, NT, starts)`` (the
+      starts ``window_starts(lengths, window)``) where the caller has
+      built it for several calls; built here when None.
 
     A grid step fetches one ``(block_size, W)`` block of each pool
     through the table-driven index map, and the grid holds a lane's
     resident blocks alone (``lane_steps``).  Returns fp32 (S, H, hd).
     Numerics contract (tier-1 pinned): allclose to the XLA gather path
-    on both pool dtypes.
+    (``paged_decode_xla``) on both pool dtypes; a head a key/value head
+    without a window is the ungrouped kernel's arithmetic as it was.
     """
     s, h, hd = q.shape
     w = int(k_pool.shape[1])
     nt = int(tables.shape[1])
     bs = int(block_size)
+    kv = h if kv_heads is None else int(kv_heads)
     quant = k_pool.dtype == jnp.int8
     if quant and (k_scale is None or v_scale is None):
         raise ValueError("int8 pools need k_scale/v_scale")
-    if k_pool.ndim != 2 or w < h * hd:
+    if h % kv:
+        raise ValueError(f"{h} query heads do not divide into {kv} "
+                         "key/value heads")
+    if k_pool.ndim != 2 or w < kv * hd:
         raise ValueError(
-            f"pools are (rows, width >= heads * head_dim = {h * hd}), "
+            f"pools are (rows, width >= heads * head_dim = {kv * hd}), "
             f"got {k_pool.shape}"
         )
+    if (kv != h or window is not None) and quant:
+        raise ValueError("an int8 pool is read a head a key/value head "
+                         "and without a window")
     sc = resolve_scale(scale, hd)
 
     def _pool_map(t, lane, j, tbl, ln):
@@ -272,6 +366,38 @@ def paged_decode_attention(
 
     def _row_map(t, lane, j, tbl, ln):
         return (lane[t], 0, 0)
+
+    if kv != h or window is not None:
+        g = h // kv
+
+        def _group_map(t, lane, j, tbl, ln):
+            return (lane[t], 0, 0, 0)
+
+        grid, scalars = _grid(tables, lengths, bs, nt, steps, window)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,  # lane, j, tables, lengths
+            grid=grid,
+            in_specs=[pl.BlockSpec((1, kv, g, hd), _group_map),  # q
+                      pl.BlockSpec((bs, w), _pool_map),          # k block
+                      pl.BlockSpec((bs, w), _pool_map)],         # v block
+            out_specs=pl.BlockSpec((1, kv, g, hd), _group_map),
+            scratch_shapes=[
+                pltpu.VMEM((kv, g, 1), jnp.float32),   # running max
+                pltpu.VMEM((kv, g, 1), jnp.float32),   # running denominator
+                pltpu.VMEM((kv, g, hd), jnp.float32),  # output accumulator
+            ],
+        )
+        out = pl.pallas_call(
+            functools.partial(_grouped_kernel, bs=bs, scale=sc, hd=hd,
+                              window=window),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s, kv, g, hd), jnp.float32),
+            interpret=(not platform.on_tpu()) if interpret is None
+            else interpret,
+            # one kernel, one name: the profile's paged_decode_attn*
+            name="paged_decode_attn",
+        )(*scalars, q.reshape(s, kv, g, hd), k_pool, v_pool)
+        return out.reshape(s, h, hd)
 
     # the lane's query as one flat row, like the pool's
     q_flat = jnp.pad(q.reshape(s, 1, h * hd), ((0, 0), (0, 0), (0, w - h * hd)))
@@ -432,6 +558,35 @@ def mla_paged_decode(
         name="mla_paged_decode",
     )(*scalars, q_lat.astype(pool.dtype), q_rope.astype(pool.dtype),
       *([pool] * g))
+
+
+def paged_decode_xla(q, k_pool, v_pool, tables, lengths, *, block_size,
+                     scale=None, kv_heads=None, window=None):
+    """``paged_decode_attention`` (compute-dtype pools) over the
+    gathered image of every lane's table: plain XLA, the kernel's
+    oracle and the path of a sharded pool.  fp32 (S, H, hd)."""
+    s, h, hd = q.shape
+    kv = h if kv_heads is None else int(kv_heads)
+    bs = int(block_size)
+    rows = (tables[:, :, None] * bs + jnp.arange(bs)[None, None, :])
+    rows = rows.reshape(s, -1)
+
+    def image(pool):  # (S, T, kv, hd)
+        return jnp.take(pool, rows, axis=0)[..., :kv * hd].reshape(
+            s, rows.shape[1], kv, hd)
+
+    kc, vc = image(k_pool), image(v_pool)
+    f32 = dict(preferred_element_type=jnp.float32)
+    sc = jnp.einsum("skgd,stkd->skgt", q.reshape(s, kv, h // kv, hd).astype(
+        kc.dtype), kc, **f32) * resolve_scale(scale, hd)
+    at = jnp.arange(rows.shape[1])[None, :]
+    seen = at <= lengths[:, None]
+    if window is not None:
+        seen &= at > lengths[:, None] - window
+    prob = jax.nn.softmax(jnp.where(seen[:, None, None, :], sc, _NEG_INF),
+                          axis=-1)
+    return jnp.einsum("skgt,stkd->skgd", prob.astype(vc.dtype), vc,
+                      **f32).reshape(s, h, hd)
 
 
 def mla_decode_xla(q_lat, q_rope, pool, tables, lengths, *, block_size,

@@ -195,9 +195,10 @@ class MoeMlp(Layer):
     (+ FFN_shared(x))``, every token served by every expert it chose.
 
     ``gated=False``: ``relu(x W_in + b_in) W_out + b_out`` per expert
-    (the training models).  ``gated=True``: ``(silu(x W_gate) ⊙ (x
-    W_up)) W_down`` without biases, with ``n_shared`` shared experts of
-    the same width fused into one always-on FFN.
+    (the training models).  ``gated=True``: ``(act(x W_gate) ⊙ (x
+    W_up)) W_down`` without biases (``activation`` ``'silu'``, or
+    ``'relu'``: ReGLU), with ``n_shared`` shared experts of the same
+    width fused into one always-on FFN.
     """
 
     def __init__(
@@ -218,6 +219,7 @@ class MoeMlp(Layer):
         experts_held: Optional[Tuple[int, int]] = None,
         param_dtype=jnp.float32,
         w_init=he_normal,
+        activation: str = "silu",
     ):
         if not 1 <= top_k <= n_experts:
             raise ValueError(
@@ -235,6 +237,9 @@ class MoeMlp(Layer):
                 f"scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
         if n_shared and not gated:
             raise ValueError("a shared expert needs gated=True")
+        if activation not in ("silu", "relu"):
+            raise ValueError(
+                f"activation must be 'silu' or 'relu', got {activation!r}")
         self.n_experts = n_experts
         self.d_hidden = d_hidden
         self.top_k = top_k
@@ -266,6 +271,7 @@ class MoeMlp(Layer):
         self.route_scale = float(route_scale)
         self.gated = bool(gated)
         self.n_shared = int(n_shared)
+        self.activation = activation
         self.param_dtype = param_dtype
         self.w_init = w_init
 
@@ -325,7 +331,9 @@ class MoeMlp(Layer):
                          "moe_grouped_mm_gate")
                 u = _gmm(impl, x_rows, leaf("w_up"), plan,
                          "moe_grouped_mm_up")
-                h = (jax.nn.silu(g.astype(jnp.float32))
+                from theanompi_tpu.ops.attention import GATES
+
+                h = (GATES[self.activation](g.astype(jnp.float32))
                      * u.astype(jnp.float32)).astype(cd)
                 out = _gmm(impl, h, leaf("w_down"), plan,
                            "moe_grouped_mm_down")
@@ -342,21 +350,24 @@ class MoeMlp(Layer):
                 out = _tile_bias(out, leaf("b_out"), plan).astype(cd)
         return out
 
-    def forward(self, params, x, valid=None, impl: str = "xla"):
+    def forward(self, params, x, valid=None, impl: str = "xla",
+                route_x=None):
         """``(y (T, d), counts, scores)``: the part of the layer's output
         that the held experts contribute for the tokens ``x`` (T, d)
         (with ``ep_axis``: the whole output for this device's tokens),
         the tokens each held expert received, and the routing scores.
         ``valid`` (T,) bool leaves padding rows unrouted; ``impl``:
         ``'pallas'`` runs the grouped products as the kernel (serving on
-        one chip), ``'xla'`` as their plain, differentiable form."""
+        one chip), ``'xla'`` as their plain, differentiable form.
+        ``route_x`` (T, d): the rows the router reads, where they are
+        not ``x`` (a block that routes on its attention's input)."""
         if impl not in ("xla", "pallas"):
             raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
         cd = jnp.dtype(self.compute_dtype) if self.compute_dtype else x.dtype
         xc = x.astype(cd)
         with jax.named_scope("moe_router"):
             idx, w, scores = route(
-                xc, params["wg"], top_k=self.top_k, scoring=self.scoring,
+                xc if route_x is None else route_x.astype(cd), params["wg"], top_k=self.top_k, scoring=self.scoring,
                 bias=params.get("route_bias"), scale=self.route_scale)
         first, count = self.experts_held
         x_all, v_all = xc, valid
@@ -380,8 +391,8 @@ class MoeMlp(Layer):
             from theanompi_tpu.ops.attention import gated_ffn
 
             sp = params["shared"]
-            y = y + gated_ffn(xc, sp["w_gate"], sp["w_up"],
-                              sp["w_down"]).astype(jnp.float32)
+            y = y + gated_ffn(xc, sp["w_gate"], sp["w_up"], sp["w_down"],
+                              self.activation).astype(jnp.float32)
         return y.astype(x.dtype), plan.counts, scores
 
     def _aux(self, scores):

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -52,7 +53,7 @@ class DensePrograms:
     state's layout and the bodies of its two jitted programs."""
 
     latent = False  # the scheduler reports no latent_rows_* stats
-    recurrent = False  # every layer's past is rows in the pool
+    lane_state = None  # every layer's past is rows in the pool
 
     def __init__(self, engine):
         cfg = engine.model.config
@@ -148,6 +149,14 @@ class DensePrograms:
         if e.kv_dtype == "int8":
             row += self.n_heads * 4  # fp32 scale per (row, head)
         return 2 * e.n_layers * e.block_size * row
+
+    def decode_rows(self, lengths) -> dict:
+        """The key/value rows a decode call has to read for lanes at
+        ``lengths`` (the decoding lanes'): ``length + 1`` a lane in
+        every layer (as ``serving/latent.py``)."""
+        n = np.asarray(lengths, np.int64) + 1
+        return {"kv_rows_full": self.engine.n_layers * int(n.sum()),
+                "kv_rows_window": 0}
 
     # ---- forward pieces (numerics mirror ops.attention exactly) ----------
     def _weights(self, params):

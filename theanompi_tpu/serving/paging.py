@@ -100,7 +100,7 @@ from theanompi_tpu.serving.latent import LatentPrograms
 # a model's ``block`` -> the programs that serve it: the one place in
 # ``serving/`` that looks at a block's name.  A family is a class with
 # ``serving_params``, ``init_state``, ``block_bytes``, ``chunk_fn``,
-# ``decode_fn`` and the flags ``latent`` and ``recurrent``
+# ``decode_fn``, ``decode_rows``, the flag ``latent`` and ``lane_state``
 # (docs/serving.md, "Adding a block family").
 PROGRAMS = {"dense": DensePrograms, "latent_moe": LatentPrograms}
 
@@ -496,10 +496,11 @@ class PagedServingEngine:
         # the family's pool layout and program bodies; its constructor
         # refuses what the family cannot serve
         self.programs = PROGRAMS[block](self)
-        if self.programs.recurrent:
+        if self.programs.lane_state:
             # the blocks of a prefix stand for the layers that keep rows
-            # and not for the lanes' recurrent state (module docstring of
-            # serving/latent.py): no request is handed another's blocks
+            # and not for what lives a lane (recurrent state, window
+            # rings; module docstring of serving/latent.py): no request
+            # is handed another's blocks
             self.prefix_cache_enabled = False
         # `last_counters` is what the newest program call returned
         # beside its logits (None: a family without counters),
@@ -642,8 +643,9 @@ class PagedServingEngine:
         ``rows`` is a list of up to ``prefill_rows`` dicts with keys
         ``tokens`` (this lane's chunk, 1..prefill_chunk ints), ``p0``
         (its absolute start position), ``table`` (the lane's block
-        ids) and, for a model with per-lane recurrent state, ``lane``
-        (whose state the chunk continues; cleared at ``p0 == 0``).
+        ids) and, for a model with per-lane state (``lane_state``),
+        ``lane`` (whose state the chunk continues; a recurrent state is
+        cleared at ``p0 == 0``).
         Returns ``(state, logits)`` — logits row i belongs to
         rows[i] (meaningful only for the lane's FINAL chunk)."""
         if not rows or len(rows) > self.prefill_rows:
@@ -655,6 +657,7 @@ class PagedServingEngine:
         p_ = self.prefill_rows
         # boundary span: the host arrays and the jitted call; its counts
         # say how much of what the program computes is padding
+        programs = self.programs
         with obs.span("prefill_chunk_dispatch", boundary=True,
                       rows=len(rows), bucket=c, rows_computed=p_,
                       computed_tokens=p_ * c) as span:
@@ -663,11 +666,11 @@ class PagedServingEngine:
             p0 = np.zeros((p_,), np.int32)
             true_len = np.zeros((p_,), np.int32)
             active = np.zeros((p_,), bool)
-            recurrent = self.programs.recurrent
+            per_lane = bool(programs.lane_state)
             # a padding row's lane is none (n_slots): written nowhere
             lanes = np.full((p_,), self.n_slots, np.int32)
             for i, r in enumerate(rows):
-                if recurrent:
+                if per_lane:
                     if not 0 <= int(r["lane"]) < self.n_slots:
                         raise ValueError(
                             f"row {i}: lane {r['lane']} outside "
@@ -681,6 +684,10 @@ class PagedServingEngine:
                 active[i] = True
             useful = int(true_len.sum())
             span.set(useful_tokens=useful)
+            if getattr(programs, "window", None) is not None:
+                span.set(kv_rows_window=sum(
+                    programs.prefill_window_rows(int(r["p0"]), len(r["tokens"]))
+                    for r in rows))
             smetrics.PREFILL_CHUNKS.inc(bucket=str(c))
             smetrics.PREFILL_TOKENS.inc(useful)
             state, logits = self._call(
@@ -688,7 +695,7 @@ class PagedServingEngine:
                 host_input(tokens), host_input(tables),
                 host_input(p0), host_input(true_len),
                 host_input(active),
-                *((host_input(lanes),) if recurrent else ()),
+                *((host_input(lanes),) if per_lane else ()),
             )
             self.last_span = span
         return state, logits
@@ -706,10 +713,11 @@ class PagedServingEngine:
         sampled acceptance draws with the request's own per-index keys.
         C is pinned by the caller (spec_k + 1) — ONE compiled program
         across every acceptance/rollback outcome."""
-        if self.programs.recurrent:
+        if self.programs.lane_state:
             raise ValueError(
-                "a model with recurrent layers cannot verify drafts: a "
-                "rejected token cannot be rolled back out of a lane's state")
+                f"a model with {self.programs.lane_state} cannot verify "
+                "drafts: a rejected token cannot be rolled back out of a "
+                "lane's state")
         smetrics.SPEC_VERIFY_DISPATCHES.inc()
         with obs.span("spec_verify_dispatch", rows=int(np.sum(active)),
                       width=int(np.asarray(tokens).shape[1])):

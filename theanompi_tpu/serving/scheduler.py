@@ -218,19 +218,24 @@ class ContinuousBatchingScheduler:
                 self.prefix = PrefixCache(self.pool)
         else:
             self.prefix = None
-        if engine.programs.recurrent:
+        lane_state = engine.programs.lane_state
+        if lane_state:
+            # a prefix cannot be rebuilt from blocks alone
             if int(spec_k):
                 raise ValueError(
                     "spec_k>0 needs a model whose past is rows alone: a "
-                    "rejected draft cannot be rolled back out of a "
-                    "recurrent state")
+                    "rejected draft cannot be rolled back out of "
+                    f"{lane_state}")
+            self.stats["prefix_reuse"] = f"off: {lane_state}"
+        if engine.programs.latent and engine.programs.recurrent:
             # per-lane state beside the pool (serving/latent.py): its
-            # size, the first chunks that cleared a lane, and why no
-            # request is handed a cached prefix
+            # size and the first chunks that cleared a lane
             self.stats["recurrent_state_bytes"] = (
                 engine.programs.recurrent_state_bytes())
             self.stats["recurrent_lanes_reset"] = 0
-            self.stats["prefix_reuse"] = "off: recurrent state"
+        if engine.programs.latent and engine.programs.n_window:
+            # the window layers' rings, beside the weights' bytes
+            self.stats["window_ring_bytes"] = engine.programs.ring_bytes()
         self.state = engine.init_state()
         if engine.programs.latent:
             # the latent pool's size and fill, in rows (= tokens)
@@ -683,6 +688,9 @@ class ContinuousBatchingScheduler:
             attn_steps=int(
                 (self._lengths // programs.attn_span + 1).sum()),
             attn_steps_table=len(self.slots) * programs.attn_steps,
+            # the key/value rows the attention must read, by the reach
+            # of the layers
+            **programs.decode_rows(self._lengths[decoding]),
         ) as span:
             self.state, logits = self.engine.decode_step_paged(
                 self.params, self.state, self._tokens,
