@@ -257,6 +257,53 @@ def test_scheduler_serves_with_prefix_reuse_off_and_counts_rows(ref, model):
         ContinuousBatchingScheduler(eng, spec_k=2, draft_engine=eng)
 
 
+def test_decode_step_span_counts_the_grouped_kernels_groups(ref, model,
+                                                             monkeypatch):
+    """A table twice the grouped kernel's group wide (at 32 a group,
+    blocks of one row under 64 columns and a ring of 31 blocks): the
+    kernel attends to ``GQA_GROUP``
+    blocks a grid step, two steps a full lane.  ``attn_steps`` is the
+    sum over lanes of ``length // span + 1`` at the lengths the decode
+    call is handed, ``attn_steps_table`` lanes × 2; the tokens served
+    through the interpreted kernel are the reference's (a lane past the
+    first span takes two steps, its window's first position inside the
+    first)."""
+    from theanompi_tpu import observability as obs
+    from theanompi_tpu.ops.pallas_paged import GQA_GROUP
+
+    bs = 64 // (2 * GQA_GROUP)
+    eng = _engine(model, block_size=bs, paged_attn="pallas")
+    assert eng.blocks_per_seq == 2 * GQA_GROUP
+    span = GQA_GROUP * bs
+    assert (eng.programs.attn_span, eng.programs.attn_steps) == (span, 2)
+    handed = []
+    decode = eng.decode_step_paged
+
+    def record(params, state, tokens, tables, lengths, active):
+        handed.append(np.array(lengths))
+        return decode(params, state, tokens, tables, lengths, active)
+
+    monkeypatch.setattr(eng, "decode_step_paged", record)
+    sched = ContinuousBatchingScheduler(eng)
+    reqs = [Request(id="long", prompt=_tokens(40, 21), max_new_tokens=4),
+            Request(id="short", prompt=_tokens(5, 22), max_new_tokens=4)]
+    t0 = time.perf_counter()
+    for r in reqs:
+        sched.submit(r)
+    done = sched.run()
+    for r in reqs:
+        want = _reference(ref, model, r.prompt + done[r.id][:-1])
+        assert done[r.id] == np.argmax(
+            want[len(r.prompt) - 1:], axis=-1).tolist(), r.id
+    spans = [s["args"] for s in obs.get_tracer().boundary_spans(t0)
+             if s["name"] == "decode_step"]
+    assert len(spans) == len(handed) > 0
+    for a, lengths in zip(spans, handed):
+        assert a["attn_steps"] == int((lengths // span + 1).sum())
+        assert a["attn_steps_table"] == 3 * 2
+    assert max(a["attn_steps"] for a in spans) >= 4  # a lane of two steps
+
+
 @pytest.mark.parametrize("lengths,full,window", [
     ([0], 1, 3), ([W - 2], W - 1, 3 * (W - 1)), ([W - 1], W, 3 * W),
     ([W, 40], W + 1 + 41, 3 * 2 * W)])

@@ -214,17 +214,22 @@ def _paged_cases(size):
 def _grouped_cases(size):
     """The same kernel with key/value heads shared by groups of query
     heads (SmallThinker's 28 over 4, heads of 128, in bfloat16), over
-    the shared table, and over per-lane rings under a window."""
+    the shared table, and over per-lane rings under a window.  A step
+    attends to a group of blocks (``gqa_grid``); the ``_edges``,
+    ``_full`` and ``_one_long`` cases put a table of several groups at
+    the group's edges (``ragged_lengths``, an idle lane among them),
+    their rings of 8 (tiny: 3) blocks read each block several times in
+    a step, and a window's first position falls inside a group."""
     from theanompi_tpu.ops.pallas_paged import (
-        paged_decode_attention, paged_decode_xla,
+        gqa_grid, paged_decode_attention, paged_decode_xla,
     )
 
-    s, h, kv, hd, bs, nt, ring, window = (
-        (32, 28, 4, 128, 32, 32, 8, 200) if size == "real"
-        else (3, 6, 2, 8, 4, 5, 3, 9))
-    nb = s * ring + 1  # the rings of every lane and the trash block
+    h, kv, hd, bs, ring, window = (
+        (28, 4, 128, 32, 8, 200) if size == "real" else (6, 2, 8, 4, 3, 9))
 
-    def make(rings):
+    def make(rings, s, nt, kind=None):
+        nb = s * ring + 1  # the rings of every lane and the trash block
+
         def make_args(key):
             kq, kk, kv_, kt, kl = jax.random.split(key, 5)
             bf = jnp.bfloat16
@@ -237,6 +242,10 @@ def _grouped_cases(size):
                           + jnp.arange(nt)[None, :] % ring).astype(jnp.int32)
             else:
                 tables = jax.random.randint(kt, (s, nt), 1, nb, jnp.int32)
+            if kind is not None:
+                span = gqa_grid(bs, nt)[0]
+                return q, kp, vp, tables, jnp.asarray(
+                    ragged_lengths(kind, s, span, nt * bs))
             lengths = jax.random.randint(kl, (s,), 0, nt * bs, jnp.int32)
             lengths = lengths.at[0].set(0).at[-1].set(nt * bs - 1)
             return q, kp, vp, tables, lengths
@@ -247,12 +256,24 @@ def _grouped_cases(size):
             q, kp, vp, tables, lengths, block_size=bs, kv_heads=kv,
             window=win)
 
+    s, nt = (32, 32) if size == "real" else (3, 5)
+    # a table of several groups and a ragged last one (real: 80 columns
+    # in groups of 32; tiny: 72)
+    s_long, nt_long = (32, 80) if size == "real" else (5, 72)
     return [
-        KernelCase(name, make(rings), run(paged_decode_attention, win),
+        KernelCase(name, make(rings, s, nt), run(paged_decode_attention, win),
                    run(paged_decode_xla, win), atol=2e-2, rtol=2e-2)
         for name, rings, win in (("paged_decode_gqa", False, None),
                                  ("paged_decode_gqa_window_ring", True,
                                   window))
+    ] + [
+        KernelCase(f"{name}_{kind}", make(rings, s_long, nt_long, kind),
+                   run(paged_decode_attention, win),
+                   run(paged_decode_xla, win), atol=2e-2, rtol=2e-2)
+        for name, rings, win in (("paged_decode_gqa", False, None),
+                                 ("paged_decode_gqa_window_ring", True,
+                                  window))
+        for kind in ("edges", "full", "one_long")
     ]
 
 

@@ -5,12 +5,13 @@ token per sequence against K/V gathered **block by block from the
 paged pool inside the kernel**.  The lane's block table is a
 scalar-prefetch argument, so the BlockSpec ``index_map`` reads
 ``table[lane, j]`` and a grid step DMAs one pool block into VMEM
-(``mla_paged_decode``: a group of 16) — the XLA path instead
-materializes the whole gathered ``(S, t_pad, H, hd)`` image in HBM
-first (and, on a dp-sharded pool, pays a GSPMD cross-shard gather for
-it).  Softmax runs as the online recurrence over the block stream
-(same max/denominator carry as ``pallas_flash``), so nothing quadratic
-in the table length ever leaves VMEM.
+(``mla_paged_decode``: a group of 16; the grouped-query form: of 32) —
+the XLA path instead materializes the whole gathered ``(S, t_pad, H,
+hd)`` image in HBM first (and, on a dp-sharded pool, pays a GSPMD
+cross-shard gather for it).  Softmax runs as the online recurrence
+over the block stream (same max/denominator carry as
+``pallas_flash``), so nothing quadratic in the table length ever leaves
+VMEM.
 
 **The grid is the lanes' resident blocks, not the table's width**
 (``lane_steps``): one flat list of (lane, block group) pairs, lanes in
@@ -129,6 +130,7 @@ def window_starts(lengths, window: Optional[int]):
 
 
 MLA_GROUP = 16  # blocks a grid step of ``mla_paged_decode``
+GQA_GROUP = 32  # blocks a grid step of the grouped ``paged_decode_attn``
 
 
 def mla_grid(block_size: int, table_blocks: int, group: int = MLA_GROUP):
@@ -137,6 +139,13 @@ def mla_grid(block_size: int, table_blocks: int, group: int = MLA_GROUP):
     steps (what ``lane_steps`` takes)."""
     g = max(1, min(int(group), int(table_blocks)))
     return g * int(block_size), -(-int(table_blocks) // g)
+
+
+def gqa_grid(block_size: int, table_blocks: int):
+    """The same of ``paged_decode_attention`` with ``kv_heads`` or a
+    ``window`` (``GQA_GROUP`` blocks a step, fewer under a narrower
+    table)."""
+    return mla_grid(block_size, table_blocks, GQA_GROUP)
 
 
 def _grid(tables, lengths, span, steps_per_lane, steps, window=None):
@@ -158,6 +167,23 @@ def _grid(tables, lengths, span, steps_per_lane, steps, window=None):
     return (total,), (lane, j, jnp.asarray(tables, jnp.int32), lengths)
 
 
+def _step_blocks(lane, j, tables, lengths, group, block_size):
+    """The pool block of each of a step's ``group`` blocks, one flat
+    int32 array (step ``t``'s block ``k`` at ``t · group + k``): column
+    ``j · group + k`` of the lane's table, or past the lane's last block
+    its last (whose positions past the length are masked).  Plain XLA
+    once a call, so a step's index maps (two a block) read one scalar
+    each where they would read four: reading the table themselves, they
+    held a step longer than its bytes did (``PERF.md``, the grouped
+    kernel alone)."""
+    last = jnp.take(lengths, lane) // block_size
+    col = jnp.minimum(
+        j[:, None] * group + jnp.arange(group, dtype=jnp.int32)[None, :],
+        last[:, None])
+    return jnp.take(tables.reshape(-1),
+                    lane[:, None] * tables.shape[1] + col).reshape(-1)
+
+
 # ---------------------------------------------------------------------------
 # kernel body
 # ---------------------------------------------------------------------------
@@ -170,39 +196,45 @@ def _head_columns(h, hd, w):
     return (col >= lo) & (col < lo + hd)
 
 
-def _grouped_kernel(lane_ref, j_ref, tbl_ref, len_ref, q_ref, k_ref, v_ref,
-                    o_ref, m_ref, d_ref, acc_ref, *, bs, scale, hd, window):
-    """One lane's online softmax over its block stream, a group of
-    query heads to each key/value head: for key/value head ``k`` the
-    group's queries (G, hd) against the block's ``hd`` columns of that
-    head, ``(G, hd) × (hd, bs)``, and the probabilities against the
-    same columns of the values, ``(G, bs) × (bs, hd)``; operands in the
-    pool's dtype, float32 accumulation.  ``window``: the lane's steps
-    begin at the block of its first position (``lane_steps``' start)
-    and positions before it are masked."""
+def _grouped_kernel(lane_ref, j_ref, ids_ref, len_ref, q_ref, *refs, span,
+                    scale, hd, window):
+    """One lane's online softmax over its stream of block groups, a
+    group of query heads to each key/value head: the step's blocks
+    (``refs``: the key blocks, then the value blocks) side by side as
+    ``span`` rows, and for key/value head ``k`` the group's queries
+    (G, hd) against those rows' ``hd`` columns of that head, ``(G, hd)
+    × (hd, span)``, and the probabilities against the same columns of
+    the values, ``(G, span) × (span, hd)``; operands in the pool's
+    dtype, float32 accumulation.  ``window``: the lane's steps begin at
+    the group that holds its first position (``lane_steps``' start) and
+    positions before it are masked."""
+    n = (len(refs) - 4) // 2
+    k_refs, v_refs = refs[:n], refs[n:2 * n]
+    o_ref, m_ref, d_ref, acc_ref = refs[2 * n:]
     t = pl.program_id(0)
     s_idx, j = lane_ref[t], j_ref[t]
     length = len_ref[s_idx]
     first = (0 if window is None
              else jnp.maximum(length - window + 1, 0))
 
-    @pl.when(j == first // bs)
+    @pl.when(j == first // span)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         d_ref[...] = jnp.zeros_like(d_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     kv, g = m_ref.shape[0], m_ref.shape[1]
-    pos = j * bs + lax.broadcasted_iota(jnp.int32, (g, bs), 1)
+    pos = j * span + lax.broadcasted_iota(jnp.int32, (g, span), 1)
     seen = pos <= length
     if window is not None:
         seen &= pos >= first
     for k in range(kv):
         cols = slice(k * hd, (k + 1) * hd)
-        kb, vb = k_ref[:, cols], v_ref[:, cols]
+        kb = jnp.concatenate([r[:, cols] for r in k_refs], axis=0)
+        vb = jnp.concatenate([r[:, cols] for r in v_refs], axis=0)
         s = lax.dot_general(
             q_ref[0, k].astype(kb.dtype), kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (G, bs)
+            preferred_element_type=jnp.float32) * scale  # (G, span)
         s = jnp.where(seen, s, _NEG_INF)
         m_prev = m_ref[k]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -213,7 +245,7 @@ def _grouped_kernel(lane_ref, j_ref, tbl_ref, len_ref, q_ref, k_ref, v_ref,
             p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
         m_ref[k] = m_new
 
-    @pl.when(j == length // bs)  # the lane's last step
+    @pl.when(j == length // span)  # the lane's last step
     def _fin():
         o_ref[0] = (acc_ref[...] / d_ref[...]).astype(o_ref.dtype)
 
@@ -329,13 +361,20 @@ def paged_decode_attention(
       The table says where the positions live, so a per-lane ring of
       ``R`` blocks is read through a table whose column ``c`` holds
       ring block ``c mod R`` (``serving/latent.py``).
-    - ``steps``: ``lane_steps(lengths, block_size, NT, starts)`` (the
-      starts ``window_starts(lengths, window)``) where the caller has
-      built it for several calls; built here when None.
+    - ``steps``: ``lane_steps(lengths, block_size, NT)`` where the
+      caller has built it for several calls; built here when None.
+      With ``kv_heads`` or a ``window``: ``lane_steps(lengths,
+      *gqa_grid(block_size, NT), window_starts(lengths, window))``.
 
     A grid step fetches one ``(block_size, W)`` block of each pool
     through the table-driven index map, and the grid holds a lane's
-    resident blocks alone (``lane_steps``).  Returns fp32 (S, H, hd).
+    resident blocks alone (``lane_steps``).  With ``kv_heads`` or a
+    ``window`` a step attends to ``GQA_GROUP`` blocks (``gqa_grid``),
+    each pool handed to the call once for each, as
+    ``mla_paged_decode`` reads its pool, and each block's id read from
+    one list built beside the steps (``_step_blocks``); a window lane's
+    first step may begin before its first position, and those rows are
+    read and masked.  Returns fp32 (S, H, hd).
     Numerics contract (tier-1 pinned): allclose to the XLA gather path
     (``paged_decode_xla``) on both pool dtypes; a head a key/value head
     without a window is the ungrouped kernel's arithmetic as it was.
@@ -369,17 +408,29 @@ def paged_decode_attention(
 
     if kv != h or window is not None:
         g = h // kv
+        span, nj = gqa_grid(bs, nt)
+        n = span // bs
+        tables = jnp.pad(jnp.asarray(tables, jnp.int32),
+                         ((0, 0), (0, nj * n - nt)))
 
-        def _group_map(t, lane, j, tbl, ln):
+        def _block_map(k):
+            def index(t, lane, j, ids, ln):
+                return (ids[t * n + k], 0)
+            return index
+
+        def _group_map(t, lane, j, ids, ln):
             return (lane[t], 0, 0, 0)
 
-        grid, scalars = _grid(tables, lengths, bs, nt, steps, window)
+        grid, (lane, j, tables, lengths) = _grid(tables, lengths, span, nj,
+                                                 steps, window)
+        scalars = (lane, j, _step_blocks(lane, j, tables, lengths, n, bs),
+                   lengths)
+        blocks = [pl.BlockSpec((bs, w), _block_map(k)) for k in range(n)]
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,  # lane, j, tables, lengths
+            num_scalar_prefetch=4,  # lane, j, the steps' blocks, lengths
             grid=grid,
-            in_specs=[pl.BlockSpec((1, kv, g, hd), _group_map),  # q
-                      pl.BlockSpec((bs, w), _pool_map),          # k block
-                      pl.BlockSpec((bs, w), _pool_map)],         # v block
+            in_specs=[pl.BlockSpec((1, kv, g, hd), _group_map)]  # q
+            + blocks + blocks,                                   # k, v
             out_specs=pl.BlockSpec((1, kv, g, hd), _group_map),
             scratch_shapes=[
                 pltpu.VMEM((kv, g, 1), jnp.float32),   # running max
@@ -388,7 +439,7 @@ def paged_decode_attention(
             ],
         )
         out = pl.pallas_call(
-            functools.partial(_grouped_kernel, bs=bs, scale=sc, hd=hd,
+            functools.partial(_grouped_kernel, span=span, scale=sc, hd=hd,
                               window=window),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((s, kv, g, hd), jnp.float32),
@@ -396,7 +447,8 @@ def paged_decode_attention(
             else interpret,
             # one kernel, one name: the profile's paged_decode_attn*
             name="paged_decode_attn",
-        )(*scalars, q.reshape(s, kv, g, hd), k_pool, v_pool)
+        )(*scalars, q.reshape(s, kv, g, hd), *([k_pool] * n),
+          *([v_pool] * n))
         return out.reshape(s, h, hd)
 
     # the lane's query as one flat row, like the pool's

@@ -268,7 +268,7 @@ class LatentPrograms:
         # lane's steps
         self.attn_span, self.attn_steps = (
             pallas_paged.mla_grid(bs, engine.blocks_per_seq) if self.n_latent
-            else (bs, engine.blocks_per_seq))
+            else pallas_paged.gqa_grid(bs, engine.blocks_per_seq))
         self.row_width = _lanes(self.attn.row_dim) if self.attn else 0
         self.kv_width = _lanes(self.grouped.width) if self.grouped else 0
         # the rows' dtype: the compute dtype where the model names one,
@@ -551,7 +551,8 @@ class LatentPrograms:
                 attend = functools.partial(
                     pallas_paged.paged_decode_attention,
                     steps=pallas_paged.lane_steps(
-                        lengths, bs, e.blocks_per_seq, starts))
+                        lengths, *pallas_paged.gqa_grid(bs, e.blocks_per_seq),
+                        starts))
             else:
                 attend = pallas_paged.paged_decode_xla
             return lambda attn, q, k_pool, v_pool: attend(
