@@ -240,6 +240,31 @@ def test_served_tokens_are_the_references_and_the_spans_carry_the_counters(
 
 # ---- (c) the absorbed path against the expanded one --------------------------
 
+def test_a_tick_fetches_its_calls_counters_under_one_span(model):
+    """A latent model's programs return their experts' counters, which
+    the tick fetches once its pick has waited: one ``counters_fetch``
+    boundary span a decode tick, a child of the tick, completing every
+    call noted since the last fetch (a prefill call's too)."""
+    from theanompi_tpu import observability as obs
+
+    eng = PagedServingEngine(model, n_slots=2, max_len=48, block_size=4,
+                             prefill_chunk=8, prefill_rows=2)
+    sched = ContinuousBatchingScheduler(eng)
+    t0 = sched.clock()
+    sched.submit(Request(id="a", prompt=_tokens(13, 31), max_new_tokens=3))
+    sched.submit(Request(id="b", prompt=_tokens(4, 32), max_new_tokens=3))
+    sched.run()
+    spans = obs.get_tracer().boundary_spans(t0)
+    by_id = {s["id"]: s for s in spans}
+    fetches = [s for s in spans if s["name"] == "counters_fetch"]
+    decodes = [s for s in spans if s["name"] == "decode_step"]
+    assert len(fetches) == len(decodes) > 0
+    assert {by_id[f["parent"]]["name"] for f in fetches} == {"tick"}
+    completed = [s for s in spans if "tokens_routed" in s["args"]]
+    assert sum(f["args"]["calls"] for f in fetches) == len(completed)
+    assert len(completed) > len(decodes)  # the prefill calls' as well
+
+
 def test_absorbed_decode_equals_expanded_attention():
     attn = LB.LatentAttention(64, 4, 24, 16, 16, 8, 16, 1e-6, ROPE)
     ap = attn.init(jax.random.PRNGKey(0), jnp.float32)

@@ -209,7 +209,8 @@ def test_the_program_installs_the_profilers_annotation():
     # with no profiler session the annotation is inert; the span records
     with obs.span("tick", boundary=True, n=1):
         pass
-    assert obs.get_tracer().boundary_spans()[-1]["name"] == "tick"
+    assert [s["name"] for s in obs.get_tracer().boundary_spans()
+            if s["name"] != "gc"][-1] == "tick"
 
 
 def test_importable_and_recording_without_jax():
@@ -219,7 +220,8 @@ def test_importable_and_recording_without_jax():
         "with o.span('tick', boundary=True, n=1):\n"
         "    with o.span('ordinary'):\n"
         "        pass\n"
-        "assert [s['name'] for s in o.get_tracer().boundary_spans()] == ['tick']\n"
+        "assert [s['name'] for s in o.get_tracer().boundary_spans()\n"
+        "        if s['name'] != 'gc'] == ['tick']\n"
         "assert sys.modules.get('jax') is None\n"
         "print('OK')\n"
     )
@@ -286,7 +288,8 @@ def test_recorder_phases_are_boundary_children_of_train_iter(tracing_off):
         rec.start("comm")  # an ordinary phase: off with tracing off
         rec.end("comm")
     rec.print_train_info(1)
-    spans = tracing_off.boundary_spans()
+    # a collection may fall anywhere: its span is no phase of the step
+    spans = [s for s in tracing_off.boundary_spans() if s["name"] != "gc"]
     assert [s["name"] for s in spans] == ["wait", "calc", "train_iter", "print"]
     by = {s["name"]: s for s in spans}
     assert by["wait"]["parent"] == by["calc"]["parent"] == by["train_iter"]["id"]

@@ -15,7 +15,9 @@ three primitives:
   ``jax.profiler.TraceAnnotation`` once a jax-importing module has
   installed the hook, so ``jax.profiler.trace`` alone puts the
   program's spans into a profile.  ``get_tracer().boundary_spans(since,
-  until)`` returns them on ``time.perf_counter``.
+  until)`` returns them on ``time.perf_counter``.  The collector's
+  pauses are timed too: ``gc_totals()`` over every collection, and a
+  ``gc`` boundary span for each long one.
 - ``metrics`` — a registry of labeled counters / gauges / fixed-bucket
   histograms with atomic snapshot, JSON and Prometheus-text exposition.
 - ``flight``  — per-thread ring buffers of recent spans/events, dumped
@@ -76,6 +78,7 @@ from theanompi_tpu.observability.trace import (
     enable_request_tracking,
     flow_begin,
     flow_end,
+    gc_totals,
     get_tracer,
     install_annotation_hook,
     instant,
@@ -116,6 +119,7 @@ __all__ = [
     "flatten_counters",
     "flow_begin",
     "flow_end",
+    "gc_totals",
     "get_flight_recorder",
     "get_registry",
     "get_tracer",

@@ -50,6 +50,18 @@ Contracts:
 - **Parents** — a boundary span carries ``id`` and ``parent`` (the
   boundary span open on the same thread when it began; a thread-local
   stack), so a layer's self time is its duration minus its children's.
+- **The collector's pauses** — a ``gc.callbacks`` hook, installed once
+  at import for the module's tracer, times every collection: each adds
+  to two process-wide totals (``gc_totals()``: collections and
+  seconds), and a collection of the oldest generation, or any of
+  ``GC_SPAN_S`` or longer, is also a boundary span ``gc`` whose parent
+  is the boundary span open on the collecting thread (a pause between
+  ticks is a root).  A collection can start inside the tracer's own
+  lock, so the hook takes no lock: it leaves the finished span in a
+  queue that the tracer moves into its buffer the next time it records
+  or is read.  Only the oldest generation's opens the annotation (at
+  the collector's start), so a profile shows the long pauses; ``gc``
+  spans do not reach the span sinks.
 - **Bounded buffer** — a ``deque(maxlen=...)`` of finished spans; a
   week-long run keeps the newest window instead of OOMing the host.
 - **Track ids** — ``pid`` is the worker/process track (defaults to
@@ -88,6 +100,7 @@ Contracts:
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
@@ -104,10 +117,15 @@ BOUNDARY_SPANS = (
     # serving: ContinuousBatchingScheduler.step and what it calls
     "tick", "admit", "prefill", "prefill_chunk_dispatch", "decode_step",
     "spec_verify", "pick",
+    # serving, inside those: a call's inputs put, a pick's wait for the
+    # device, the experts' counters fetched
+    "host_inputs", "device_wait", "counters_fetch",
     # serving: a params tree becomes a scheduler's (paging.serving_params)
     "weights_relayout",
     # training: TpuModel.train_iter, the Recorder's phases, the print
     "train_iter", "wait", "calc", "print",
+    # any thread: a pause of the collector (the gc hook below)
+    "gc",
 )
 
 # ---- the boundary level's per-thread parent stack and annotation hook ----
@@ -130,6 +148,52 @@ def install_annotation_hook(factory: Optional[Callable[..., Any]]) -> None:
     modules pass ``jax.profiler.TraceAnnotation``; ``None`` removes it."""
     global _ANNOTATE
     _ANNOTATE = factory
+
+
+# ---- the collector's pauses: a gc.callbacks hook for the module's tracer ----
+GC_SPAN_S = 1e-3  # a younger generation's collection this long is a span
+_GC_OLDEST = 2
+_GC_TOTALS = [0, 0.0]  # every collection: how many, seconds
+_GC_OPEN = [0.0, None]  # the running collection: its start, its annotation
+# finished gc spans not yet in the buffer (bounded like it: a process
+# that records no span never drains them)
+_GC_DONE: deque = deque(maxlen=DEFAULT_BUFFER)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """Two clock reads a collection; a span (left in ``_GC_DONE``, since
+    a collection may start inside the tracer's lock) only for the oldest
+    generation or a pause of ``GC_SPAN_S`` or more."""
+    if phase == "start":
+        if info["generation"] == _GC_OLDEST and _ANNOTATE is not None:
+            ann = _GC_OPEN[1] = _ANNOTATE("gc", generation=_GC_OLDEST)
+            ann.__enter__()
+        _GC_OPEN[0] = time.perf_counter()
+        return
+    end = time.perf_counter()
+    start = _GC_OPEN[0]
+    _GC_TOTALS[0] += 1
+    _GC_TOTALS[1] += end - start
+    generation = info["generation"]
+    if generation != _GC_OLDEST and end - start < GC_SPAN_S:
+        return
+    ann = _GC_OPEN[1]
+    if ann is not None:
+        _GC_OPEN[1] = None
+        ann.__exit__(None, None, None)
+    stack = _stack()
+    _GC_DONE.append((
+        start, end,
+        {"generation": generation, "collected": info["collected"],
+         "uncollectable": info["uncollectable"]},
+        next(_IDS), stack[-1] if stack else None, threading.current_thread(),
+    ))
+
+
+def gc_totals() -> dict:
+    """What the collector has cost this process since the tracer's
+    import, every generation: ``{"collections": n, "seconds": s}``."""
+    return {"collections": _GC_TOTALS[0], "seconds": _GC_TOTALS[1]}
 
 
 class _NoopSpan:
@@ -294,6 +358,8 @@ class Tracer:
 
     def clear(self) -> None:
         with self._lock:
+            if self is _TRACER:
+                _GC_DONE.clear()
             self._buf.clear()
             self._tracks.clear()
             self.dropped = 0
@@ -546,8 +612,8 @@ class Tracer:
                 rec["events"].append(ev)
 
     # ---- recording -----------------------------------------------------
-    def _track_locked(self) -> int:
-        th = threading.current_thread()
+    def _track_locked(self, th: Optional[threading.Thread] = None) -> int:
+        th = th or threading.current_thread()
         entry = self._tracks.get(th.ident)
         if entry is None:
             entry = (len(self._tracks), th.name)
@@ -558,6 +624,16 @@ class Tracer:
         if len(self._buf) == self._buf.maxlen:
             self.dropped += 1
         self._buf.append(ev)
+
+    def _take_gc_locked(self) -> None:
+        """Move the collector's finished spans into the module's buffer."""
+        if self is not _TRACER:
+            return
+        while _GC_DONE:
+            start, end, args, span_id, parent, th = _GC_DONE.popleft()
+            ev = self._event("gc", start, end, args, span_id, parent)
+            ev["tid"] = self._track_locked(th)
+            self._push_locked(ev)
 
     def _us(self, t: float) -> float:
         return round((t - self._epoch) * 1e6, 3)
@@ -574,7 +650,7 @@ class Tracer:
         if self.enabled:
             self._record(name, start, end, args)
 
-    def _record(self, name, start, end, args, span_id=None, parent=None):
+    def _event(self, name, start, end, args, span_id, parent) -> dict:
         ev = {
             "ph": "X",
             "name": name,
@@ -587,7 +663,13 @@ class Tracer:
         if span_id is not None:
             ev["id"] = span_id
             ev["parent"] = parent
+        return ev
+
+    def _record(self, name, start, end, args, span_id=None, parent=None):
+        ev = self._event(name, start, end, args, span_id, parent)
         with self._lock:
+            if _GC_DONE:
+                self._take_gc_locked()
             tid = ev["tid"] = self._track_locked()
             if self._req_tracking:
                 # request buffers fill BEFORE the sampling drop: a
@@ -709,6 +791,7 @@ class Tracer:
     # ---- export --------------------------------------------------------
     def snapshot(self) -> List[dict]:
         with self._lock:
+            self._take_gc_locked()
             return list(self._buf)
 
     def boundary_spans(
@@ -719,6 +802,7 @@ class Tracer:
         tracer's own clock (``perf_counter``: what a driver around the
         program reads), ``id``, ``parent``, ``tid`` and ``args``."""
         with self._lock:
+            self._take_gc_locked()
             events = [ev for ev in self._buf
                       if ev["ph"] == "X" and "parent" in ev]
             epoch = self._epoch
@@ -1046,6 +1130,7 @@ def merge_raw_traces(named_traces, align_clocks: bool = True) -> dict:
 # ---------------------------------------------------------------------------
 
 _TRACER = Tracer()
+gc.callbacks.append(_on_gc)  # the collector's pauses go to _TRACER
 
 
 def get_tracer() -> Tracer:

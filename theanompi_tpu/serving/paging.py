@@ -655,48 +655,50 @@ class PagedServingEngine:
             )
         c = self.pick_chunk_bucket(max(len(r["tokens"]) for r in rows))
         p_ = self.prefill_rows
-        # boundary span: the host arrays and the jitted call; its counts
-        # say how much of what the program computes is padding
+        # boundary span: the host arrays (its child `host_inputs`) and the
+        # jitted call's launch; its counts say how much of what the
+        # program computes is padding
         programs = self.programs
+        per_lane = bool(programs.lane_state)
         with obs.span("prefill_chunk_dispatch", boundary=True,
                       rows=len(rows), bucket=c, rows_computed=p_,
                       computed_tokens=p_ * c) as span:
-            tokens = np.zeros((p_, c), np.int32)
-            tables = np.zeros((p_, self.blocks_per_seq), np.int32)
-            p0 = np.zeros((p_,), np.int32)
-            true_len = np.zeros((p_,), np.int32)
-            active = np.zeros((p_,), bool)
-            per_lane = bool(programs.lane_state)
-            # a padding row's lane is none (n_slots): written nowhere
-            lanes = np.full((p_,), self.n_slots, np.int32)
-            for i, r in enumerate(rows):
-                if per_lane:
-                    if not 0 <= int(r["lane"]) < self.n_slots:
-                        raise ValueError(
-                            f"row {i}: lane {r['lane']} outside "
-                            f"0..{self.n_slots - 1}")
-                    lanes[i] = int(r["lane"])
-                n = len(r["tokens"])
-                tokens[i, :n] = r["tokens"]
-                tables[i, :len(r["table"])] = r["table"]
-                p0[i] = int(r["p0"])
-                true_len[i] = n
-                active[i] = True
-            useful = int(true_len.sum())
-            span.set(useful_tokens=useful)
-            if getattr(programs, "window", None) is not None:
-                span.set(kv_rows_window=sum(
-                    programs.prefill_window_rows(int(r["p0"]), len(r["tokens"]))
-                    for r in rows))
-            smetrics.PREFILL_CHUNKS.inc(bucket=str(c))
-            smetrics.PREFILL_TOKENS.inc(useful)
+            with obs.span("host_inputs", boundary=True,
+                          arrays=5 + per_lane):
+                tokens = np.zeros((p_, c), np.int32)
+                tables = np.zeros((p_, self.blocks_per_seq), np.int32)
+                p0 = np.zeros((p_,), np.int32)
+                true_len = np.zeros((p_,), np.int32)
+                active = np.zeros((p_,), bool)
+                # a padding row's lane is none (n_slots): written nowhere
+                lanes = np.full((p_,), self.n_slots, np.int32)
+                for i, r in enumerate(rows):
+                    if per_lane:
+                        if not 0 <= int(r["lane"]) < self.n_slots:
+                            raise ValueError(
+                                f"row {i}: lane {r['lane']} outside "
+                                f"0..{self.n_slots - 1}")
+                        lanes[i] = int(r["lane"])
+                    n = len(r["tokens"])
+                    tokens[i, :n] = r["tokens"]
+                    tables[i, :len(r["table"])] = r["table"]
+                    p0[i] = int(r["p0"])
+                    true_len[i] = n
+                    active[i] = True
+                useful = int(true_len.sum())
+                span.set(useful_tokens=useful)
+                if getattr(programs, "window", None) is not None:
+                    span.set(kv_rows_window=sum(
+                        programs.prefill_window_rows(int(r["p0"]),
+                                                     len(r["tokens"]))
+                        for r in rows))
+                smetrics.PREFILL_CHUNKS.inc(bucket=str(c))
+                smetrics.PREFILL_TOKENS.inc(useful)
+                args = [host_input(a) for a in (
+                    tokens, tables, p0, true_len, active,
+                    *((lanes,) if per_lane else ()))]
             state, logits = self._call(
-                self._paged_prefill_jit, params, state,
-                host_input(tokens), host_input(tables),
-                host_input(p0), host_input(true_len),
-                host_input(active),
-                *((host_input(lanes),) if per_lane else ()),
-            )
+                self._paged_prefill_jit, params, state, *args)
             self.last_span = span
         return state, logits
 
@@ -719,17 +721,20 @@ class PagedServingEngine:
                 "drafts: a rejected token cannot be rolled back out of a "
                 "lane's state")
         smetrics.SPEC_VERIFY_DISPATCHES.inc()
-        with obs.span("spec_verify_dispatch", rows=int(np.sum(active)),
-                      width=int(np.asarray(tokens).shape[1])):
-            state, logits = self._call(
-                self._paged_verify_jit, params, state,
-                host_input(tokens, jnp.int32),
-                host_input(tables, jnp.int32),
-                host_input(p0, jnp.int32),
-                host_input(true_len, jnp.int32),
-                host_input(active, bool),
-            )
-        return state, logits
+        return self._call(self._paged_verify_jit, params, state,
+                          *self._inputs(tokens, tables, p0, true_len,
+                                        active))
+
+    @staticmethod
+    def _inputs(*arrays):
+        """Device arrays of a call's host ``arrays`` (int32 but the last,
+        the lanes' boolean ``active``), each from a private copy
+        (``host_input``), under a ``host_inputs`` boundary span, so that
+        the caller's span less this one is the call's launch alone."""
+        *ints, active = arrays
+        with obs.span("host_inputs", boundary=True, arrays=len(arrays)):
+            return (*(host_input(a, jnp.int32) for a in ints),
+                    host_input(active, bool))
 
     def _call(self, program, *args):
         """``(state, logits)`` of a jitted program; what a family's
@@ -742,13 +747,8 @@ class PagedServingEngine:
     def decode_step_paged(self, params, state, tokens, tables, lengths,
                           active):
         """One decode tick; host arrays in, ``(state, logits)`` out."""
-        return self._call(
-            self._paged_decode_jit, params, state,
-            host_input(tokens, jnp.int32),
-            host_input(tables, jnp.int32),
-            host_input(lengths, jnp.int32),
-            host_input(active, bool),
-        )
+        return self._call(self._paged_decode_jit, params, state,
+                          *self._inputs(tokens, tables, lengths, active))
 
     # ------------------------------------------------------------------
     # convenience: single-sequence greedy decode (tests / smoke)

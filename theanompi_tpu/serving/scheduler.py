@@ -394,10 +394,24 @@ class ContinuousBatchingScheduler:
         path needs — one dispatch picks a request's NEXT ``k+1`` tokens
         at indices ``len(output) + [0, k]``, each with the exact key the
         non-speculative path would have used at that index."""
-        # boundary span: the wait for the program that made the
-        # logits, the transfer to the host and the draw
+        # boundary span: the draw's enqueue, the wait for the device
+        # (its child) and the transfer to the host
         with obs.span("pick", boundary=True, rows=len(picks)):
-            return np.asarray(self._draw_tokens(picks, logits))
+            return self._fetch(self._draw_tokens(picks, logits), drains=True)
+
+    @staticmethod
+    def _fetch(drawn, drains: bool):
+        """``drawn`` on the host, its wait for the device under a
+        ``device_wait`` boundary span.  ``drains``: no program dispatched
+        after ``drawn``'s is still to run, so from the wait's end the
+        device has nothing queued until the host's next dispatch.  The
+        copy is asked for before the wait, so that it follows the program
+        as ``np.asarray`` alone would have it: asked for after the wait,
+        it cost a pick 0.24 ms on a v5e (PERF.md, section 6)."""
+        drawn.copy_to_host_async()
+        with obs.span("device_wait", boundary=True, drains=drains):
+            drawn.block_until_ready()
+        return np.asarray(drawn)
 
     def _draw_tokens(self, picks, logits):
         """The draw of ``_pick_tokens``, enqueued behind the program
@@ -635,11 +649,12 @@ class ContinuousBatchingScheduler:
         self.stats["prefill_chunks"] += len(calls)
         self.stats["prefill_tokens"] += n_tokens
         produced = 0
-        for lanes, reqs, drawn in calls:  # lane order, so emits are too
+        # lane order, so emits are too
+        for n, (lanes, reqs, drawn) in enumerate(calls, 1):
             if drawn is None:
                 continue
             with obs.span("pick", boundary=True, rows=width):
-                picks = np.asarray(drawn)
+                picks = self._fetch(drawn, drains=n == len(calls))
             for i, req, token in zip(lanes, reqs, picks):
                 if req is None:
                     continue
@@ -706,7 +721,9 @@ class ContinuousBatchingScheduler:
             logits,
         )
         if self._counters:
-            self._fetch_counters()
+            with obs.span("counters_fetch", boundary=True,
+                          calls=len(self._counters)):
+                self._fetch_counters()
         produced = 0
         for i in range(len(self.slots)):
             if not decoding[i]:
@@ -852,8 +869,9 @@ class ContinuousBatchingScheduler:
         step.  Returns the number of tokens generated."""
         self._n_ticks += 1
         # boundary span over the whole tick; its children are `admit`,
-        # `prefill`, `decode_step`/`spec_verify` and `pick`, so its self
-        # time is the Python between the programs
+        # `prefill`, `decode_step`/`spec_verify`, `pick` and
+        # `counters_fetch`, so its self time is the Python between the
+        # programs
         with obs.span("tick", boundary=True, n=self._n_ticks,
                       active=self.n_active, queued=len(self.queue)) as span:
             produced = self._tick()
